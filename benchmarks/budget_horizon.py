@@ -43,6 +43,7 @@ from benchmarks.common import csv_line, get_suite
 from repro.cluster import ClusterSim, PowerTopology, scenario as sc
 from repro.cluster import budget as bm
 from repro.cluster.controller import make_controller
+from repro.kernels.ops import use_compile_cache
 
 #: planner knobs (full tiers); ``--fast`` shortens the horizon with the day
 HORIZON = 12
@@ -244,6 +245,7 @@ def check_against(reference: dict, results: list) -> list[str]:
 
 
 def main() -> None:
+    use_compile_cache()
     import argparse
     import sys
 

@@ -33,6 +33,7 @@ import numpy as np
 from benchmarks.common import csv_line, get_suite
 from repro.cluster import ClusterSim, Scenario
 from repro.cluster.controller import make_controller
+from repro.kernels.ops import use_compile_cache
 
 #: wall-clock guard for the top-tier 20-round grouped scenario (matches the
 #: CI smoke budget; the acceptance bar for DESIGN.md §11)
@@ -205,6 +206,7 @@ def check_against(reference: dict, results: list) -> list[str]:
 
 
 def main() -> None:
+    use_compile_cache()
     import argparse
     import sys
 

@@ -38,6 +38,7 @@ from benchmarks.common import csv_line, get_suite
 from repro.cluster import ClusterSim, PowerTopology, Scenario
 from repro.cluster.controller import make_controller
 from repro.cluster.faults import ControllerCrash
+from repro.kernels.ops import use_compile_cache
 
 #: per-channel per-round fault probabilities swept by the flat tier
 RATES = (0.0, 0.05, 0.15, 0.30)
@@ -313,6 +314,7 @@ def check_against(reference: dict, results: list) -> list[str]:
 
 
 def main() -> None:
+    use_compile_cache()
     import argparse
     import sys
 

@@ -39,6 +39,7 @@ import time
 from benchmarks.common import csv_line, get_suite
 from repro.cluster import ClusterSim, PowerDomain, PowerTopology
 from repro.cluster.controller import make_controller
+from repro.kernels.ops import use_compile_cache
 
 #: acceptance bar: multi-domain round time vs the flat grouped round
 MAX_RATIO_VS_FLAT = 2.0
@@ -415,6 +416,7 @@ def check_against(reference: dict, results: list) -> list[str]:
 
 
 def main() -> None:
+    use_compile_cache()
     import argparse
     import sys
 

@@ -83,6 +83,7 @@ import numpy as np
 from benchmarks.common import csv_line, get_suite
 from repro.cluster import ClusterSim, scenario as sc
 from repro.cluster.controller import make_controller
+from repro.kernels.ops import use_compile_cache
 
 #: acceptance bar (ISSUE 5): the steady-state (no-event) warm round at the
 #: top tier must be >= this factor faster than the from-scratch round
@@ -312,27 +313,25 @@ def _measure_fused_case(
     return case
 
 
-def _measure_fused_churn_case(
+def fused_churn_rounds(
     system, apps, surfs, n: int, churn: float, *, topology, policy: str,
-) -> dict:
-    """Fused round under *structure churn* (DESIGN.md §17): the same MIX
-    event storm as the host churn cases, three controllers (fused / host
-    incremental / from-scratch) through identical sims, per-round
-    bit-for-bit parity.  The fused path must serve every structure-
-    changing round on device — ``post_warmup_fallbacks`` proves it."""
+    variants: tuple,
+):
+    """Drive the fused-under-churn scenario (DESIGN.md §17) through one
+    identical sim per ``(label, controller kwargs)`` variant.
+
+    ``CHURN_N_ROUNDS`` rounds under budget drift (``budget - 25 r``: no
+    whole-solution cache hits) with the MIX event storm on ``churn * n``
+    nodes per round from round 1.  Yields ``(r, budget, [(label, sim,
+    ctrl, result)])`` per round; the first variant runs first."""
     budget = _budget(n)
     rng = np.random.default_rng(23)
-    variants = (
-        ("fused", dict(fused=True)),
-        ("host", {}),
-        ("from_scratch", dict(incremental=False)),
-    )
     trips = []
     for label, kw in variants:
         sim = _sim(system, apps, surfs, n, topology=topology)
         ctrl = make_controller(policy, system, **kw)
         trips.append((label, sim, ctrl))
-    sim0, fused_ctrl = trips[0][1], trips[0][2]
+    sim0 = trips[0][1]
     _, recv, _ = sim0.partition_rows()
     recv_apps = sorted(
         {sim0.table.strings[g] for g in sim0.table.base_gid[recv]}
@@ -343,12 +342,9 @@ def _measure_fused_churn_case(
         if topology is not None
         else None
     )
-    alloc_ts: dict[str, list[float]] = {label: [] for label, _, _ in trips}
-    device_ts: list[float] = []
     k = int(n * churn)
-    warmup_fallbacks = 0
     for r in range(CHURN_N_ROUNDS):
-        b = budget - 25.0 * r  # drift: no whole-solution cache hits
+        b = budget - 25.0 * r
         events = (
             _churn_events(sim0, rng, r, k, recv_apps, app_by_name, racks)
             if churn > 0 and r >= 1 else []
@@ -358,19 +354,47 @@ def _measure_fused_churn_case(
             if events:
                 touched = sim.apply_events(events)
                 ctrl.invalidate(touched)
-            if label == "fused":
+            if ctrl.fused:
                 _fused_sync(ctrl)
             res = sim.run_round(ctrl, budget=b, round_index=r)
-            if label == "fused":
+            if ctrl.fused:
                 _fused_sync(ctrl)
+            results.append((label, sim, ctrl, res))
+        yield r, b, results
+
+
+def _measure_fused_churn_case(
+    system, apps, surfs, n: int, churn: float, *, topology, policy: str,
+) -> dict:
+    """Fused round under *structure churn* (DESIGN.md §17): the same MIX
+    event storm as the host churn cases, three controllers (fused / host
+    incremental / from-scratch) through identical sims, per-round
+    bit-for-bit parity.  The fused path must serve every structure-
+    changing round on device — ``post_warmup_fallbacks`` proves it."""
+    variants = (
+        ("fused", dict(fused=True)),
+        ("host", {}),
+        ("from_scratch", dict(incremental=False)),
+    )
+    alloc_ts: dict[str, list[float]] = {label: [] for label, _ in variants}
+    device_ts: list[float] = []
+    warmup_fallbacks = 0
+    fused_ctrl = None
+    for r, _b, results in fused_churn_rounds(
+        system, apps, surfs, n, churn, topology=topology, policy=policy,
+        variants=variants,
+    ):
+        got = []
+        for label, sim, ctrl, res in results:
             alloc_ts[label].append(float(sim.last_round_profile["allocate_s"]))
             if label == "fused":
+                fused_ctrl = ctrl
                 device_ts.append(
                     float(sim.last_round_profile["alloc_device_s"])
                 )
-            results.append((dict(res.allocation.caps), res.allocation.spent))
-        for (label, _, _), got in zip(trips[1:], results[1:]):
-            assert results[0] == got, (
+            got.append((dict(res.allocation.caps), res.allocation.spent))
+        for (label, *_), other in zip(results[1:], got[1:]):
+            assert got[0] == other, (
                 f"{policy} n={n} fused churn={churn}: fused diverged from "
                 f"{label} at round {r}"
             )
@@ -616,6 +640,7 @@ def check_against(reference: dict, results: list) -> list[str]:
 
 
 def main() -> None:
+    use_compile_cache()
     import argparse
     import sys
 

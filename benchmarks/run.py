@@ -10,8 +10,11 @@ import argparse
 import sys
 import time
 
+from repro.kernels.ops import use_compile_cache
+
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true", help="trimmed sweeps")
     ap.add_argument("--only", default=None, help="substring filter on modules")

@@ -15,6 +15,7 @@ instantaneous budget.
 from repro.cluster import ClusterSim, ConstantProvider, Scenario
 from repro.cluster.controller import make_controller
 from repro.core import surfaces, types
+from repro.kernels.ops import use_compile_cache
 
 SYSTEM = types.SYSTEM_1
 N_NODES = 100
@@ -36,6 +37,7 @@ def score(res):
 
 
 def main() -> None:
+    use_compile_cache()
     apps, surfs = surfaces.build_paper_suite(SYSTEM)
     scen = Scenario.carbon_aware(N_ROUNDS, ConstantProvider(BUDGET_W))
 

@@ -13,11 +13,13 @@ import numpy as np
 from repro.core import ncf, policies, surfaces, types
 from repro.core.allocator import EcoShiftAllocator
 from repro.core.emulator import ClusterEmulator
+from repro.kernels.ops import use_compile_cache
 
 SYSTEM = types.SYSTEM_2
 
 
 def main() -> None:
+    use_compile_cache()
     print("== EcoShift quickstart ==")
     apps, surfs = surfaces.build_paper_suite(SYSTEM)
 

@@ -16,12 +16,14 @@ import numpy as np
 
 from repro import configs
 from repro.core.arch_surfaces import RooflineSurface
+from repro.kernels.ops import use_compile_cache
 from repro.models.model import Model
 from repro.roofline import model as roof
 from repro.serving.engine import ServeEngine
 
 
 def main() -> None:
+    use_compile_cache()
     cfg = dataclasses.replace(configs.smoke_config("gemma3-27b"), dtype="float32")
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))

@@ -21,6 +21,7 @@ from repro.cluster.sim import NodeState
 from repro.core import policies
 from repro.core.arch_surfaces import RooflineSurface
 from repro.core.types import SYSTEM_TPU_V5E, AppSpec
+from repro.kernels.ops import use_compile_cache
 from repro.models.model import Model
 from repro.train.checkpoint import CheckpointManager
 from repro.train.data import make_batch_fn
@@ -28,6 +29,7 @@ from repro.train.train_loop import Trainer
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b")
     ap.add_argument("--steps", type=int, default=120)

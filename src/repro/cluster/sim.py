@@ -160,7 +160,9 @@ class DeviceView:
     The fused steady-state round (DESIGN.md §14/§17) keeps its decision
     pipeline on device; this view gives the engine the matching residency
     for the numeric cluster state: ``caps``/``alive``/``slowdown``/
-    ``domain_id`` live as jax device arrays (float64 preserved), and
+    ``domain_id`` live as jax device arrays (the float columns in
+    ``ops.device_value_dtype()``: float64 on the CPU, float32 on a TPU,
+    whose kernels take no float64), and
     :meth:`refresh` syncs them against the table's dirty-row log — one
     donated row scatter per changed column in steady state.  Growth is
     O(growth), not O(cluster): the resident prefix is reused as-is on
@@ -185,27 +187,39 @@ class DeviceView:
         self.slowdown = None
         self.domain_id = None
 
+    def _col(self, name: str, rows=slice(None)):
+        """Host column slice in its device dtype."""
+        import jax.numpy as jnp
+
+        from repro.kernels import ops
+
+        col = getattr(self._table, name)[rows]
+        if col.dtype.kind == "f":
+            return jnp.asarray(col, dtype=ops.device_value_dtype())
+        return jnp.asarray(col)
+
     def refresh(self) -> "DeviceView":
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+
+        from repro.kernels import ops
 
         t = self._table
         if t.version == self.version and self._n == len(t):
             return self
         dirty = t.dirty_since(self.version) if self.version >= 0 else None
-        with enable_x64():
+        with ops.device_value_scope():
             # patching more than half the table costs more dispatches than
             # one bulk upload
             if dirty is None or len(dirty) > max(1, len(t) // 2):
                 for c in self._COLS:
-                    setattr(self, c, jnp.asarray(getattr(t, c)))
+                    setattr(self, c, self._col(c))
                 self.uploads_full += 1
             else:
                 if len(t) > self._n:
                     # device-side extend (rows are append-only): keep the
                     # resident prefix, upload only the appended tail
                     for c in self._COLS:
-                        tail = jnp.asarray(getattr(t, c)[self._n:])
+                        tail = self._col(c, slice(self._n, None))
                         setattr(
                             self, c,
                             jnp.concatenate([getattr(self, c), tail]),
@@ -214,10 +228,10 @@ class DeviceView:
                     self.uploads_rows += len(t) - self._n
                     dirty = dirty[dirty < self._n]
                 if len(dirty):
-                    rows = jnp.asarray(dirty)
+                    rows = jnp.asarray(dirty, dtype=jnp.int32)
                     patch = _device_patch_fn()
                     for c in self._COLS:
-                        vals = jnp.asarray(getattr(t, c)[dirty])
+                        vals = self._col(c, dirty)
                         setattr(self, c, patch(getattr(self, c), rows, vals))
                     self.uploads_rows += int(len(dirty))
         self.version = t.version

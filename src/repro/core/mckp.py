@@ -1663,9 +1663,10 @@ def _pow2_at_least(n: int, floor: int) -> int:
 class FusedState:
     """Device-resident warm state for the fused steady-state round.
 
-    Holds the padded ``[S, L, K]`` option banks (spend offsets on the
-    shared integer micro-watt lattice + float64 values) as *resident jax
-    device arrays*, the host-side per-row content signatures that drive
+    Holds the padded ``[S, L, K]`` option banks (int32 spend offsets on
+    the shared integer micro-watt lattice + values in
+    ``ops.device_value_dtype()``: float64 on the CPU, float32 on a TPU)
+    as *resident jax device arrays*, the host-side per-row content signatures that drive
     delta patching, and the reversed per-stage key arrays the host
     assembly maps device backpointers through.  Banks use
     **capacity-slack layouts** (DESIGN.md §17): padded dims are quantized
@@ -1695,7 +1696,7 @@ class FusedState:
         self.names: tuple | None = None  # per-leaf names (compaction map)
         self.row_sigs: list | None = None  # [L][S] per-row content sigs
         self.kb_dev = None  # [S, L, K] int32 device bank (global lattice)
-        self.vb_dev = None  # [S, L, K] float64 device bank
+        self.vb_dev = None  # [S, L, K] device-value-dtype bank
         self.keys_desc: list | None = None  # [L][S] host reversed key arrays
         self.g: int = 0  # global micro-watt lattice pitch
         self.last_key: tuple | None = None
@@ -1717,6 +1718,7 @@ class FusedState:
             "slack_utilization": 0.0,
             "device_s": 0.0,
             "fallback_reason": "",
+            "value_bound": 0.0,
         }
 
     def clear(self) -> None:
@@ -1867,21 +1869,22 @@ def _tree_waves(
 @functools.cache
 def _fused_pipeline_fn(
     tree: tuple | None, L: int, Lp: int, S: int, K: int, NB: int, NBT: int,
-    block_b: int, shards: int, interpret: bool,
+    shards: int, interpret: bool,
 ):
     """Build the jitted fused round for one static shape.
 
     One XLA program: batched leaf super-stage DPs (Pallas sparse-option
     (max,+) stages with backpointer outputs), the depth-wave frontier
-    aggregation schedule of an arbitrary-depth domain tree (the same
-    kernel with dense descending offsets, masked at each owning domain's
-    cap cut), the root argmax, and the index-based backtrack — device
-    gathers through the recorded backpointer tables instead of a host
-    Python unwind.  Mirrors ``_superstage_dp_batch`` +
+    aggregation schedule of an arbitrary-depth domain tree (the dense
+    (max,+) kernel in descending shift order, masked at each owning
+    domain's cap cut), the root argmax, and the index-based backtrack —
+    device gathers through the recorded backpointer tables instead of a
+    host Python unwind.  Mirrors ``_superstage_dp_batch`` +
     ``_combine_frontiers`` + ``_backtrack_superstages`` op for op
-    (float64, first-max argmax, per-stage feasibility masks, per-pair
-    cap pruning), so its decisions are bit-for-bit the sparse host
-    path's at any tree depth.
+    (first-max argmax, per-stage feasibility masks, per-pair cap
+    pruning), so with float64 values its decisions are bit-for-bit the
+    sparse host path's at any tree depth; with float32 values (a TPU)
+    they hold the DESIGN.md §14 bound.  Indices are int32 throughout.
 
     ``tree`` is the static ``(waves, dom_rows)`` schedule from
     ``_tree_ops``/``_tree_waves`` (None for flat/leaf-root rounds);
@@ -1910,14 +1913,14 @@ def _fused_pipeline_fn(
     root_row = dom_rows[-1] if dom_rows else 0
 
     def leaf_scan(kb, vb, tmax_leaf):
-        t_idx = jnp.arange(NB)
+        t_idx = jnp.arange(NB, dtype=jnp.int32)
         neg = jnp.asarray(-jnp.inf, vb.dtype)
         dp0 = jnp.full((kb.shape[1], NB), neg).at[:, 0].set(0.0)
 
         def stage(dp, skv):
             kb_s, vb_s = skv
             out, arg = _mk.maxplus_stage_pallas_batched(
-                dp, kb_s, vb_s, block_b=block_b, interpret=interpret
+                dp, kb_s, vb_s, interpret=interpret
             )
             # per-leaf feasibility mask after every stage == the host
             # batch's out[li, tmax+1:] = -inf
@@ -1926,61 +1929,33 @@ def _fused_pipeline_fn(
 
         return jax.lax.scan(stage, dp0, (kb, vb))
 
-    if shards > 1:
-        from jax.sharding import PartitionSpec as P
+    def rows(ids):
+        return jnp.asarray(ids, dtype=jnp.int32)
 
-        try:
-            from jax.experimental.shard_map import shard_map
-        except ImportError:  # newer jax: promoted out of experimental
-            from jax import shard_map  # type: ignore[attr-defined]
-
-        from repro.kernels import ops as _kops
-
-        leaf_fn = shard_map(
-            leaf_scan,
-            mesh=_kops.leaf_shard_mesh(shards),
-            in_specs=(
-                P(None, "leaves", None),
-                P(None, "leaves", None),
-                P("leaves"),
-            ),
-            out_specs=(P("leaves", None), P(None, "leaves", None)),
-            check_rep=False,  # pallas_call carries no replication rule
-        )
-    else:
-        leaf_fn = leaf_scan
-
-    @jax.jit
-    def run(kb, vb, tmax_leaf, tcuts):
-        rows_i = jnp.arange(L)
-        neg = jnp.asarray(-jnp.inf, vb.dtype)
-        dp, wins = leaf_fn(kb, vb, tmax_leaf)  # dp: [Lp, NB]; wins: [S, Lp, NB]
-
-        # frontier aggregation: depth waves of pairwise combines, each the
-        # same sparse-option kernel with the dense descending offset row
-        # (b-spend descending == the dict DP's smallest-a-spend tie-break),
+    def tree_solve(dp, tcuts):
+        # frontier aggregation: depth waves of pairwise combines, each one
+        # dense (max,+) convolution of the left frontiers with the first
+        # k_level points of the right ones, first max in *descending*
+        # right spend (== the dict DP's smallest-left-spend tie-break),
         # masked at the owning domain's cap cut — the device image of
         # _combine_frontiers applying _maxplus_pair(..., eff) at every pair
-        t_idx_tree = jnp.arange(NBT)
-        tree_block = min(NBT, 256)
+        neg = jnp.asarray(-jnp.inf, dp.dtype)
+        t_idx_tree = jnp.arange(NBT, dtype=jnp.int32)
         buf = (
             jnp.concatenate([dp, jnp.full((Lp, NBT - NB), neg)], axis=1)
             if NBT > NB
             else dp
         )
-        wins_tree = []
+        wins_tree = []  # per wave: [ops, NBT] winning right spends
         for k_level, wave in waves:
-            left = buf[jnp.asarray([op[0] for op in wave])]
-            right = buf[jnp.asarray([op[1] for op in wave])]
-            comb_desc = jnp.arange(k_level - 1, -1, -1, dtype=jnp.int32)
-            ckb = jnp.broadcast_to(comb_desc[None, :], (len(wave), k_level))
-            cvb = right[:, k_level - 1 :: -1]
-            out, arg = _mk.maxplus_stage_pallas_batched(
-                left, ckb, cvb, block_b=tree_block, interpret=interpret
+            left = buf[rows([op[0] for op in wave])]
+            right = buf[rows([op[1] for op in wave]), :k_level]
+            out, t_right = _mk.maxplus_conv_pallas_batched(
+                left, right, descending=True, interpret=interpret
             )
-            tc = tcuts[jnp.asarray([op[3] for op in wave])]
+            tc = tcuts[rows([op[3] for op in wave])]
             out = jnp.where(t_idx_tree[None, :] > tc[:, None], neg, out)
-            wins_tree.append(arg)
+            wins_tree.append(t_right)
             buf = jnp.concatenate([buf, out], axis=0)
 
         root_vec = buf[root_row]
@@ -1991,20 +1966,56 @@ def _fused_pipeline_fn(
         # in reverse wave order (an op's output t is known before its
         # inputs are needed — the schedule is topological)
         t_of = {root_row: t_root}
-        for (k_level, wave), win in zip(reversed(waves), reversed(wins_tree)):
+        for (_k, wave), win in zip(reversed(waves), reversed(wins_tree)):
             for i in range(len(wave) - 1, -1, -1):
                 l_row, r_row, o_row, _d = wave[i]
                 t_out = t_of[o_row]
-                j = win[i, t_out]
-                t_r = (k_level - 1 - j).astype(jnp.int32)
+                t_r = win[i, t_out]
                 t_of[r_row] = t_r
-                t_of[l_row] = (t_out - t_r).astype(jnp.int32)
+                t_of[l_row] = t_out - t_r
         t_leaf = jnp.stack([t_of[i] for i in range(L)]).astype(jnp.int32)
         t_dom = (
             jnp.stack([t_of[r] for r in dom_rows]).astype(jnp.int32)
             if dom_rows
             else jnp.zeros((0,), jnp.int32)
         )
+        return t_root, root_val, t_leaf, t_dom
+
+    if shards > 1:
+        from jax.sharding import PartitionSpec as P
+
+        from repro.kernels import ops as _kops
+
+        mesh = _kops.leaf_shard_mesh(shards)
+        leaf_fn = jax.shard_map(
+            leaf_scan,
+            mesh=mesh,
+            in_specs=(
+                P(None, "leaves", None),
+                P(None, "leaves", None),
+                P("leaves"),
+            ),
+            out_specs=(P("leaves", None), P(None, "leaves", None)),
+            check_vma=False,  # pallas_call carries no replication rule
+        )
+        # the frontier tree is small: every device combines the gathered
+        # leaf frontiers redundantly (a Mosaic kernel in a multi-device
+        # program must sit inside a shard_map)
+        tree_fn = jax.shard_map(
+            tree_solve,
+            mesh=mesh,
+            in_specs=(P(), P()),
+            out_specs=(P(), P(), P(), P()),
+            check_vma=False,
+        )
+    else:
+        leaf_fn, tree_fn = leaf_scan, tree_solve
+
+    @jax.jit
+    def run(kb, vb, tmax_leaf, tcuts):
+        rows_i = jnp.arange(L, dtype=jnp.int32)
+        dp, wins = leaf_fn(kb, vb, tmax_leaf)  # dp: [Lp, NB]; wins: [S, Lp, NB]
+        t_root, root_val, t_leaf, t_dom = tree_fn(dp, tcuts)
 
         # leaf backtrack: walk the backpointer tables stage-by-stage, the
         # device-gather analogue of _IntStages.backtrack
@@ -2025,7 +2036,8 @@ def _fused_leaf_rows(
 ) -> tuple[int, int, list] | None:
     """Per-leaf lattice prep, mirroring ``_superstage_dp_batch``'s per-job
     block: micro-int class keys, the leaf gcd pitch, and the per-stage
-    descending (offsets, values, keys) rows.  None routes to host."""
+    descending (offsets, values, keys, sig, max |value|) rows.  None
+    routes to host."""
     name, eff, plan, curves_, curve_keys = spec
     lkey = tuple(curve_keys)
     ent = fstate._leaf_ints.get(lkey)
@@ -2062,11 +2074,13 @@ def _fused_leaf_rows(
             if not len(keep):
                 return None
             kb = (ia[keep] // g_l)[::-1].copy()  # leaf-lattice units
+            vals = curve.vals[keep][::-1].copy()
             row = (
                 kb,
-                curve.vals[keep][::-1].copy(),
+                vals,
                 curve.keys[keep][::-1].copy(),
                 sig,
+                float(np.abs(vals).max()),
             )
             if len(fstate._row_cache) > 4096:
                 fstate._row_cache.clear()
@@ -2107,8 +2121,9 @@ def _fused_run(
     import time
 
     import jax
-    import jax.experimental
     import jax.numpy as jnp
+
+    from repro.kernels import ops as _kops
 
     stats = fstate.stats
     seg = fstate.last_segments = {
@@ -2146,6 +2161,7 @@ def _fused_run(
     s_max = 1
     k_max = 1
     nb_needed = 1
+    v_abs = 0.0  # sum of every stage's largest |value|: bounds any path
     tmax_dev = np.zeros(Lp, dtype=np.int32)
     for li, (g_l, tmax_host, rows, all_zero) in enumerate(prepped):
         if rows:
@@ -2158,8 +2174,9 @@ def _fused_run(
             tmax_dev[li] = td
             nb_needed = max(nb_needed, td + 1)
             s_max = max(s_max, len(rows))
-            for kb, _, _, _ in rows:
-                k_max = max(k_max, len(kb))
+            for row in rows:
+                k_max = max(k_max, len(row[0]))
+                v_abs += row[4]
 
     use_tree = kind == "tree"
     tcuts = np.zeros(len(doms), dtype=np.int32)
@@ -2240,6 +2257,17 @@ def _fused_run(
         (nbt_needed / nbt_pad) if use_tree else 0.0,
     )
 
+    # DESIGN.md §14: a device path value is a sum tree over its option
+    # values with n = stages + tree depth + 1 roundings per term (the +1
+    # rounds each value into the device dtype), so it is within
+    # gamma_n * v_abs of the exact sum, and the device's argmax path
+    # trails the host optimum by at most twice that: 2 (n + 1) u v_abs
+    # with u = eps / 2 (the extra +1 absorbs gamma_n's higher-order term
+    # and the float64 host's own sums)
+    vdt = _kops.device_value_dtype()
+    n_round = s_max + (max(depths.values()) if depths else 0) + 1
+    stats["value_bound"] = (n_round + 1) * float(np.finfo(vdt).eps) * v_abs
+
     bank_shape = (s_pad, Lp, k_pad)
     rebuild = fstate.shape is None
     compact = not rebuild and (
@@ -2254,7 +2282,22 @@ def _fused_run(
         # leaf identities): cold host rebuild — still a fused round
         rebuild, compact = True, False
 
-    with jax.experimental.enable_x64():
+    if shards > 1:
+        # resident banks live split leaf-wise over the shard mesh, where
+        # the sharded leaf scan reads them
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        bank_sharding = NamedSharding(
+            _kops.leaf_shard_mesh(shards), P(None, "leaves", None)
+        )
+
+        def place(x):
+            return jax.device_put(x, bank_sharding)
+    else:
+        place = jnp.asarray
+
+    with _kops.device_value_scope():
 
         def upload_rows(entries):
             # entries: (s, li, kb_glob | None, vb | None); None = identity.
@@ -2283,7 +2326,9 @@ def _fused_run(
             vb_rows[m:] = vb_rows[0]
             si, lj = jnp.asarray(s_np), jnp.asarray(l_np)
             fstate.kb_dev = patch(fstate.kb_dev, si, lj, jnp.asarray(kb_rows))
-            fstate.vb_dev = patch(fstate.vb_dev, si, lj, jnp.asarray(vb_rows))
+            fstate.vb_dev = patch(
+                fstate.vb_dev, si, lj, jnp.asarray(vb_rows, dtype=vdt)
+            )
             stats["row_uploads"] += m
             fstate.last_key = None
 
@@ -2299,15 +2344,15 @@ def _fused_run(
             keys_desc: list[list] = [[None] * s_pad for _ in range(L)]
             for li, (g_l, tmax_host, rows, all_zero) in enumerate(prepped):
                 mult = 1 if all_zero else g_l // g
-                for s, (kb, vb, keys, sig) in enumerate(rows):
+                for s, (kb, vb, keys, sig, _a) in enumerate(rows):
                     n = len(kb)
                     kb_np[s, li, :n] = kb * mult
                     vb_np[s, li, :n] = vb
                     vb_np[s, li, n:] = -np.inf
                     row_sigs[li][s] = (sig, mult)
                     keys_desc[li][s] = keys
-            fstate.kb_dev = jnp.asarray(kb_np)
-            fstate.vb_dev = jnp.asarray(vb_np)
+            fstate.kb_dev = place(kb_np)
+            fstate.vb_dev = place(vb_np.astype(vdt))
             fstate.row_sigs = row_sigs
             fstate.keys_desc = keys_desc
             fstate.shape = layout
@@ -2337,7 +2382,7 @@ def _fused_run(
                 oli = old_pos.get(names[li])
                 for s in range(s_pad):
                     if s < len(rows):
-                        kb, vb, keys, sig = rows[s]
+                        kb, vb, keys, sig, _a = rows[s]
                         esig = (sig, mult)
                     else:
                         kb = vb = keys = None
@@ -2355,10 +2400,10 @@ def _fused_run(
                         src_l[s, li] = oli
                     else:
                         dirty.append((s, li, kb * mult, vb))
-            fstate.kb_dev, fstate.vb_dev = _kops.bank_compact(
+            fstate.kb_dev, fstate.vb_dev = map(place, _kops.bank_compact(
                 fstate.kb_dev, fstate.vb_dev,
                 jnp.asarray(src_s), jnp.asarray(src_l), k_pad=k_pad,
-            )
+            ))
             fstate.row_sigs = row_sigs
             fstate.keys_desc = keys_desc
             fstate.shape = layout
@@ -2381,7 +2426,7 @@ def _fused_run(
                 mult = 1 if all_zero else g_l // g
                 for s in range(s_pad):
                     if s < len(rows):
-                        kb, vb, keys, sig = rows[s]
+                        kb, vb, keys, sig, _a = rows[s]
                         esig = (sig, mult)
                     else:
                         kb = vb = keys = None
@@ -2405,7 +2450,7 @@ def _fused_run(
             tree_static = (waves, dom_rows)
         run = _fused_pipeline_fn(
             tree_static, L, Lp, s_pad, k_pad, nb_pad, nbt_pad,
-            min(nb_pad, 256), shards, _interpret(),
+            shards, not _kops.on_tpu(),
         )
         t0 = time.perf_counter()
         out = jax.block_until_ready(
@@ -2515,13 +2560,6 @@ def _fused_run(
     fstate.last_solution = sol
     seg["assembly_s"] += time.perf_counter() - t_seg
     return sol
-
-
-@functools.cache
-def _interpret() -> bool:
-    import jax
-
-    return jax.default_backend() != "tpu"
 
 
 def solve_grouped_fused(

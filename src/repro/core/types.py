@@ -190,6 +190,11 @@ class FusedRoundStats:
     #: (the historical "structure_change" fallback is retired — structure
     #: churn patches or compacts the resident banks and stays fused)
     fallback_reason: str = ""
+    #: most recent round's bound on how far its total value may trail the
+    #: host optimum (DESIGN.md §14): rounding of the device value dtype
+    #: over the round's stage count and tree depth — float32 on a TPU;
+    #: under float64 the round is bit-for-bit the host's
+    value_bound: float = 0.0
 
     @property
     def attempts(self) -> int:

@@ -12,22 +12,25 @@ paper's host-Python loop to an accelerator kernel, see DESIGN.md §8.1).
 
 TPU mapping
 -----------
-(max,+) cannot use the MXU (no tropical matmul), so this is a VPU kernel:
+(max,+) cannot use the MXU (no tropical matmul), so this is a VPU kernel
+over (8, 128)-aligned tiles:
 
- * ``dp`` is small (NB fp32 ≈ 56 KB at NB=14001): we keep the *whole*
-   left-padded operand resident in VMEM (no HBM re-streaming per block).
- * The output is tiled into ``block_b``-wide vector blocks (multiple of the
-   128-lane VPU width); the grid iterates over output blocks.
- * For each shift ``k`` the candidate vector ``dp[b0-k : b0-k+block_b]`` is
-   a *contiguous* VMEM slice (the Toeplitz structure turns the gather into a
-   sliding window), so the inner loop is: contiguous load -> broadcast add
-   f[k] -> elementwise max.  ``block_b`` elements of useful work per loop
-   iteration, no scatter/gather.
- * Argmax is tracked alongside (smallest maximizing k, matching the numpy
-   reference tie-break).
+ * Rows (independent DPs) ride the 8 sublanes, the budget grid the lanes:
+   each grid step holds one ``[8, NBp]`` row tile (NBp = NB rounded up to
+   128 lanes) of ``dp`` and ``f`` in VMEM.
+ * For each shift ``k`` every row of the tile needs ``dp[b - k]`` — one
+   uniform lane rotation (``pltpu.roll``) with the wrapped-around lanes
+   ``b < k`` masked to -inf, so no load is ever lane-unaligned.
+ * The per-row scalar ``f[k]`` comes from the 128-lane-aligned block that
+   holds lane ``k``, rotated so that lane lands at 0 and broadcast along
+   the lanes.
+ * Argmax is tracked alongside in int32 (first maximizer in the loop's
+   shift order; ``-1`` where nothing beats -inf, mapped to 0 by callers).
 
-Left-padding ``dp`` with NB entries of -inf makes every slice in-bounds:
-index ``NB + b0 - k`` is >= 1 for k <= NB-1.
+Every index is explicit int32, so the kernel lowers the same under x64
+(CPU interpret mode, float64 values) and on the chip (float32 values).
+The sparse-option stage of the fused round reuses the same kernel: its
+options are scattered onto a dense per-row curve first.
 """
 
 from __future__ import annotations
@@ -37,113 +40,107 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+#: (sublane, lane) tile of one 32-bit vector register
+_ROWS = 8
+_LANES = 128
 
 
-def _maxplus_kernel(dp_pad_ref, f_ref, out_ref, arg_ref, *, block_b: int, nb: int):
-    i = pl.program_id(0)
-    b0 = i * block_b
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
-    def body(k, carry):
+
+def _maxplus_kernel(dp_ref, f_ref, out_ref, arg_ref, *, n_shifts, descending):
+    dp = dp_ref[...]
+    neg = jnp.asarray(-jnp.inf, dp.dtype)
+    lane = jax.lax.broadcasted_iota(jnp.int32, dp.shape, 1)
+    last = jnp.int32(n_shifts - 1)
+
+    def body(i, carry):
         acc, arg = carry
-        # contiguous sliding-window slice: dp[b - k] for b in [b0, b0+block_b)
-        col = dp_pad_ref[pl.dslice(nb + b0 - k, block_b)]
-        fk = f_ref[pl.dslice(k, 1)]  # [1], broadcasts
+        k = last - i if descending else i
+        # f[:, k] as an [8, 1] column: aligned 128-lane block, lane k%128
+        # rotated to lane 0
+        base = pl.multiple_of((k // _LANES) * _LANES, _LANES)
+        fblk = f_ref[:, pl.ds(base, _LANES)]
+        fk = pltpu.roll(fblk, (_LANES - k % _LANES) % _LANES, 1)[:, :1]
+        # dp[b - k]: uniform rotation, wrapped lanes b < k read -inf
+        col = jnp.where(lane >= k, pltpu.roll(dp, k, 1), neg)
         cand = col + fk
         better = cand > acc
-        acc = jnp.where(better, cand, acc)
-        arg = jnp.where(better, k, arg)
-        return acc, arg
+        return jnp.where(better, cand, acc), jnp.where(better, k, arg)
 
-    acc0 = jnp.full((block_b,), -jnp.inf, dtype=out_ref.dtype)
-    arg0 = jnp.zeros((block_b,), dtype=jnp.int32)
-    acc, arg = jax.lax.fori_loop(0, nb, body, (acc0, arg0))
+    acc0 = jnp.full(dp.shape, neg)
+    arg0 = jnp.full(dp.shape, -1, jnp.int32)
+    acc, arg = jax.lax.fori_loop(
+        jnp.int32(0), jnp.int32(n_shifts), body, (acc0, arg0)
+    )
     out_ref[...] = acc
     arg_ref[...] = arg
 
 
-def _maxplus_kernel_batched(
-    dp_pad_ref, f_ref, out_ref, arg_ref, *, block_b: int, nb: int
-):
-    i = pl.program_id(1)
-    b0 = i * block_b
+def _maxplus_tiles(dp, f, *, n_shifts: int, descending: bool, interpret: bool):
+    """Row-batched dense (max,+) convolution over aligned [8, NBp] tiles.
 
-    def body(k, carry):
-        acc, arg = carry
-        # per-row contiguous sliding window: dp[r, b - k] for the block
-        col = dp_pad_ref[0, pl.dslice(nb + b0 - k, block_b)]
-        fk = f_ref[0, pl.dslice(k, 1)]  # [1], broadcasts
-        cand = col + fk
-        better = cand > acc
-        acc = jnp.where(better, cand, acc)
-        arg = jnp.where(better, k, arg)
-        return acc, arg
-
-    acc0 = jnp.full((block_b,), -jnp.inf, dtype=out_ref.dtype)
-    arg0 = jnp.zeros((block_b,), dtype=jnp.int32)
-    acc, arg = jax.lax.fori_loop(0, nb, body, (acc0, arg0))
-    out_ref[0, ...] = acc
-    arg_ref[0, ...] = arg
-
-
-def _maxplus_stage_kernel_batched(
-    dp_pad_ref, kb_ref, vb_ref, out_ref, arg_ref, *, block_b: int, nb: int,
-    k_opts: int,
-):
-    """Sparse-option (max,+) DP stage with a backpointer output.
-
-    Where :func:`_maxplus_kernel_batched` slides over every grid offset,
-    this kernel iterates only the stage's ``k_opts`` *options* — spend
-    offsets ``kb[j]`` (descending) with values ``vb[j]`` — and emits, per
-    output position, the winning option index ``j`` (first maximizer in
-    option order, i.e. the largest spend among ties: the sparse solvers'
-    dict-DP tie-break).  That argmax row is the *backpointer table* the
-    fused device-resident round gathers through instead of unwinding the
-    DP in host Python (DESIGN.md §14).
+    dp, f: [R, NB] (same dtype).  Returns ``(out [R, NB], arg [R, NB])``
+    with ``out[r, b] = max_{k < n_shifts, k <= b} dp[r, b-k] + f[r, k]``
+    and ``arg`` the first maximizing shift in ascending (or, with
+    ``descending``, descending) shift order, -1 where every candidate is
+    -inf.
     """
-    i = pl.program_id(1)
-    b0 = i * block_b
+    r, nb = dp.shape
+    rp, nbp = _round_up(r, _ROWS), _round_up(nb, _LANES)
+    neg = jnp.asarray(-jnp.inf, dp.dtype)
+    pad = ((0, rp - r), (0, nbp - nb))
+    dp_p = jnp.pad(dp, pad, constant_values=neg)
+    f_p = jnp.pad(f.astype(dp.dtype), pad, constant_values=neg)
+    tile = pl.BlockSpec((_ROWS, nbp), lambda i: (i, 0))
+    out, arg = pl.pallas_call(
+        functools.partial(
+            _maxplus_kernel, n_shifts=n_shifts, descending=descending
+        ),
+        grid=(rp // _ROWS,),
+        in_specs=[tile, tile],
+        out_specs=[tile, tile],
+        out_shape=[
+            jax.ShapeDtypeStruct((rp, nbp), dp.dtype),
+            jax.ShapeDtypeStruct((rp, nbp), jnp.int32),
+        ],
+        interpret=interpret,
+    )(dp_p, f_p)
+    return out[:r, :nb], arg[:r, :nb]
 
-    def body(j, carry):
-        acc, arg = carry
-        k = kb_ref[0, j]
-        # per-option contiguous sliding window: dp[b - kb[j]] for the block
-        col = dp_pad_ref[0, pl.dslice(nb + b0 - k, block_b)]
-        vj = vb_ref[0, pl.dslice(j, 1)]  # [1], broadcasts
-        cand = col + vj
-        better = cand > acc
-        acc = jnp.where(better, cand, acc)
-        arg = jnp.where(better, j, arg)
-        return acc, arg
 
-    acc0 = jnp.full((block_b,), -jnp.inf, dtype=out_ref.dtype)
-    arg0 = jnp.zeros((block_b,), dtype=jnp.int32)
-    acc, arg = jax.lax.fori_loop(0, k_opts, body, (acc0, arg0))
-    out_ref[0, ...] = acc
-    arg_ref[0, ...] = arg
-
-
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def maxplus_stage_pallas_batched(
     dp: jax.Array,
     kb: jax.Array,
     vb: jax.Array,
     *,
-    block_b: int = 256,
     interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """Row-batched sparse-option (max,+) stage with backpointers.
 
     dp: [R, NB] float; kb: [R, K] int32 spend offsets in [0, NB]
-    (descending per row); vb: [R, K] option values (pad options with
+    (non-increasing per row); vb: [R, K] option values (pad options with
     ``vb = -inf``, ``kb = 0``).  Returns
 
         out[r, b] = max_j dp[r, b - kb[r, j]] + vb[r, j]
-        arg[r, b] = first maximizing j (int32)
+        arg[r, b] = first maximizing j (int32; 0 where out is -inf)
 
-    with out-of-range gathers (kb[j] > b) reading -inf.  Unlike the dense
-    :func:`maxplus_conv_pallas_batched` this keeps the input dtype
-    (float64 in interpret mode drives the bit-for-bit fused solver path;
-    TPU compiles the same kernel in float32 for the dense paths).
+    with out-of-range gathers (kb[j] > b) reading -inf.  The first
+    maximizer in option order is the largest spend among ties — the
+    sparse solvers' dict-DP tie-break and the backpointer table the fused
+    device-resident round backtracks through (DESIGN.md §14).  Options
+    sharing one spend resolve to the first of maximal value.
+
+    The options are scattered onto a dense per-row curve (max value per
+    spend, plus the option index that holds it) and run through the dense
+    kernel in descending shift order; every candidate is the same single
+    IEEE add ``dp + vb`` as the option loop, so results are bitwise the
+    option-order scan.  Keeps the input dtype (float64 under x64 for the
+    bit-for-bit CPU contract; float32 on the chip).
     """
     if dp.ndim != 2 or kb.shape != vb.shape or kb.shape[0] != dp.shape[0]:
         raise ValueError(
@@ -153,137 +150,78 @@ def maxplus_stage_pallas_batched(
     k_opts = kb.shape[1]
     vb = vb.astype(dp.dtype)
     kb = kb.astype(jnp.int32)
-    nblocks = pl.cdiv(nb, block_b)
-    nb_pad = nblocks * block_b
-    neg = jnp.asarray(-jnp.inf, dp.dtype)
-    # left pad NB (kb <= NB stays in-bounds), right pad to the block multiple
-    dp_pad = jnp.concatenate(
-        [
-            jnp.full((r, nb), neg),
-            dp,
-            jnp.full((r, nb_pad - nb), neg),
-        ],
-        axis=1,
+    rows = jnp.arange(r, dtype=jnp.int32)[:, None]
+    # spends >= NB can never land in out[:, :NB]: drop them from the curve
+    k_in = jnp.where(kb < nb, kb, nb)
+    f = jnp.full((r, nb), -jnp.inf, dp.dtype).at[rows, k_in].max(
+        vb, mode="drop"
     )
-
-    out, arg = pl.pallas_call(
-        functools.partial(
-            _maxplus_stage_kernel_batched, block_b=block_b, nb=nb,
-            k_opts=k_opts,
-        ),
-        grid=(r, nblocks),
-        in_specs=[
-            pl.BlockSpec((1, dp_pad.shape[1]), lambda ri, i: (ri, 0)),
-            pl.BlockSpec((1, k_opts), lambda ri, i: (ri, 0)),
-            pl.BlockSpec((1, k_opts), lambda ri, i: (ri, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_b), lambda ri, i: (ri, i)),
-            pl.BlockSpec((1, block_b), lambda ri, i: (ri, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, nb_pad), dp.dtype),
-            jax.ShapeDtypeStruct((r, nb_pad), jnp.int32),
-        ],
-        interpret=interpret,
-    )(dp_pad, kb, vb)
-    return out[:, :nb], arg[:, :nb]
+    top = vb == f[rows, jnp.minimum(k_in, nb - 1)]
+    jmap = jnp.full((r, nb), k_opts, jnp.int32).at[
+        rows, jnp.where(top, k_in, nb)
+    ].min(
+        jnp.broadcast_to(jnp.arange(k_opts, dtype=jnp.int32), (r, k_opts)),
+        mode="drop",
+    )
+    out, arg_k = _maxplus_tiles(
+        dp, f, n_shifts=nb, descending=True, interpret=interpret
+    )
+    arg = jnp.take_along_axis(jmap, jnp.maximum(arg_k, 0), axis=1)
+    return out, jnp.where(arg_k < 0, 0, arg)
 
 
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
+@functools.partial(jax.jit, static_argnames=("descending", "interpret"))
 def maxplus_conv_pallas_batched(
     dp: jax.Array,
     f: jax.Array,
     *,
-    block_b: int = 256,
+    descending: bool = False,
     interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """Row-batched (max,+) convolution: one kernel launch for R rounds.
 
-    dp, f: [R, NB].  out[r, b] = max_{k<=b} dp[r, b-k] + f[r, k], plus the
-    per-row argmax — each row identical to :func:`maxplus_conv_pallas` on
-    that row alone.  The grid adds a leading row dimension, so R
-    independent DP stages (e.g. all dirty rack leaves of a hierarchical
-    solve) share a single dispatch instead of a vmap of R launches.
+    dp: [R, NB], f: [R, K] with K <= NB.  out[r, b] = max_{k<=b, k<K}
+    dp[r, b-k] + f[r, k], plus the per-row argmax k — the smallest
+    maximizing k, or with ``descending`` the largest (0 where out is
+    -inf).  Each row is identical to :func:`maxplus_conv_pallas` on that
+    row alone: R independent DP stages (e.g. all dirty rack leaves of a
+    hierarchical solve) share a single dispatch.  Keeps a float64 input
+    float64 (the fused round's frontier combine under x64); anything else
+    runs in float32.
     """
-    if dp.ndim != 2 or dp.shape != f.shape:
-        raise ValueError(f"dp/f must be equal-shape 2D, got {dp.shape} {f.shape}")
-    r, nb = dp.shape
-    dp = dp.astype(jnp.float32)
-    f = f.astype(jnp.float32)
-    nblocks = pl.cdiv(nb, block_b)
-    nb_pad = nblocks * block_b
-    neg = jnp.asarray(-jnp.inf, jnp.float32)
-    dp_pad = jnp.concatenate(
-        [
-            jnp.full((r, nb), neg),
-            dp,
-            jnp.full((r, nb_pad - nb), neg),
-        ],
-        axis=1,
+    if dp.ndim != 2 or f.ndim != 2 or f.shape[0] != dp.shape[0]:
+        raise ValueError(f"dp/f must be 2D with equal rows, got {dp.shape} {f.shape}")
+    if f.shape[1] > dp.shape[1]:
+        raise ValueError(f"more shifts than grid points: {f.shape} > {dp.shape}")
+    dtype = jnp.float64 if dp.dtype == jnp.float64 else jnp.float32
+    dp = dp.astype(dtype)
+    n_shifts = f.shape[1]
+    f = jnp.pad(
+        f.astype(dtype), ((0, 0), (0, dp.shape[1] - n_shifts)),
+        constant_values=-jnp.inf,
     )
-
-    out, arg = pl.pallas_call(
-        functools.partial(_maxplus_kernel_batched, block_b=block_b, nb=nb),
-        grid=(r, nblocks),
-        in_specs=[
-            pl.BlockSpec((1, dp_pad.shape[1]), lambda ri, i: (ri, 0)),
-            pl.BlockSpec((1, nb), lambda ri, i: (ri, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_b), lambda ri, i: (ri, i)),
-            pl.BlockSpec((1, block_b), lambda ri, i: (ri, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, nb_pad), jnp.float32),
-            jax.ShapeDtypeStruct((r, nb_pad), jnp.int32),
-        ],
-        interpret=interpret,
-    )(dp_pad, f)
-    return out[:, :nb], arg[:, :nb]
+    out, arg = _maxplus_tiles(
+        dp, f, n_shifts=n_shifts, descending=descending, interpret=interpret
+    )
+    return out, jnp.maximum(arg, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def maxplus_conv_pallas(
     dp: jax.Array,
     f: jax.Array,
     *,
-    block_b: int = 256,
     interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """out[b] = max_{k<=b} dp[b-k] + f[k]; also returns argmax k (int32).
 
     dp, f: [NB] float32.  ``interpret=True`` runs the kernel body on CPU
-    (the validation mode in this container); on a real TPU pass False.
+    (the validation mode off the chip); on a TPU pass False.
     """
     if dp.ndim != 1 or dp.shape != f.shape:
         raise ValueError(f"dp/f must be equal-length 1D, got {dp.shape} {f.shape}")
-    nb = dp.shape[0]
-    dp = dp.astype(jnp.float32)
-    f = f.astype(jnp.float32)
-    nblocks = pl.cdiv(nb, block_b)
-    nb_pad = nblocks * block_b
-    neg = jnp.asarray(-jnp.inf, jnp.float32)
-    # left pad NB (validity masking), right pad to the block multiple
-    dp_pad = jnp.concatenate(
-        [jnp.full((nb,), neg), dp, jnp.full((nb_pad - nb,), neg)]
-    )
-
-    out, arg = pl.pallas_call(
-        functools.partial(_maxplus_kernel, block_b=block_b, nb=nb),
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec(dp_pad.shape, lambda i: (0,)),  # whole padded dp in VMEM
-            pl.BlockSpec(f.shape, lambda i: (0,)),  # whole f in VMEM
-        ],
-        out_specs=[
-            pl.BlockSpec((block_b,), lambda i: (i,)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((nb_pad,), jnp.int32),
-        ],
+    out, arg = maxplus_conv_pallas_batched(
+        dp.astype(jnp.float32)[None], f.astype(jnp.float32)[None],
         interpret=interpret,
-    )(dp_pad, f)
-    return out[:nb], arg[:nb]
+    )
+    return out[0], arg[0]

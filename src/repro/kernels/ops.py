@@ -1,22 +1,71 @@
 """jit'd public wrappers for the Pallas kernels.
 
-Every op auto-selects ``interpret=True`` off-TPU (this container is
-CPU-only; interpret mode executes the kernel bodies with JAX semantics) and
-compiles natively on TPU.  Reference semantics live in ``repro.kernels.ref``.
+Every op runs its kernel in interpret mode off a TPU (interpret mode
+executes the kernel bodies with JAX semantics on the CPU) and compiles it
+natively with Mosaic on a TPU — :func:`on_tpu` is the one place that
+choice is made.  Reference semantics live in ``repro.kernels.ref``.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import os
 
 import jax
+import numpy as np
 
 from repro.kernels import mckp_dp as _mckp_dp
 
 
 @functools.cache
-def _on_tpu() -> bool:
+def on_tpu() -> bool:
+    """Whether JAX's default backend is a TPU.
+
+    The device choice of every kernel wrapper: Pallas kernels compile
+    natively there and run interpreted everywhere else."""
     return jax.default_backend() == "tpu"
+
+
+def device_value_dtype() -> type:
+    """Dtype of the float values the device-resident paths keep.
+
+    float64 where the backend provides it (the CPU: the fused round is
+    then bit-for-bit the host's float64 DP); float32 on a TPU, whose
+    kernels take no float64 (DESIGN.md §14 states the bound that holds
+    there)."""
+    return np.float32 if on_tpu() else np.float64
+
+
+def device_value_scope():
+    """Context for building and running the device-resident paths: x64
+    on where values ride float64, and no x64 scope at all on a TPU."""
+    if device_value_dtype() == np.float64:
+        return jax.enable_x64(True)
+    return contextlib.nullcontext()
+
+
+#: fixed persistent-compile-cache directory (git-ignored) at the checkout root
+_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..", ".jax_cache"
+)
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Entry points (``chip_smoke.py``, the benchmarks, the tools and the
+    examples) call this before their first compile.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already uses it and nothing
+    is changed; otherwise the cache lives in one fixed directory at the
+    checkout root, so every process of a checkout shares it (a moving
+    path would never hit)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.normpath(_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 @functools.cache
@@ -31,7 +80,6 @@ def leaf_shard_mesh(n_devices: int):
     reduces the gathered per-device partials (DESIGN.md §16).  Multi-host
     CPU smoke rides ``XLA_FLAGS=--xla_force_host_platform_device_count``.
     """
-    import numpy as np
     from jax.sharding import Mesh
 
     return Mesh(np.asarray(jax.devices()[:n_devices]), ("leaves",))
@@ -75,14 +123,12 @@ def bank_compact(kb_old, vb_old, src_s, src_l, *, k_pad: int):
     return jnp.where(m, kb_g, kb_id), jnp.where(m, vb_g, vb_id)
 
 
-def maxplus_conv(dp: jax.Array, f: jax.Array, *, block_b: int = 256):
+def maxplus_conv(dp: jax.Array, f: jax.Array):
     """(max,+)-convolution DP stage.  Returns (out, argmax_k)."""
-    return _mckp_dp.maxplus_conv_pallas(
-        dp, f, block_b=block_b, interpret=not _on_tpu()
-    )
+    return _mckp_dp.maxplus_conv_pallas(dp, f, interpret=not on_tpu())
 
 
-def maxplus_conv_batched(dp: jax.Array, f: jax.Array, *, block_b: int = 256):
+def maxplus_conv_batched(dp: jax.Array, f: jax.Array):
     """Batched (max,+) stage: one row-batched Pallas launch.
 
     dp, f: [R, NB].  Returns (out [R, NB], argmax_k [R, NB]) — each row
@@ -92,13 +138,11 @@ def maxplus_conv_batched(dp: jax.Array, f: jax.Array, *, block_b: int = 256):
     hierarchical leaf solve runs through this to advance many independent
     DPs in a single dispatch.
     """
-    return _mckp_dp.maxplus_conv_pallas_batched(
-        dp, f, block_b=block_b, interpret=not _on_tpu()
-    )
+    return _mckp_dp.maxplus_conv_pallas_batched(dp, f, interpret=not on_tpu())
 
 
 @functools.cache
-def _maxplus_scan_batched_fn(block_b: int, interpret: bool):
+def _maxplus_scan_batched_fn(interpret: bool):
     import jax
     import jax.numpy as jnp
 
@@ -111,7 +155,7 @@ def _maxplus_scan_batched_fn(block_b: int, interpret: bool):
         def stage(dp, gid_col):  # dp: [L, NB]; gid_col: [L]
             rows = f_groups[rows_idx, gid_col]
             out, arg = _mckp_dp.maxplus_conv_pallas_batched(
-                dp, rows, block_b=block_b, interpret=interpret
+                dp, rows, interpret=interpret
             )
             return out, arg
 
@@ -124,7 +168,7 @@ def _maxplus_scan_batched_fn(block_b: int, interpret: bool):
     return run
 
 
-def maxplus_scan_batched(f_groups, stage_gids, *, block_b: int = 256):
+def maxplus_scan_batched(f_groups, stage_gids):
     """Ragged batched repeated-stage (max,+) DP scan over many leaves.
 
     f_groups: [L, G, NB] per-leaf class curve banks (leaves padded to a
@@ -140,11 +184,11 @@ def maxplus_scan_batched(f_groups, stage_gids, *, block_b: int = 256):
     """
     import jax.numpy as jnp
 
-    run = _maxplus_scan_batched_fn(block_b, not _on_tpu())
+    run = _maxplus_scan_batched_fn(not on_tpu())
     return run(f_groups, jnp.asarray(stage_gids))
 
 
-def maxplus_scan(f_groups, stage_gids, *, block_b: int = 256):
+def maxplus_scan(f_groups, stage_gids):
     """Repeated-stage (max,+) DP scan over a group-id sequence.
 
     f_groups: [G, NB] per-behaviour-class dense curves; stage_gids: [N]
@@ -162,13 +206,11 @@ def maxplus_scan(f_groups, stage_gids, *, block_b: int = 256):
     import jax.numpy as jnp
 
     gids = jnp.asarray(stage_gids)
-    dp_final, args = maxplus_scan_batched(
-        f_groups[None], gids[None], block_b=block_b
-    )
+    dp_final, args = maxplus_scan_batched(f_groups[None], gids[None])
     return dp_final[0], args[0]
 
 
-def maxplus_stage_batched(dp, kb, vb, *, block_b: int = 256):
+def maxplus_stage_batched(dp, kb, vb):
     """Sparse-option (max,+) stage with backpointer output.
 
     dp: [R, NB]; kb: [R, K] int32 descending spend offsets; vb: [R, K]
@@ -179,7 +221,7 @@ def maxplus_stage_batched(dp, kb, vb, *, block_b: int = 256):
     fused solver path).
     """
     return _mckp_dp.maxplus_stage_pallas_batched(
-        dp, kb, vb, block_b=block_b, interpret=not _on_tpu()
+        dp, kb, vb, interpret=not on_tpu()
     )
 
 
@@ -187,7 +229,7 @@ def flash_attention(q, k, v, **kw):
     """Fused GQA attention (train/prefill).  See flash_attention.py."""
     from repro.kernels import flash_attention as _fa
 
-    return _fa.flash_attention(q, k, v, interpret=not _on_tpu(), **kw)
+    return _fa.flash_attention(q, k, v, interpret=not on_tpu(), **kw)
 
 
 def decode_attention(q, k_cache, v_cache, lengths, **kw):
@@ -195,7 +237,7 @@ def decode_attention(q, k_cache, v_cache, lengths, **kw):
     from repro.kernels import decode_attention as _da
 
     return _da.decode_attention(
-        q, k_cache, v_cache, lengths, interpret=not _on_tpu(), **kw
+        q, k_cache, v_cache, lengths, interpret=not on_tpu(), **kw
     )
 
 
@@ -203,4 +245,4 @@ def rmsnorm(x, scale, *, eps: float = 1e-6):
     """Fused RMSNorm."""
     from repro.kernels import rmsnorm as _rn
 
-    return _rn.rmsnorm(x, scale, eps=eps, interpret=not _on_tpu())
+    return _rn.rmsnorm(x, scale, eps=eps, interpret=not on_tpu())

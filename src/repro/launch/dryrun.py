@@ -1,10 +1,12 @@
 import os
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 "
-    + os.environ.get("XLA_FLAGS", "")
-).strip()
+if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=512 "
+        + os.environ.get("XLA_FLAGS", "")
+    ).strip()
 # ^ MUST precede every other import (jax locks the device count on first
-#   init).  The 512 placeholder host devices exist ONLY for this dry-run.
+#   init).  The 512 placeholder host devices exist ONLY for this dry-run:
+#   importing a helper from this module leaves the process's devices alone.
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 

@@ -87,6 +87,14 @@ assert sb is not None, fstate.stats["fallback_reason"]
 assert fstate.stats["fallbacks"] == 0, fstate.stats
 assert fstate.stats["rebuilds"] == 1, fstate.stats  # cold start only
 assert fstate.stats["compactions"] >= 1, fstate.stats
+# the resident banks really spread leaf-wise over all four devices (after
+# a cold upload, a compaction and row patches) instead of piling up on one
+for bank in (fstate.kb_dev, fstate.vb_dev):
+    shards_ = bank.addressable_shards
+    assert len({sh.device for sh in shards_}) == 4, bank.sharding
+    assert all(
+        sh.data.shape[1] * 4 == bank.shape[1] for sh in shards_
+    ), [sh.data.shape for sh in shards_]
 hb = mckp.solve_hierarchical(root_b, budget)
 assert sb.picks == hb.picks
 assert sb.total_value == hb.total_value

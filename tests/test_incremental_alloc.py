@@ -901,3 +901,26 @@ class TestDeviceView:
         view = sim.table.device_view()
         assert str(view.caps.dtype) == "float64"
         assert str(view.slowdown.dtype) == "float64"
+
+    def test_float32_residency_on_chip_dtype(self, suite, monkeypatch):
+        """With the TPU's device dtype the float columns mirror the table
+        in float32 — through the full upload, the extend and the patch."""
+        from repro.kernels import ops
+
+        monkeypatch.setattr(ops, "device_value_dtype", lambda: np.float32)
+        system, apps, surfs = suite
+        sim = ClusterSim.build(system, apps, surfs, n_nodes=8, seed=0)
+        sim.table.device_view()
+        sim.apply_events([
+            sc.NodeArrival(round=1, app=apps[0], caps=(150.0, 150.0)),
+            sc.StragglerOnset(round=1, node_id=3, slowdown=1.3),
+        ])
+        view = sim.table.device_view()
+        assert view.extends == 1
+        for col in ("caps", "slowdown"):
+            got = getattr(view, col)
+            assert str(got.dtype) == "float32"
+            np.testing.assert_array_equal(
+                np.asarray(got), getattr(sim.table, col).astype(np.float32)
+            )
+        assert str(view.domain_id.dtype) == "int32"

@@ -1,7 +1,5 @@
 """Pallas kernels vs pure-jnp oracles (interpret mode), shape/dtype sweeps."""
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,15 +25,25 @@ def _tol(dtype):
 
 class TestMaxPlus:
     @pytest.mark.parametrize("nb", [17, 64, 200, 513])
-    @pytest.mark.parametrize("block_b", [32, 128])
-    def test_matches_ref(self, nb, block_b):
-        rng = np.random.default_rng(nb + block_b)
-        dp = jnp.asarray(np.maximum.accumulate(rng.uniform(0, 1, nb)), jnp.float32)
-        f = jnp.asarray(np.maximum.accumulate(rng.uniform(0, 1, nb)), jnp.float32)
-        out_p, arg_p = mckp_dp.maxplus_conv_pallas(dp, f, block_b=block_b)
-        out_r, arg_r = ref.maxplus_conv(dp, f)
-        np.testing.assert_allclose(out_p, out_r, rtol=1e-6)
-        np.testing.assert_array_equal(np.asarray(arg_p), np.asarray(arg_r))
+    @pytest.mark.parametrize("rows", [1, 11])
+    def test_matches_ref(self, nb, rows):
+        """Single-row kernel, and every row of a batch spanning two
+        8-row tiles, against the jnp oracle."""
+        rng = np.random.default_rng(nb + rows)
+        dp = np.maximum.accumulate(rng.uniform(0, 1, (rows, nb)), axis=1)
+        f = np.maximum.accumulate(rng.uniform(0, 1, (rows, nb)), axis=1)
+        dp, f = jnp.asarray(dp, jnp.float32), jnp.asarray(f, jnp.float32)
+        if rows == 1:
+            out_p, arg_p = mckp_dp.maxplus_conv_pallas(dp[0], f[0])
+            out_p, arg_p = out_p[None], arg_p[None]
+        else:
+            out_p, arg_p = mckp_dp.maxplus_conv_pallas_batched(dp, f)
+        for r in range(rows):
+            out_r, arg_r = ref.maxplus_conv(dp[r], f[r])
+            np.testing.assert_allclose(out_p[r], out_r, rtol=1e-6)
+            np.testing.assert_array_equal(
+                np.asarray(arg_p[r]), np.asarray(arg_r)
+            )
 
     def test_monotone_inputs_monotone_output(self):
         rng = np.random.default_rng(0)
@@ -198,13 +206,14 @@ def _stage_inputs(rng, r, nb, k, dtype):
 
 class TestMaxPlusStageBatched:
     @pytest.mark.parametrize("r,nb,k", [(1, 16, 3), (4, 64, 8), (3, 200, 21)])
-    @pytest.mark.parametrize("block_b", [32, 256])
-    def test_matches_scalar_ref(self, r, nb, k, block_b):
+    @pytest.mark.parametrize("tiles", [1, 3])
+    def test_matches_scalar_ref(self, r, nb, k, tiles):
+        """``tiles=3`` stacks rows past one 8-row kernel tile."""
+        r = r * tiles
         rng = np.random.default_rng(r * 1000 + nb + k)
         dp, kb, vb = _stage_inputs(rng, r, nb, k, np.float32)
         out, arg = mckp_dp.maxplus_stage_pallas_batched(
             jnp.asarray(dp), jnp.asarray(kb), jnp.asarray(vb),
-            block_b=block_b,
         )
         out_r, arg_r = _stage_ref_np(dp, kb, vb)
         np.testing.assert_array_equal(np.asarray(out), out_r)
@@ -214,11 +223,10 @@ class TestMaxPlusStageBatched:
         """f64 inputs (the fused solver path) reproduce the host adds
         bit-for-bit — same IEEE ops in the same order."""
         rng = np.random.default_rng(7)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             dp, kb, vb = _stage_inputs(rng, 5, 96, 12, np.float64)
             out, arg = mckp_dp.maxplus_stage_pallas_batched(
                 jnp.asarray(dp), jnp.asarray(kb), jnp.asarray(vb),
-                block_b=64,
             )
             assert out.dtype == jnp.float64
             out_r, arg_r = _stage_ref_np(dp, kb, vb)
@@ -233,10 +241,8 @@ class TestMaxPlusStageBatched:
         rng = np.random.default_rng(11)
         dp, kb, vb = _stage_inputs(rng, 4, 80, 9, np.float32)
         args = (jnp.asarray(dp), jnp.asarray(kb), jnp.asarray(vb))
-        out_d, arg_d = mckp_dp.maxplus_stage_pallas_batched(*args, block_b=32)
-        jitted = jax.jit(
-            functools.partial(mckp_dp.maxplus_stage_pallas_batched, block_b=32)
-        )
+        out_d, arg_d = mckp_dp.maxplus_stage_pallas_batched(*args)
+        jitted = jax.jit(mckp_dp.maxplus_stage_pallas_batched)
         out_j, arg_j = jitted(*args)
         np.testing.assert_array_equal(np.asarray(out_d), np.asarray(out_j))
         np.testing.assert_array_equal(np.asarray(arg_d), np.asarray(arg_j))
@@ -247,7 +253,7 @@ class TestMaxPlusStageBatched:
         dp = jnp.asarray(np.zeros((1, 8), np.float32))
         kb = jnp.asarray(np.array([[2, 2, 0]], np.int32))
         vb = jnp.asarray(np.array([[0.5, 0.5, 0.1]], np.float32))
-        out, arg = mckp_dp.maxplus_stage_pallas_batched(dp, kb, vb, block_b=8)
+        out, arg = mckp_dp.maxplus_stage_pallas_batched(dp, kb, vb)
         np.testing.assert_array_equal(
             np.asarray(arg)[0], [2, 2, 0, 0, 0, 0, 0, 0]
         )
@@ -300,3 +306,34 @@ def test_maxplus_property(nb, seed):
     ks = np.asarray(arg)
     bs = np.arange(nb)
     np.testing.assert_allclose(out, dp_n[bs - ks] + f_n[ks], rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# Persistent compile cache placement (entry points call use_compile_cache)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """``$JAX_COMPILATION_CACHE_DIR`` wins untouched; otherwise one fixed,
+    git-ignored directory at the checkout root."""
+    from pathlib import Path
+
+    from repro.kernels import ops
+
+    root = Path(__file__).resolve().parents[1]
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        got = ops.use_compile_cache()
+        now = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    if env_dir is None:
+        assert got == str(root / ".jax_cache") == now
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    else:
+        assert got == env_dir and now == before

@@ -10,8 +10,8 @@ from repro.launch import sharding as shr
 from repro.launch import steps as steps_mod
 from repro.models.model import Model
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH_MP = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class TestParamSpecs:

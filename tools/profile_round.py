@@ -55,6 +55,7 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmarks.common import get_suite  # noqa: E402
+from repro.kernels.ops import use_compile_cache  # noqa: E402
 from benchmarks.incremental_alloc import (  # noqa: E402
     _budget,
     _churn_events,
@@ -98,6 +99,7 @@ def _level_summary(sim, topo) -> list[dict]:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--nodes", type=int, default=10000)
     ap.add_argument("--racks", type=int, default=16,
