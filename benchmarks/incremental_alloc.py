@@ -236,6 +236,14 @@ def _fused_sync(ctrl) -> None:
             jax.block_until_ready(buf)
 
 
+def _dispatch_s(ctrl) -> float:
+    """Seconds of the last round's pipeline call, launch and wait (0.0
+    when the round was not served fused)."""
+    if ctrl.last_solver != "fused":
+        return 0.0
+    return float(ctrl.fused_segments()["dispatch_s"])
+
+
 def _measure_fused_case(
     system, apps, surfs, n: int, *, topology, policy: str,
 ) -> dict:
@@ -275,9 +283,7 @@ def _measure_fused_case(
             round_ts[label].append(time.perf_counter() - t0)
             alloc_ts[label].append(float(sim.last_round_profile["allocate_s"]))
             if label == "fused":
-                device_ts.append(
-                    float(sim.last_round_profile["alloc_device_s"])
-                )
+                device_ts.append(_dispatch_s(ctrl))
             allocs[label].append(
                 (dict(res.allocation.caps), res.allocation.spent)
             )
@@ -389,9 +395,7 @@ def _measure_fused_churn_case(
             alloc_ts[label].append(float(sim.last_round_profile["allocate_s"]))
             if label == "fused":
                 fused_ctrl = ctrl
-                device_ts.append(
-                    float(sim.last_round_profile["alloc_device_s"])
-                )
+                device_ts.append(_dispatch_s(ctrl))
             got.append((dict(res.allocation.caps), res.allocation.spent))
         for (label, *_), other in zip(results[1:], got[1:]):
             assert got[0] == other, (

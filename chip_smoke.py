@@ -133,7 +133,10 @@ def smoke_rounds(n_nodes: int, n_racks: int, churn: float) -> dict:
             "budget_w": b,
             "solver": fctrl.last_solver,
             "fused_alloc_s": prof_f["allocate_s"],
-            "fused_device_s": prof_f["alloc_device_s"],
+            "fused_device_s": (
+                fctrl.fused_segments()["dispatch_s"]
+                if fctrl.last_solver == "fused" else 0.0
+            ),
             "host_alloc_s": prof_h["allocate_s"],
             "spent_w": fused[3].allocation.spent,
             **chk,

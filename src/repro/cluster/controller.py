@@ -42,6 +42,7 @@ import numpy as np
 from repro.core import curves, mckp
 from repro.core import policies as policies_mod
 from repro.core.curves import OptionTable
+from repro.core.spans import Span
 from repro.core.surfaces import PowerSurface
 from repro.core.types import (
     Allocation,
@@ -761,9 +762,6 @@ class EcoShiftController(_OptionCachingController):
         #: fused, wasn't attempted, or hit the alloc cache) — mirrors
         #: ``FusedRoundStats.fallback_reason``
         self.last_fallback_reason: str = ""
-        #: device seconds spent inside the last fused pipeline call (0.0
-        #: for host rounds and alloc-cache hits)
-        self.last_device_s: float = 0.0
         #: receding-horizon planning (DESIGN.md §15): plan length, weighted
         #: spend fraction, and DP bounds — planning is active only when
         #: horizon > 1 AND eco_factor < 1 AND the engine fed an outlook
@@ -887,27 +885,12 @@ class EcoShiftController(_OptionCachingController):
         return FusedRoundStats(**self._fused_state.stats)
 
     def fused_segments(self) -> dict:
-        """Last fused round's wall-clock split (seconds): prep_s /
-        patch_s / compact_s / dispatch_s / backtrack_s / assembly_s —
-        the attribution table behind ``tools/profile_round.py --churn``.
+        """Last fused round's split (seconds): prep_s / patch_s /
+        compact_s / dispatch_s / backtrack_s / assembly_s, the durations
+        of its ``fused.*`` spans (``dispatch_s`` = launch + wait) — the
+        attribution table behind ``tools/profile_round.py --churn``.
         Empty until a fused round has been attempted."""
         return dict(self._fused_state.last_segments)
-
-    def _try_fused_grouped(self, groups, budget) -> mckp.MCKPSolution | None:
-        """One fused-round attempt; returns None to use the host path."""
-        fstate = self._fused_state
-        d0 = fstate.stats["device_s"]
-        sol = mckp.solve_grouped_fused(
-            groups,
-            budget,
-            fstate=fstate,
-            curve_cache=self._agg_curves,
-            pick_cache=self._pick_cache,
-            plan_cache=self._plan_cache,
-            chain_cache=self._chain_cache,
-        )
-        self.last_device_s = fstate.stats["device_s"] - d0
-        return sol
 
     @property
     def supports_grouped(self) -> bool:  # type: ignore[override]
@@ -959,54 +942,65 @@ class EcoShiftController(_OptionCachingController):
             and self.solver == "sparse"
             and getattr(batch, "seq", 0) != 0
         )
-        if incremental:
-            self._incremental_groups(batch)
-            groups = self._grouping.groups(0)
-        else:
-            groups = self._grouped_options_for(batch)
+        with Span("controller.grouping_sync"):
+            if incremental:
+                self._incremental_groups(batch)
+                groups = self._grouping.groups(0)
+            else:
+                groups = self._grouped_options_for(batch)
         if self._plan_pending():
-            budget = self._plan_budget(
-                budget, lambda cap: self._planning_frontier(groups, cap)
-            )
+            with Span("controller.plan_budget"):
+                budget = self._plan_budget(
+                    budget, lambda cap: self._planning_frontier(groups, cap)
+                )
+        key = None
         if incremental:
-            key = (
-                tuple(sorted(mckp._group_token(g) for g in groups)),
-                mckp._qkey(budget),
-            )
-            hit = self._alloc_cache.get(key)
+            with Span("controller.cache_key"):
+                key = (
+                    tuple(sorted(mckp._group_token(g) for g in groups)),
+                    mckp._qkey(budget),
+                )
+                hit = self._alloc_cache.get(key)
             if hit is not None:
                 self.last_solver = "cache"
-                self.last_device_s = 0.0
                 self.last_fallback_reason = ""
                 return hit
-        else:
-            key = None
         sol = None
-        self.last_device_s = 0.0
         self.last_fallback_reason = ""
         if incremental and self.fused:
-            sol = self._try_fused_grouped(groups, budget)
+            with Span("controller.fused_specs"):
+                sol = mckp.solve_grouped_fused(
+                    groups,
+                    budget,
+                    fstate=self._fused_state,
+                    curve_cache=self._agg_curves,
+                    pick_cache=self._pick_cache,
+                    plan_cache=self._plan_cache,
+                    chain_cache=self._chain_cache,
+                )
             if sol is None:
                 self.last_fallback_reason = self._fused_state.stats.get(
                     "fallback_reason", ""
                 )
         self.last_solver = "fused" if sol is not None else "host"
         if sol is None:
-            sol = mckp.solve_grouped(
-                groups,
-                budget,
-                solver=self.solver,
-                unit=self.unit,
-                curve_cache=self._agg_curves,
-                pick_cache=self._pick_cache if incremental else None,
-                plan_cache=self._plan_cache if incremental else None,
-                chain_cache=self._chain_cache if incremental else None,
+            with Span("controller.host_solve"):
+                sol = mckp.solve_grouped(
+                    groups,
+                    budget,
+                    solver=self.solver,
+                    unit=self.unit,
+                    curve_cache=self._agg_curves,
+                    pick_cache=self._pick_cache if incremental else None,
+                    plan_cache=self._plan_cache if incremental else None,
+                    chain_cache=self._chain_cache if incremental else None,
+                )
+        with Span("controller.allocation"):
+            alloc = policies_mod.allocation_from_solution(
+                sol, batch.baselines_map(), budget, self.system.grid
             )
-        alloc = policies_mod.allocation_from_solution(
-            sol, batch.baselines_map(), budget, self.system.grid
-        )
-        if key is not None:
-            self._alloc_cache[key] = alloc
+            if key is not None:
+                self._alloc_cache[key] = alloc
         return alloc
 
     def allocate_batch(
@@ -1190,7 +1184,8 @@ class EcoShiftHierController(EcoShiftController):
             raise ValueError("ecoshift_hier needs a bound PowerTopology")
         if batch.domain_ids is None:
             raise ValueError("receiver batch carries no domain ids")
-        batch = self._served_batch(batch)
+        with Span("controller.serve_batch"):
+            batch = self._served_batch(batch)
         if not _skip_pins and self._pins:
             pins = self._active_pins()
             present = set(batch.names)
@@ -1207,75 +1202,78 @@ class EcoShiftHierController(EcoShiftController):
         )
         state = None
         key = None
-        if incremental:
-            self._incremental_groups(
-                batch, leaf_ids=np.asarray(batch.domain_ids)
-            )
-            by_leaf = self._grouping.by_scope()
-            state = self._hier_state
-        else:
-            by_leaf = self._grouped_options_by_leaf(batch)
+        with Span("controller.grouping_sync"):
+            if incremental:
+                self._incremental_groups(
+                    batch, leaf_ids=np.asarray(batch.domain_ids)
+                )
+                by_leaf = self._grouping.by_scope()
+                state = self._hier_state
+            else:
+                by_leaf = self._grouped_options_by_leaf(batch)
         root = None
         if self._plan_pending():
             # the root frontier under the quantized cutoff serves every
             # horizon cap; the primed leaf frontiers and tree combines are
             # the same warm HierState entries the solve below reuses
-            root = policies_mod.domain_tree(self.topology, domain_extra, by_leaf)
-            budget = self._plan_budget(
-                budget,
-                lambda cap: mckp.hierarchical_frontier(
-                    root, cap, state=self._hier_state
-                ),
-            )
+            with Span("controller.plan_budget"):
+                root = policies_mod.domain_tree(self.topology, domain_extra, by_leaf)
+                budget = self._plan_budget(
+                    budget,
+                    lambda cap: mckp.hierarchical_frontier(
+                        root, cap, state=self._hier_state
+                    ),
+                )
         if incremental:
-            key = (
-                tuple(
-                    (leaf, tuple(sorted(mckp._group_token(g) for g in groups)))
-                    for leaf, groups in sorted(by_leaf.items())
-                ),
-                mckp._qkey(budget),
-                np.asarray(domain_extra).tobytes(),
-            )
-            hit = self._alloc_cache.get(key)
+            with Span("controller.cache_key"):
+                key = (
+                    tuple(
+                        (leaf, tuple(sorted(mckp._group_token(g) for g in groups)))
+                        for leaf, groups in sorted(by_leaf.items())
+                    ),
+                    mckp._qkey(budget),
+                    np.asarray(domain_extra).tobytes(),
+                )
+                hit = self._alloc_cache.get(key)
             if hit is not None:
                 self.last_domain_spent = hit[1]
                 self.last_solver = "cache"
-                self.last_device_s = 0.0
                 self.last_fallback_reason = ""
                 return hit[0]
         if root is None:
-            root = policies_mod.domain_tree(self.topology, domain_extra, by_leaf)
+            with Span("controller.domain_tree"):
+                root = policies_mod.domain_tree(self.topology, domain_extra, by_leaf)
         sol = None
-        self.last_device_s = 0.0
         self.last_fallback_reason = ""
         if incremental and self.fused:
             fstate = self._fused_state
-            d0 = fstate.stats["device_s"]
-            sol = mckp.solve_hierarchical_fused(
-                root, budget, state=self._hier_state, fstate=fstate
-            )
-            self.last_device_s = fstate.stats["device_s"] - d0
+            with Span("controller.fused_specs"):
+                sol = mckp.solve_hierarchical_fused(
+                    root, budget, state=self._hier_state, fstate=fstate
+                )
             if sol is None:
                 self.last_fallback_reason = fstate.stats.get(
                     "fallback_reason", ""
                 )
         self.last_solver = "fused" if sol is not None else "host"
         if sol is None:
-            sol = mckp.solve_hierarchical(
-                root,
-                budget,
-                solver=self.solver,
-                unit=self.unit,
-                curve_cache=self._agg_curves,
-                frontier_cache=self._frontiers,
-                state=state,
-            )
+            with Span("controller.host_solve"):
+                sol = mckp.solve_hierarchical(
+                    root,
+                    budget,
+                    solver=self.solver,
+                    unit=self.unit,
+                    curve_cache=self._agg_curves,
+                    frontier_cache=self._frontiers,
+                    state=state,
+                )
         self.last_domain_spent = sol.domain_spent
-        alloc = policies_mod.allocation_from_solution(
-            sol, batch.baselines_map(), budget, self.system.grid
-        )
-        if key is not None:
-            self._alloc_cache[key] = (alloc, sol.domain_spent)
+        with Span("controller.allocation"):
+            alloc = policies_mod.allocation_from_solution(
+                sol, batch.baselines_map(), budget, self.system.grid
+            )
+            if key is not None:
+                self._alloc_cache[key] = (alloc, sol.domain_spent)
         return alloc
 
     def ingest_telemetry(self, records) -> None:

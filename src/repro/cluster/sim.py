@@ -50,7 +50,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import itertools
-import time as _time
 import zlib
 from typing import Callable, Mapping, Sequence
 
@@ -60,6 +59,7 @@ from repro.cluster import budget as budget_mod
 from repro.cluster import scenario as scenario_mod
 from repro.cluster.predictor import TelemetryBatch
 from repro.cluster.scenario import Scenario
+from repro.core.spans import Span
 from repro.core.surfaces import PowerSurface, measured_runtime
 from repro.core.types import (
     Allocation,
@@ -554,8 +554,9 @@ class ClusterSim:
         #: conservation check and measurement share one gather, and a
         #: cache-hit allocation skips it entirely
         self._alloc_caps_cache: tuple | None = None
-        #: per-phase wall-clock of the latest run_round plus the fused
-        #: split: alloc_device_s / alloc_solver (tools/profile_round)
+        #: per-phase seconds of the latest run_round (``partition_s`` ..
+        #: ``measure_s``, the durations of its engine spans) and the
+        #: solver that served it (tools/profile_round)
         self.last_round_profile: dict[str, float | str] = {}
         #: telemetry emitted by the latest vectorized-measurement round
         self.last_telemetry: object = ()
@@ -832,96 +833,97 @@ class ClusterSim:
         later events see earlier ones), replacing the legacy one-O(n)-
         list-rebuild-per-event path; returns affected instance names.
         """
-        t = self.table
-        touched: list[str] = []
-        dirty: list[np.ndarray] = []
-        for event in events:
-            if isinstance(event, scenario_mod.NodeFailure):
-                rows = np.flatnonzero(
-                    np.isin(t.node_ids, np.asarray(event.node_ids))
-                )
-                touched.extend(t.names[r] for r in rows)
-                t.alive[rows] = False
-                dirty.append(rows)
-            elif isinstance(event, scenario_mod.StragglerOnset):
-                rows = np.flatnonzero(t.node_ids == event.node_id)
-                t.slowdown[rows] = event.slowdown
-                touched.extend(t.names[r] for r in rows)
-                dirty.append(rows)
-            elif isinstance(event, scenario_mod.PhaseChange):
-                if event.surface_id not in self.surfaces:
-                    raise KeyError(f"unknown surface {event.surface_id!r}")
-                rows = np.flatnonzero(t.node_ids == event.node_id)
-                gid = np.int32(t.interner.intern(event.surface_id))
-                # rebind the instance's surface identity too, so
-                # predictor-backed controllers resolve the new phase
-                t.base_gid[rows] = gid
-                t.sid_gid[rows] = gid
-                touched.extend(t.names[r] for r in rows)
-                dirty.append(rows)
-            elif isinstance(event, scenario_mod.NodeArrival):
-                if event.surface is not None:
-                    # a genuinely new app: register its ground-truth surface
-                    self.surfaces = {
-                        **self.surfaces, event.app.name: event.surface
-                    }
-                if event.app.name not in self.surfaces:
-                    raise KeyError(
-                        f"no surface for arriving app {event.app.name!r}"
+        with Span("engine.apply_events"):
+            t = self.table
+            touched: list[str] = []
+            dirty: list[np.ndarray] = []
+            for event in events:
+                if isinstance(event, scenario_mod.NodeFailure):
+                    rows = np.flatnonzero(
+                        np.isin(t.node_ids, np.asarray(event.node_ids))
                     )
-                nid = t.next_node_id()
-                domain_id = -1
-                if self.topology is not None:
-                    if event.domain is not None:
-                        domain_id = self.topology.require_leaf(event.domain)
-                    else:
-                        # the assigned id must fall inside some leaf range
-                        try:
-                            domain_id = int(self.topology.leaf_of([nid])[0])
-                        except ValueError:
-                            raise ValueError(
-                                f"arrival of {event.app.name!r} at round "
-                                f"{event.round} got node id {nid}, which no "
-                                f"leaf domain owns — pass "
-                                f"NodeArrival(domain=...) to place it"
-                            ) from None
-                caps = event.caps or (self.system.init_cpu, self.system.init_gpu)
-                t.append(
-                    node_id=nid,
-                    name=f"{event.app.name}#n{nid}",
-                    base_app=event.app.name,
-                    surface_id=event.app.surface_id,
-                    sclass=event.app.sclass,
-                    caps=caps,
-                    domain_id=domain_id,
-                )
-                dirty.append(np.array([len(t) - 1], dtype=np.int64))
-            elif isinstance(event, scenario_mod.DomainCapChange):
-                if self.topology is None:
-                    raise ValueError(
-                        "DomainCapChange requires an attached PowerTopology"
+                    touched.extend(t.names[r] for r in rows)
+                    t.alive[rows] = False
+                    dirty.append(rows)
+                elif isinstance(event, scenario_mod.StragglerOnset):
+                    rows = np.flatnonzero(t.node_ids == event.node_id)
+                    t.slowdown[rows] = event.slowdown
+                    touched.extend(t.names[r] for r in rows)
+                    dirty.append(rows)
+                elif isinstance(event, scenario_mod.PhaseChange):
+                    if event.surface_id not in self.surfaces:
+                        raise KeyError(f"unknown surface {event.surface_id!r}")
+                    rows = np.flatnonzero(t.node_ids == event.node_id)
+                    gid = np.int32(t.interner.intern(event.surface_id))
+                    # rebind the instance's surface identity too, so
+                    # predictor-backed controllers resolve the new phase
+                    t.base_gid[rows] = gid
+                    t.sid_gid[rows] = gid
+                    touched.extend(t.names[r] for r in rows)
+                    dirty.append(rows)
+                elif isinstance(event, scenario_mod.NodeArrival):
+                    if event.surface is not None:
+                        # a genuinely new app: register its ground-truth surface
+                        self.surfaces = {
+                            **self.surfaces, event.app.name: event.surface
+                        }
+                    if event.app.name not in self.surfaces:
+                        raise KeyError(
+                            f"no surface for arriving app {event.app.name!r}"
+                        )
+                    nid = t.next_node_id()
+                    domain_id = -1
+                    if self.topology is not None:
+                        if event.domain is not None:
+                            domain_id = self.topology.require_leaf(event.domain)
+                        else:
+                            # the assigned id must fall inside some leaf range
+                            try:
+                                domain_id = int(self.topology.leaf_of([nid])[0])
+                            except ValueError:
+                                raise ValueError(
+                                    f"arrival of {event.app.name!r} at round "
+                                    f"{event.round} got node id {nid}, which no "
+                                    f"leaf domain owns — pass "
+                                    f"NodeArrival(domain=...) to place it"
+                                ) from None
+                    caps = event.caps or (self.system.init_cpu, self.system.init_gpu)
+                    t.append(
+                        node_id=nid,
+                        name=f"{event.app.name}#n{nid}",
+                        base_app=event.app.name,
+                        surface_id=event.app.surface_id,
+                        sclass=event.app.sclass,
+                        caps=caps,
+                        domain_id=domain_id,
                     )
-                if event.domain not in self.topology.index:
-                    raise KeyError(f"unknown domain {event.domain!r}")
-                self._cap_overrides.set(
-                    self.topology.index[event.domain], event.round, event.cap
-                )
-            else:
-                known = ", ".join(
-                    c.__name__ for c in scenario_mod.Event.__args__
-                )
-                raise TypeError(
-                    f"unknown event type {type(event).__name__!r}: {event!r} "
-                    f"(expected one of: {known}; fault events attach via "
-                    f"Scenario.with_faults, not the event timeline)"
-                )
-        rows = (
-            np.unique(np.concatenate(dirty))
-            if dirty
-            else np.empty(0, dtype=np.int64)
-        )
-        t.bump(rows)
-        return touched
+                    dirty.append(np.array([len(t) - 1], dtype=np.int64))
+                elif isinstance(event, scenario_mod.DomainCapChange):
+                    if self.topology is None:
+                        raise ValueError(
+                            "DomainCapChange requires an attached PowerTopology"
+                        )
+                    if event.domain not in self.topology.index:
+                        raise KeyError(f"unknown domain {event.domain!r}")
+                    self._cap_overrides.set(
+                        self.topology.index[event.domain], event.round, event.cap
+                    )
+                else:
+                    known = ", ".join(
+                        c.__name__ for c in scenario_mod.Event.__args__
+                    )
+                    raise TypeError(
+                        f"unknown event type {type(event).__name__!r}: {event!r} "
+                        f"(expected one of: {known}; fault events attach via "
+                        f"Scenario.with_faults, not the event timeline)"
+                    )
+            rows = (
+                np.unique(np.concatenate(dirty))
+                if dirty
+                else np.empty(0, dtype=np.int64)
+            )
+            t.bump(rows)
+            return touched
 
     def apply_event(self, event) -> list[str]:
         """Apply one scenario event; returns affected instance names."""
@@ -1508,132 +1510,121 @@ class ClusterSim:
         """
         prof = self.last_round_profile = {}
         t = self.table
-        tp = _time.perf_counter()
-        if receivers is not None:
-            _recv_rows = self._rows_for_nodes(receivers)
-        if _recv_rows is not None and budget is not None:
-            recv_rows = np.asarray(_recv_rows)
-        else:
-            _, part_rows, pool = self.partition_rows()
-            recv_rows = (
-                np.asarray(_recv_rows) if _recv_rows is not None else part_rows
-            )
-        b = float(pool if budget is None else budget)
-        base = t.caps[recv_rows]
+        with Span("engine.round", round=round_index):
+            with Span("engine.partition") as sp:
+                if receivers is not None:
+                    _recv_rows = self._rows_for_nodes(receivers)
+                if _recv_rows is not None and budget is not None:
+                    recv_rows = np.asarray(_recv_rows)
+                else:
+                    _, part_rows, pool = self.partition_rows()
+                    recv_rows = (
+                        np.asarray(_recv_rows) if _recv_rows is not None else part_rows
+                    )
+                b = float(pool if budget is None else budget)
+                base = t.caps[recv_rows]
 
-        hierarchical = self.topology is not None and getattr(
-            controller, "supports_hierarchical", False
-        )
-        headroom = (
-            self.domain_headroom(round_index, recv_rows)
-            if self.topology is not None
-            else None
-        )
-        prof["partition_s"] = _time.perf_counter() - tp
+                hierarchical = self.topology is not None and getattr(
+                    controller, "supports_hierarchical", False
+                )
+                headroom = (
+                    self.domain_headroom(round_index, recv_rows)
+                    if self.topology is not None
+                    else None
+                )
+            prof["partition_s"] = sp.seconds
 
-        tp = _time.perf_counter()
-        names: Sequence[str] | None = None
-        batch = None
-        if hierarchical or getattr(controller, "supports_grouped", False):
-            batch = self._receiver_batch(
-                recv_rows,
-                policy_surfaces,
-                controller.sees_truth,
-                skip_surfaces=getattr(controller, "serves_own_surfaces", False),
-            )
-            names = batch.names
-        prof["batch_s"] = _time.perf_counter() - tp
+            with Span("engine.batch") as sp:
+                names: Sequence[str] | None = None
+                batch = None
+                if hierarchical or getattr(controller, "supports_grouped", False):
+                    batch = self._receiver_batch(
+                        recv_rows,
+                        policy_surfaces,
+                        controller.sees_truth,
+                        skip_surfaces=getattr(controller, "serves_own_surfaces", False),
+                    )
+                    names = batch.names
+            prof["batch_s"] = sp.seconds
 
-        tp = _time.perf_counter()
-        if hierarchical:
-            controller.bind_topology(self.topology)
-            alloc = controller.allocate_hierarchical(batch, b, headroom[0])
-        elif batch is not None:
-            alloc = controller.allocate_grouped(batch, b)
-        else:
-            recv_nodes = t.views(recv_rows)
-            names = [n.app.name for n in recv_nodes]
-            recv_apps = [n.app for n in recv_nodes]
-            baselines = {n.app.name: n.caps for n in recv_nodes}
-            true_by_inst = {n.app.name: self._surface(n) for n in recv_nodes}
-            seen = (
-                policy_surfaces if policy_surfaces is not None else true_by_inst
+            with Span("engine.allocate") as sp:
+                if hierarchical:
+                    controller.bind_topology(self.topology)
+                    alloc = controller.allocate_hierarchical(batch, b, headroom[0])
+                elif batch is not None:
+                    alloc = controller.allocate_grouped(batch, b)
+                else:
+                    recv_nodes = t.views(recv_rows)
+                    names = [n.app.name for n in recv_nodes]
+                    recv_apps = [n.app for n in recv_nodes]
+                    baselines = {n.app.name: n.caps for n in recv_nodes}
+                    true_by_inst = {n.app.name: self._surface(n) for n in recv_nodes}
+                    seen = (
+                        policy_surfaces if policy_surfaces is not None else true_by_inst
+                    )
+                    if controller.sees_truth:
+                        seen = true_by_inst
+                    alloc = controller.allocate(recv_apps, baselines, b, seen)
+            prof["allocate_s"] = sp.seconds
+            # which path produced the solution (DESIGN.md §14); the fused
+            # round's own split is the controller's fused_segments()
+            prof["alloc_solver"] = getattr(controller, "last_solver", None) or ""
+            prof["alloc_fallback_reason"] = (
+                getattr(controller, "last_fallback_reason", "") or ""
             )
-            if controller.sees_truth:
-                seen = true_by_inst
-            alloc = controller.allocate(recv_apps, baselines, b, seen)
-        prof["allocate_s"] = _time.perf_counter() - tp
-        # fused-round split (DESIGN.md §14): seconds inside the jitted
-        # device pipeline and which path produced the solution
-        prof["alloc_device_s"] = float(
-            getattr(controller, "last_device_s", 0.0) or 0.0
-        )
-        prof["alloc_solver"] = getattr(controller, "last_solver", None) or ""
-        prof["alloc_fallback_reason"] = (
-            getattr(controller, "last_fallback_reason", "") or ""
-        )
-        # resident-bank sync counters (DESIGN.md §17): cumulative cold
-        # rebuilds / device compactions and the last round's slack
-        # occupancy, so scenario tooling can prove churn stayed O(churn)
-        fstats_fn = getattr(controller, "fused_stats", None)
-        if fstats_fn is not None:
-            fstats = fstats_fn()
-            prof["alloc_fused_rebuilds"] = fstats.rebuilds
-            prof["alloc_fused_compactions"] = fstats.compactions
-            prof["alloc_fused_slack_utilization"] = fstats.slack_utilization
 
-        tp = _time.perf_counter()
-        if self.topology is not None:
-            self._check_domain_conservation(
-                recv_rows, names, base, alloc, round_index, headroom,
-                enforce=hierarchical,
-            )
-        prof["conserve_s"] = _time.perf_counter() - tp
+            with Span("engine.conserve") as sp:
+                if self.topology is not None:
+                    self._check_domain_conservation(
+                        recv_rows, names, base, alloc, round_index, headroom,
+                        enforce=hierarchical,
+                    )
+            prof["conserve_s"] = sp.seconds
 
-        # -- actuation + PowerGuard (fault-injected runs, DESIGN.md §18) --
-        tp = _time.perf_counter()
-        self.last_actuation = None
-        self.last_guard = None
-        applied: np.ndarray | None = None
-        if _fault_injector is not None and names is not None:
-            cmd = self._alloc_caps_array(alloc, names)
-            applied, report, guard = self._actuate_and_guard(
-                recv_rows, names, base, cmd, b, round_index,
-                headroom, _fault_injector,
-            )
-            self.last_actuation = report
-            self.last_guard = guard
-            notify = getattr(controller, "notify_actuation", None)
-            if notify is not None:
-                notify(report)
-        prof["actuate_s"] = _time.perf_counter() - tp
+            # -- actuation + PowerGuard (fault-injected runs, DESIGN.md §18) --
+            with Span("engine.actuate") as sp:
+                self.last_actuation = None
+                self.last_guard = None
+                applied: np.ndarray | None = None
+                if _fault_injector is not None and names is not None:
+                    cmd = self._alloc_caps_array(alloc, names)
+                    applied, report, guard = self._actuate_and_guard(
+                        recv_rows, names, base, cmd, b, round_index,
+                        headroom, _fault_injector,
+                    )
+                    self.last_actuation = report
+                    self.last_guard = guard
+                    notify = getattr(controller, "notify_actuation", None)
+                    if notify is not None:
+                        notify(report)
+            prof["actuate_s"] = sp.seconds
 
-        tp = _time.perf_counter()
-        rng = self.round_rng(controller.policy, round_index)
-        if use_loop_measurement:
-            recv_nodes = t.views(recv_rows)
-            improvements = self.measure_improvements_loop(recv_nodes, alloc, rng)
-            self.last_telemetry = ()
-        else:
-            new = (
-                applied
-                if applied is not None
-                else self._alloc_caps_array(alloc, names)
-            )
-            t0, t1, imp = self._measure_rows(recv_rows, base, new, rng)
-            improvements = dict(zip(names, imp.tolist()))
-            self.last_telemetry = TelemetryBatch(
-                round=round_index,
-                inst_gids=t.name_gid[recv_rows],
-                app_gids=t.base_gid[recv_rows],
-                strings=t.strings,
-                baseline_caps=base,
-                allocated_caps=new,
-                t_baseline=t0,
-                t_allocated=t1,
-                improvement=imp,
-            )
-        prof["measure_s"] = _time.perf_counter() - tp
+            with Span("engine.measure") as sp:
+                rng = self.round_rng(controller.policy, round_index)
+                if use_loop_measurement:
+                    recv_nodes = t.views(recv_rows)
+                    improvements = self.measure_improvements_loop(recv_nodes, alloc, rng)
+                    self.last_telemetry = ()
+                else:
+                    new = (
+                        applied
+                        if applied is not None
+                        else self._alloc_caps_array(alloc, names)
+                    )
+                    t0, t1, imp = self._measure_rows(recv_rows, base, new, rng)
+                    improvements = dict(zip(names, imp.tolist()))
+                    self.last_telemetry = TelemetryBatch(
+                        round=round_index,
+                        inst_gids=t.name_gid[recv_rows],
+                        app_gids=t.base_gid[recv_rows],
+                        strings=t.strings,
+                        baseline_caps=base,
+                        allocated_caps=new,
+                        t_baseline=t0,
+                        t_allocated=t1,
+                        improvement=imp,
+                    )
+            prof["measure_s"] = sp.seconds
         return EmulationResult(
             policy=controller.policy,
             improvements=improvements,

@@ -65,6 +65,7 @@ from typing import MutableMapping, Sequence
 import numpy as np
 
 from repro.core.curves import OptionTable, dense_curve, dense_curves_matrix
+from repro.core.spans import Span
 
 
 class LRUCache(MutableMapping):
@@ -1705,8 +1706,8 @@ class FusedState:
         self._leaf_ints: dict = {}
         #: row sig -> (kb_glob desc, vals desc, keys desc)
         self._row_cache: dict = {}
-        #: last round's wall-clock split: prep/patch/compact/dispatch/
-        #: backtrack/assembly seconds (tools/profile_round.py --churn)
+        #: last round's seconds per ``fused.*`` span: prep/patch/compact/
+        #: dispatch (launch + wait)/backtrack/assembly (``fused_segments``)
         self.last_segments: dict = {}
         self.stats: dict = {
             "rounds": 0,
@@ -1716,7 +1717,6 @@ class FusedState:
             "row_uploads": 0,
             "short_circuits": 0,
             "slack_utilization": 0.0,
-            "device_s": 0.0,
             "fallback_reason": "",
             "value_bound": 0.0,
         }
@@ -1912,6 +1912,11 @@ def _fused_pipeline_fn(
     waves, dom_rows = tree if tree is not None else ((), ())
     root_row = dom_rows[-1] if dom_rows else 0
 
+    # named device scopes (leaf_scan, option_scatter in the stage kernel's
+    # wrapper, stage_mask, frontier_wave<i>, root_argmax, tree_backtrack,
+    # leaf_backtrack) label the ops of the compiled program, so a trace
+    # reduction finds each part of the pipeline by name
+    @jax.named_scope("leaf_scan")
     def leaf_scan(kb, vb, tmax_leaf):
         t_idx = jnp.arange(NB, dtype=jnp.int32)
         neg = jnp.asarray(-jnp.inf, vb.dtype)
@@ -1924,7 +1929,8 @@ def _fused_pipeline_fn(
             )
             # per-leaf feasibility mask after every stage == the host
             # batch's out[li, tmax+1:] = -inf
-            out = jnp.where(t_idx[None, :] > tmax_leaf[:, None], neg, out)
+            with jax.named_scope("stage_mask"):
+                out = jnp.where(t_idx[None, :] > tmax_leaf[:, None], neg, out)
             return out, arg
 
         return jax.lax.scan(stage, dp0, (kb, vb))
@@ -1947,38 +1953,41 @@ def _fused_pipeline_fn(
             else dp
         )
         wins_tree = []  # per wave: [ops, NBT] winning right spends
-        for k_level, wave in waves:
-            left = buf[rows([op[0] for op in wave])]
-            right = buf[rows([op[1] for op in wave]), :k_level]
-            out, t_right = _mk.maxplus_conv_pallas_batched(
-                left, right, descending=True, interpret=interpret
-            )
-            tc = tcuts[rows([op[3] for op in wave])]
-            out = jnp.where(t_idx_tree[None, :] > tc[:, None], neg, out)
-            wins_tree.append(t_right)
-            buf = jnp.concatenate([buf, out], axis=0)
+        for w, (k_level, wave) in enumerate(waves):
+            with jax.named_scope(f"frontier_wave{w}"):
+                left = buf[rows([op[0] for op in wave])]
+                right = buf[rows([op[1] for op in wave]), :k_level]
+                out, t_right = _mk.maxplus_conv_pallas_batched(
+                    left, right, descending=True, interpret=interpret
+                )
+                tc = tcuts[rows([op[3] for op in wave])]
+                out = jnp.where(t_idx_tree[None, :] > tc[:, None], neg, out)
+                wins_tree.append(t_right)
+                buf = jnp.concatenate([buf, out], axis=0)
 
-        root_vec = buf[root_row]
-        t_root = jnp.argmax(root_vec).astype(jnp.int32)  # first max
-        root_val = root_vec[t_root]
+        with jax.named_scope("root_argmax"):
+            root_vec = buf[root_row]
+            t_root = jnp.argmax(root_vec).astype(jnp.int32)  # first max
+            root_val = root_vec[t_root]
 
         # tree backtrack: split t down the static schedule via gathers,
         # in reverse wave order (an op's output t is known before its
         # inputs are needed — the schedule is topological)
-        t_of = {root_row: t_root}
-        for (_k, wave), win in zip(reversed(waves), reversed(wins_tree)):
-            for i in range(len(wave) - 1, -1, -1):
-                l_row, r_row, o_row, _d = wave[i]
-                t_out = t_of[o_row]
-                t_r = win[i, t_out]
-                t_of[r_row] = t_r
-                t_of[l_row] = t_out - t_r
-        t_leaf = jnp.stack([t_of[i] for i in range(L)]).astype(jnp.int32)
-        t_dom = (
-            jnp.stack([t_of[r] for r in dom_rows]).astype(jnp.int32)
-            if dom_rows
-            else jnp.zeros((0,), jnp.int32)
-        )
+        with jax.named_scope("tree_backtrack"):
+            t_of = {root_row: t_root}
+            for (_k, wave), win in zip(reversed(waves), reversed(wins_tree)):
+                for i in range(len(wave) - 1, -1, -1):
+                    l_row, r_row, o_row, _d = wave[i]
+                    t_out = t_of[o_row]
+                    t_r = win[i, t_out]
+                    t_of[r_row] = t_r
+                    t_of[l_row] = t_out - t_r
+            t_leaf = jnp.stack([t_of[i] for i in range(L)]).astype(jnp.int32)
+            t_dom = (
+                jnp.stack([t_of[r] for r in dom_rows]).astype(jnp.int32)
+                if dom_rows
+                else jnp.zeros((0,), jnp.int32)
+            )
         return t_root, root_val, t_leaf, t_dom
 
     if shards > 1:
@@ -2024,8 +2033,9 @@ def _fused_pipeline_fn(
             j = win_s[rows_i, t]
             return (t - kb_s[rows_i, j]).astype(jnp.int32), j.astype(jnp.int32)
 
-        _, js_rev = jax.lax.scan(bstep, t_leaf, (kb[::-1], wins[::-1]))
-        js = js_rev[::-1].swapaxes(0, 1)  # [L, S]
+        with jax.named_scope("leaf_backtrack"):
+            _, js_rev = jax.lax.scan(bstep, t_leaf, (kb[::-1], wins[::-1]))
+            js = js_rev[::-1].swapaxes(0, 1)  # [L, S]
         return t_root, t_leaf, js, root_val, t_dom
 
     return run
@@ -2118,8 +2128,6 @@ def _fused_run(
     off-lattice keys, oversized grids, empty rounds or an infeasible
     root; ``fstate.stats['fallback_reason']`` records which.
     """
-    import time
-
     import jax
     import jax.numpy as jnp
 
@@ -2130,172 +2138,173 @@ def _fused_run(
         "prep_s": 0.0, "patch_s": 0.0, "compact_s": 0.0,
         "dispatch_s": 0.0, "backtrack_s": 0.0, "assembly_s": 0.0,
     }
-    t_seg = time.perf_counter()
-    L = len(specs)
-    if L == 0:
-        stats["fallbacks"] += 1
-        stats["fallback_reason"] = "empty"
-        return None
-
-    prepped = []
-    for spec in specs:
-        pr = _fused_leaf_rows(spec, fstate)
-        if pr is None:
+    with Span("fused.prep") as sp:
+        L = len(specs)
+        if L == 0:
             stats["fallbacks"] += 1
-            stats["fallback_reason"] = "off_lattice"
+            stats["fallback_reason"] = "empty"
             return None
-        prepped.append(pr)
 
-    g = 0
-    for (g_l, _, rows, all_zero) in prepped:
-        if rows and not all_zero:
-            # zero-spend leaves contribute nothing: their only state (0)
-            # sits on every lattice, so they must not shrink the pitch
-            g = int(np.gcd(g, g_l))
-    if g <= 0:
-        g = 1
-
-    shards = _fused_shards()
-    Lp = -(-L // shards) * shards  # pad rows are identity leaves
-
-    s_max = 1
-    k_max = 1
-    nb_needed = 1
-    v_abs = 0.0  # sum of every stage's largest |value|: bounds any path
-    tmax_dev = np.zeros(Lp, dtype=np.int32)
-    for li, (g_l, tmax_host, rows, all_zero) in enumerate(prepped):
-        if rows:
-            mult = 1 if all_zero else g_l // g
-            td = tmax_host * mult
-            if td + 1 > _FUSED_MAX_NB:
+        prepped = []
+        for spec in specs:
+            pr = _fused_leaf_rows(spec, fstate)
+            if pr is None:
                 stats["fallbacks"] += 1
-                stats["fallback_reason"] = "grid_overflow"
+                stats["fallback_reason"] = "off_lattice"
                 return None
-            tmax_dev[li] = td
-            nb_needed = max(nb_needed, td + 1)
-            s_max = max(s_max, len(rows))
-            for row in rows:
-                k_max = max(k_max, len(row[0]))
-                v_abs += row[4]
+            prepped.append(pr)
 
-    use_tree = kind == "tree"
-    tcuts = np.zeros(len(doms), dtype=np.int32)
-    nbt_needed = nb_needed
-    ops: tuple = ()
-    depths: dict = {}
-    leaves_under: dict = {}
-    dom_rows: tuple = ()
-    if use_tree:
-        # the exact _maxplus_pair prune per internal domain: keep combined
-        # states whose reconstructed float64 key is <= eff + 1e-9
-        cut_by_eff: dict[float, int] = {}
-        for i, (_dn, eff_d) in enumerate(doms):
-            c = cut_by_eff.get(eff_d)
-            if c is None:
-                ub = int((eff_d + 1e-9) * 1e6 // g) + 1
-                if ub + 1 > 4 * _FUSED_MAX_NB:
+        g = 0
+        for (g_l, _, rows, all_zero) in prepped:
+            if rows and not all_zero:
+                # zero-spend leaves contribute nothing: their only state (0)
+                # sits on every lattice, so they must not shrink the pitch
+                g = int(np.gcd(g, g_l))
+        if g <= 0:
+            g = 1
+
+        shards = _fused_shards()
+        Lp = -(-L // shards) * shards  # pad rows are identity leaves
+
+        s_max = 1
+        k_max = 1
+        nb_needed = 1
+        v_abs = 0.0  # sum of every stage's largest |value|: bounds any path
+        tmax_dev = np.zeros(Lp, dtype=np.int32)
+        for li, (g_l, tmax_host, rows, all_zero) in enumerate(prepped):
+            if rows:
+                mult = 1 if all_zero else g_l // g
+                td = tmax_host * mult
+                if td + 1 > _FUSED_MAX_NB:
                     stats["fallbacks"] += 1
                     stats["fallback_reason"] = "grid_overflow"
                     return None
-                ks = (
-                    np.arange(ub + 2, dtype=np.int64) * g
-                ).astype(np.float64) * 1e-6
-                c = int(np.flatnonzero(ks <= eff_d + 1e-9).max())
-                cut_by_eff[eff_d] = c
-            tcuts[i] = c
-        ops, depths, leaves_under, dom_rows = _tree_ops(tree_sig, Lp)
-        # the tree grid only needs the reachable spend-sum support: every
-        # state beyond min(cap cut, sum of input supports) is -inf
-        support = {li: int(tmax_dev[li]) for li in range(L)}
-        for l_row, r_row, o_row, d in ops:
-            support[o_row] = min(
-                support[l_row] + support[r_row], int(tcuts[d])
-            )
-        nbt_needed = max(nb_needed, max(support.values()) + 1)
+                tmax_dev[li] = td
+                nb_needed = max(nb_needed, td + 1)
+                s_max = max(s_max, len(rows))
+                for row in rows:
+                    k_max = max(k_max, len(row[0]))
+                    v_abs += row[4]
 
-    if k_max > _FUSED_MAX_OPTS:
-        stats["fallbacks"] += 1
-        stats["fallback_reason"] = "grid_overflow"
-        return None
-    nb_pad = _pow2_at_least(nb_needed, 16)
-    nbt_pad = _pow2_at_least(nbt_needed, 16) if use_tree else nb_pad
-    if max(nb_pad, nbt_pad) > _FUSED_MAX_NB:
-        stats["fallbacks"] += 1
-        stats["fallback_reason"] = "grid_overflow"
-        return None
-    s_pad = max(1, -(-s_max // 8) * 8)
-    k_pad = _pow2_at_least(max(k_max, 1), 4)
+        use_tree = kind == "tree"
+        tcuts = np.zeros(len(doms), dtype=np.int32)
+        nbt_needed = nb_needed
+        ops: tuple = ()
+        depths: dict = {}
+        leaves_under: dict = {}
+        dom_rows: tuple = ()
+        if use_tree:
+            # the exact _maxplus_pair prune per internal domain: keep combined
+            # states whose reconstructed float64 key is <= eff + 1e-9
+            cut_by_eff: dict[float, int] = {}
+            for i, (_dn, eff_d) in enumerate(doms):
+                c = cut_by_eff.get(eff_d)
+                if c is None:
+                    ub = int((eff_d + 1e-9) * 1e6 // g) + 1
+                    if ub + 1 > 4 * _FUSED_MAX_NB:
+                        stats["fallbacks"] += 1
+                        stats["fallback_reason"] = "grid_overflow"
+                        return None
+                    ks = (
+                        np.arange(ub + 2, dtype=np.int64) * g
+                    ).astype(np.float64) * 1e-6
+                    c = int(np.flatnonzero(ks <= eff_d + 1e-9).max())
+                    cut_by_eff[eff_d] = c
+                tcuts[i] = c
+            ops, depths, leaves_under, dom_rows = _tree_ops(tree_sig, Lp)
+            # the tree grid only needs the reachable spend-sum support: every
+            # state beyond min(cap cut, sum of input supports) is -inf
+            support = {li: int(tmax_dev[li]) for li in range(L)}
+            for l_row, r_row, o_row, d in ops:
+                support[o_row] = min(
+                    support[l_row] + support[r_row], int(tcuts[d])
+                )
+            nbt_needed = max(nb_needed, max(support.values()) + 1)
 
-    names = tuple(name for name, *_ in specs)
-    dom_names = tuple(dn for dn, _ in doms)
-    # sticky pads: padding up is always exact (identity stages, -inf
-    # option tails, masked grid tops), so never *shrink* the resident
-    # tiers while the solver kind matches — churn across a pow2 boundary
-    # must not flap between compactions and recompiles, and keeping tiers
-    # across leaf-count changes means compaction never truncates content
-    if fstate.shape is not None and fstate.shape[0] == kind:
-        _pk, _pL, ps, pkk, pnb, pnbt = fstate.shape[:6]
-        s_pad = max(s_pad, ps)
-        k_pad = max(k_pad, pkk)
-        nb_pad = max(nb_pad, pnb)
-        nbt_pad = max(nbt_pad, pnbt) if use_tree else nb_pad
-    nbt_pad = max(nbt_pad, nb_pad)
-    # capacity-slack layout signature (DESIGN.md §17): only what the
-    # jitted pipeline is specialized on — kind, leaf count, padded tiers
-    # and the static tree schedule.  Everything else (global pitch g,
-    # leaf names, class digests/layouts, option rows) is *content*: the
-    # per-row signatures below move it through the delta-patch or
-    # compaction path under an unchanged layout, with no re-jit and no
-    # host round.  Row signatures fold in the leaf->global lattice
-    # multiplier, so a pitch change re-uploads exactly the rows whose
-    # device image (kb * mult) it moved.
-    layout = (kind, L, s_pad, k_pad, nb_pad, nbt_pad, tree_sig)
-    stats["slack_utilization"] = max(
-        s_max / s_pad,
-        k_max / k_pad,
-        nb_needed / nb_pad,
-        (nbt_needed / nbt_pad) if use_tree else 0.0,
-    )
+        if k_max > _FUSED_MAX_OPTS:
+            stats["fallbacks"] += 1
+            stats["fallback_reason"] = "grid_overflow"
+            return None
+        nb_pad = _pow2_at_least(nb_needed, 16)
+        nbt_pad = _pow2_at_least(nbt_needed, 16) if use_tree else nb_pad
+        if max(nb_pad, nbt_pad) > _FUSED_MAX_NB:
+            stats["fallbacks"] += 1
+            stats["fallback_reason"] = "grid_overflow"
+            return None
+        s_pad = max(1, -(-s_max // 8) * 8)
+        k_pad = _pow2_at_least(max(k_max, 1), 4)
 
-    # DESIGN.md §14: a device path value is a sum tree over its option
-    # values with n = stages + tree depth + 1 roundings per term (the +1
-    # rounds each value into the device dtype), so it is within
-    # gamma_n * v_abs of the exact sum, and the device's argmax path
-    # trails the host optimum by at most twice that: 2 (n + 1) u v_abs
-    # with u = eps / 2 (the extra +1 absorbs gamma_n's higher-order term
-    # and the float64 host's own sums)
-    vdt = _kops.device_value_dtype()
-    n_round = s_max + (max(depths.values()) if depths else 0) + 1
-    stats["value_bound"] = (n_round + 1) * float(np.finfo(vdt).eps) * v_abs
-
-    bank_shape = (s_pad, Lp, k_pad)
-    rebuild = fstate.shape is None
-    compact = not rebuild and (
-        fstate.shape != layout or tuple(fstate.kb_dev.shape) != bank_shape
-    )
-    if compact and (
-        fstate.shape[0] != kind
-        or len(set(names)) != len(names)
-        or len(set(fstate.names or ())) != len(fstate.names or ())
-    ):
-        # unmappable resident state (different solver kind, ambiguous
-        # leaf identities): cold host rebuild — still a fused round
-        rebuild, compact = True, False
-
-    if shards > 1:
-        # resident banks live split leaf-wise over the shard mesh, where
-        # the sharded leaf scan reads them
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as P
-
-        bank_sharding = NamedSharding(
-            _kops.leaf_shard_mesh(shards), P(None, "leaves", None)
+        names = tuple(name for name, *_ in specs)
+        dom_names = tuple(dn for dn, _ in doms)
+        # sticky pads: padding up is always exact (identity stages, -inf
+        # option tails, masked grid tops), so never *shrink* the resident
+        # tiers while the solver kind matches — churn across a pow2 boundary
+        # must not flap between compactions and recompiles, and keeping tiers
+        # across leaf-count changes means compaction never truncates content
+        if fstate.shape is not None and fstate.shape[0] == kind:
+            _pk, _pL, ps, pkk, pnb, pnbt = fstate.shape[:6]
+            s_pad = max(s_pad, ps)
+            k_pad = max(k_pad, pkk)
+            nb_pad = max(nb_pad, pnb)
+            nbt_pad = max(nbt_pad, pnbt) if use_tree else nb_pad
+        nbt_pad = max(nbt_pad, nb_pad)
+        # capacity-slack layout signature (DESIGN.md §17): only what the
+        # jitted pipeline is specialized on — kind, leaf count, padded tiers
+        # and the static tree schedule.  Everything else (global pitch g,
+        # leaf names, class digests/layouts, option rows) is *content*: the
+        # per-row signatures below move it through the delta-patch or
+        # compaction path under an unchanged layout, with no re-jit and no
+        # host round.  Row signatures fold in the leaf->global lattice
+        # multiplier, so a pitch change re-uploads exactly the rows whose
+        # device image (kb * mult) it moved.
+        layout = (kind, L, s_pad, k_pad, nb_pad, nbt_pad, tree_sig)
+        stats["slack_utilization"] = max(
+            s_max / s_pad,
+            k_max / k_pad,
+            nb_needed / nb_pad,
+            (nbt_needed / nbt_pad) if use_tree else 0.0,
         )
 
-        def place(x):
-            return jax.device_put(x, bank_sharding)
-    else:
-        place = jnp.asarray
+        # DESIGN.md §14: a device path value is a sum tree over its option
+        # values with n = stages + tree depth + 1 roundings per term (the +1
+        # rounds each value into the device dtype), so it is within
+        # gamma_n * v_abs of the exact sum, and the device's argmax path
+        # trails the host optimum by at most twice that: 2 (n + 1) u v_abs
+        # with u = eps / 2 (the extra +1 absorbs gamma_n's higher-order term
+        # and the float64 host's own sums)
+        vdt = _kops.device_value_dtype()
+        n_round = s_max + (max(depths.values()) if depths else 0) + 1
+        stats["value_bound"] = (n_round + 1) * float(np.finfo(vdt).eps) * v_abs
+
+        bank_shape = (s_pad, Lp, k_pad)
+        rebuild = fstate.shape is None
+        compact = not rebuild and (
+            fstate.shape != layout or tuple(fstate.kb_dev.shape) != bank_shape
+        )
+        if compact and (
+            fstate.shape[0] != kind
+            or len(set(names)) != len(names)
+            or len(set(fstate.names or ())) != len(fstate.names or ())
+        ):
+            # unmappable resident state (different solver kind, ambiguous
+            # leaf identities): cold host rebuild — still a fused round
+            rebuild, compact = True, False
+
+        if shards > 1:
+            # resident banks live split leaf-wise over the shard mesh, where
+            # the sharded leaf scan reads them
+            from jax.sharding import NamedSharding
+            from jax.sharding import PartitionSpec as P
+
+            bank_sharding = NamedSharding(
+                _kops.leaf_shard_mesh(shards), P(None, "leaves", None)
+            )
+
+            def place(x):
+                return jax.device_put(x, bank_sharding)
+        else:
+            place = jnp.asarray
+    seg["prep_s"] = sp.seconds
 
     with _kops.device_value_scope():
 
@@ -2332,117 +2341,118 @@ def _fused_run(
             stats["row_uploads"] += m
             fstate.last_key = None
 
-        seg["prep_s"] = time.perf_counter() - t_seg
-        t_seg = time.perf_counter()
         if rebuild:
-            # cold start (or unmappable state): host-built banks, one
-            # full upload — the only non-O(churn) sync point left
-            kb_np = np.zeros((s_pad, Lp, k_pad), dtype=np.int32)
-            vb_np = np.full((s_pad, Lp, k_pad), -np.inf)
-            vb_np[:, :, 0] = 0.0  # identity padding stages/rows: spend 0, +0.0
-            row_sigs: list[list] = [[None] * s_pad for _ in range(L)]
-            keys_desc: list[list] = [[None] * s_pad for _ in range(L)]
-            for li, (g_l, tmax_host, rows, all_zero) in enumerate(prepped):
-                mult = 1 if all_zero else g_l // g
-                for s, (kb, vb, keys, sig, _a) in enumerate(rows):
-                    n = len(kb)
-                    kb_np[s, li, :n] = kb * mult
-                    vb_np[s, li, :n] = vb
-                    vb_np[s, li, n:] = -np.inf
-                    row_sigs[li][s] = (sig, mult)
-                    keys_desc[li][s] = keys
-            fstate.kb_dev = place(kb_np)
-            fstate.vb_dev = place(vb_np.astype(vdt))
-            fstate.row_sigs = row_sigs
-            fstate.keys_desc = keys_desc
-            fstate.shape = layout
-            fstate.names = names
-            fstate.g = g
-            fstate.last_key = None
-            fstate.last_solution = None
-            stats["rebuilds"] += 1
-            seg["patch_s"] += time.perf_counter() - t_seg
+            with Span("fused.patch") as sp:
+                # cold start (or unmappable state): host-built banks, one
+                # full upload — the only non-O(churn) sync point left
+                kb_np = np.zeros((s_pad, Lp, k_pad), dtype=np.int32)
+                vb_np = np.full((s_pad, Lp, k_pad), -np.inf)
+                vb_np[:, :, 0] = 0.0  # identity padding stages/rows: spend 0, +0.0
+                row_sigs: list[list] = [[None] * s_pad for _ in range(L)]
+                keys_desc: list[list] = [[None] * s_pad for _ in range(L)]
+                for li, (g_l, tmax_host, rows, all_zero) in enumerate(prepped):
+                    mult = 1 if all_zero else g_l // g
+                    for s, (kb, vb, keys, sig, _a) in enumerate(rows):
+                        n = len(kb)
+                        kb_np[s, li, :n] = kb * mult
+                        vb_np[s, li, :n] = vb
+                        vb_np[s, li, n:] = -np.inf
+                        row_sigs[li][s] = (sig, mult)
+                        keys_desc[li][s] = keys
+                fstate.kb_dev = place(kb_np)
+                fstate.vb_dev = place(vb_np.astype(vdt))
+                fstate.row_sigs = row_sigs
+                fstate.keys_desc = keys_desc
+                fstate.shape = layout
+                fstate.names = names
+                fstate.g = g
+                fstate.last_key = None
+                fstate.last_solution = None
+                stats["rebuilds"] += 1
+            seg["patch_s"] += sp.seconds
         elif compact:
-            # device-side compaction (DESIGN.md §17): the layout moved
-            # (leaf set / pad tier / topology), so repack every row whose
-            # content signature survived via one jitted gather out of the
-            # old banks — clean subtrees keep their rows bit-for-bit with
-            # zero upload — and scatter only the dirty rows after
-            from repro.kernels import ops as _kops
+            with Span("fused.compact") as sp:
+                # device-side compaction (DESIGN.md §17): the layout moved
+                # (leaf set / pad tier / topology), so repack every row whose
+                # content signature survived via one jitted gather out of the
+                # old banks — clean subtrees keep their rows bit-for-bit with
+                # zero upload — and scatter only the dirty rows after
+                from repro.kernels import ops as _kops
 
-            old_pos = {nm: i for i, nm in enumerate(fstate.names or ())}
-            o_s_pad = int(fstate.kb_dev.shape[0])
-            src_s = np.full((s_pad, Lp), -1, dtype=np.int32)
-            src_l = np.full((s_pad, Lp), -1, dtype=np.int32)
-            row_sigs = [[None] * s_pad for _ in range(L)]
-            keys_desc = [[None] * s_pad for _ in range(L)]
-            dirty: list[tuple] = []
-            for li, (g_l, tmax_host, rows, all_zero) in enumerate(prepped):
-                mult = 1 if all_zero else g_l // g
-                oli = old_pos.get(names[li])
-                for s in range(s_pad):
-                    if s < len(rows):
-                        kb, vb, keys, sig, _a = rows[s]
-                        esig = (sig, mult)
-                    else:
-                        kb = vb = keys = None
-                        esig = None
-                    row_sigs[li][s] = esig
-                    keys_desc[li][s] = keys
-                    if esig is None:
-                        continue  # identity rows come from the init
-                    if (
-                        oli is not None
-                        and s < o_s_pad
-                        and fstate.row_sigs[oli][s] == esig
-                    ):
-                        src_s[s, li] = s
-                        src_l[s, li] = oli
-                    else:
-                        dirty.append((s, li, kb * mult, vb))
-            fstate.kb_dev, fstate.vb_dev = map(place, _kops.bank_compact(
-                fstate.kb_dev, fstate.vb_dev,
-                jnp.asarray(src_s), jnp.asarray(src_l), k_pad=k_pad,
-            ))
-            fstate.row_sigs = row_sigs
-            fstate.keys_desc = keys_desc
-            fstate.shape = layout
-            fstate.names = names
-            fstate.g = g
-            fstate.last_key = None
-            fstate.last_solution = None
-            stats["compactions"] += 1
-            seg["compact_s"] += time.perf_counter() - t_seg
-            t_seg = time.perf_counter()
-            if dirty:
-                upload_rows(dirty)
-            seg["patch_s"] += time.perf_counter() - t_seg
+                old_pos = {nm: i for i, nm in enumerate(fstate.names or ())}
+                o_s_pad = int(fstate.kb_dev.shape[0])
+                src_s = np.full((s_pad, Lp), -1, dtype=np.int32)
+                src_l = np.full((s_pad, Lp), -1, dtype=np.int32)
+                row_sigs = [[None] * s_pad for _ in range(L)]
+                keys_desc = [[None] * s_pad for _ in range(L)]
+                dirty: list[tuple] = []
+                for li, (g_l, tmax_host, rows, all_zero) in enumerate(prepped):
+                    mult = 1 if all_zero else g_l // g
+                    oli = old_pos.get(names[li])
+                    for s in range(s_pad):
+                        if s < len(rows):
+                            kb, vb, keys, sig, _a = rows[s]
+                            esig = (sig, mult)
+                        else:
+                            kb = vb = keys = None
+                            esig = None
+                        row_sigs[li][s] = esig
+                        keys_desc[li][s] = keys
+                        if esig is None:
+                            continue  # identity rows come from the init
+                        if (
+                            oli is not None
+                            and s < o_s_pad
+                            and fstate.row_sigs[oli][s] == esig
+                        ):
+                            src_s[s, li] = s
+                            src_l[s, li] = oli
+                        else:
+                            dirty.append((s, li, kb * mult, vb))
+                fstate.kb_dev, fstate.vb_dev = map(place, _kops.bank_compact(
+                    fstate.kb_dev, fstate.vb_dev,
+                    jnp.asarray(src_s), jnp.asarray(src_l), k_pad=k_pad,
+                ))
+                fstate.row_sigs = row_sigs
+                fstate.keys_desc = keys_desc
+                fstate.shape = layout
+                fstate.names = names
+                fstate.g = g
+                fstate.last_key = None
+                fstate.last_solution = None
+                stats["compactions"] += 1
+            seg["compact_s"] += sp.seconds
+            with Span("fused.patch") as sp:
+                if dirty:
+                    upload_rows(dirty)
+            seg["patch_s"] += sp.seconds
         else:
-            # delta patch: upload only the rows whose content signature
-            # moved (class churn / pitch moves / headroom drift), via
-            # donated scatter
-            entries: list[tuple] = []
-            for li, (g_l, tmax_host, rows, all_zero) in enumerate(prepped):
-                mult = 1 if all_zero else g_l // g
-                for s in range(s_pad):
-                    if s < len(rows):
-                        kb, vb, keys, sig, _a = rows[s]
-                        esig = (sig, mult)
-                    else:
-                        kb = vb = keys = None
-                        esig = None
-                    if fstate.row_sigs[li][s] == esig:
-                        continue
-                    entries.append(
-                        (s, li, None if kb is None else kb * mult, vb)
-                    )
-                    fstate.row_sigs[li][s] = esig
-                    fstate.keys_desc[li][s] = keys
-            if entries:
-                upload_rows(entries)
-            fstate.names = names
-            fstate.g = g
-            seg["patch_s"] += time.perf_counter() - t_seg
+            with Span("fused.patch") as sp:
+                # delta patch: upload only the rows whose content signature
+                # moved (class churn / pitch moves / headroom drift), via
+                # donated scatter
+                entries: list[tuple] = []
+                for li, (g_l, tmax_host, rows, all_zero) in enumerate(prepped):
+                    mult = 1 if all_zero else g_l // g
+                    for s in range(s_pad):
+                        if s < len(rows):
+                            kb, vb, keys, sig, _a = rows[s]
+                            esig = (sig, mult)
+                        else:
+                            kb = vb = keys = None
+                            esig = None
+                        if fstate.row_sigs[li][s] == esig:
+                            continue
+                        entries.append(
+                            (s, li, None if kb is None else kb * mult, vb)
+                        )
+                        fstate.row_sigs[li][s] = esig
+                        fstate.keys_desc[li][s] = keys
+                if entries:
+                    upload_rows(entries)
+                fstate.names = names
+                fstate.g = g
+            seg["patch_s"] += sp.seconds
 
         tree_static = None
         if use_tree:
@@ -2452,113 +2462,115 @@ def _fused_run(
             tree_static, L, Lp, s_pad, k_pad, nb_pad, nbt_pad,
             shards, not _kops.on_tpu(),
         )
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(
-            run(
+        # launch and wait apart: the call returns once the program is
+        # enqueued, block_until_ready waits for the device
+        with Span("fused.launch") as sp:
+            out = run(
                 fstate.kb_dev,
                 fstate.vb_dev,
                 jnp.asarray(tmax_dev),
                 jnp.asarray(tcuts),
             )
-        )
-        stats["device_s"] += time.perf_counter() - t0
-        seg["dispatch_s"] += time.perf_counter() - t0
+        launch_s = sp.seconds
+        with Span("fused.wait") as sp:
+            out = jax.block_until_ready(out)
+        seg["dispatch_s"] += launch_s + sp.seconds
         stats["rounds"] += 1
 
-    t_seg = time.perf_counter()
-    if not np.isfinite(float(out[3])):
-        # no feasible root state: keep the host path authoritative
-        stats["fallbacks"] += 1
-        stats["fallback_reason"] = "no_feasible_root"
-        return None
-    stats["fallback_reason"] = ""
-    t_root = int(out[0])
-    t_leaf = np.asarray(out[1])
-    js = np.asarray(out[2])
+    with Span("fused.backtrack") as sp:
+        if not np.isfinite(float(out[3])):
+            # no feasible root state: keep the host path authoritative
+            stats["fallbacks"] += 1
+            stats["fallback_reason"] = "no_feasible_root"
+            return None
+        stats["fallback_reason"] = ""
+        t_root = int(out[0])
+        t_leaf = np.asarray(out[1])
+        js = np.asarray(out[2])
 
-    leaf_meta = []
-    for name, eff, plan, curves_, curve_keys in specs:
-        tok = (
-            st.token(("leaf", (plan.layout, _qkey(eff))))
-            if st is not None
-            else None
-        )
-        leaf_meta.append((tok, plan.key))
-
-    # layout no longer pins pitch / leaf names / class layouts (they are
-    # patchable content now), so the short-circuit key carries them
-    # explicitly alongside the row signatures
-    dec_key = (
-        layout,
-        g,
-        names,
-        dom_names,
-        tuple(tuple(rs) for rs in fstate.row_sigs),
-        tuple(leaf_meta),
-        t_root,
-        t_leaf.tobytes(),
-        js.tobytes(),
-    )
-    seg["backtrack_s"] += time.perf_counter() - t_seg
-    t_seg = time.perf_counter()
-    if dec_key == fstate.last_key and fstate.last_solution is not None:
-        # unchanged device decision vector: the previous solution is the
-        # bit-identical answer — skip the host assembly entirely
-        fstate.stats["short_circuits"] += 1
-        return fstate.last_solution
-
-    picks: dict[str, tuple[float, float, tuple[float, float]]] = {}
-    domain_spent: dict[str, float] | None = (
-        {} if kind in ("tree", "leaf_root") else None
-    )
-    if use_tree:
-        # per-internal-domain spends off the device backtrack: the
-        # float64(t * g) * 1e-6 reconstruction is the host frontier-key
-        # round-trip, so the values are bitwise _backtrack_frontier's
-        t_dom = np.asarray(out[4])
-        for i, (dname, _de) in enumerate(doms):
-            domain_spent[dname] = float(
-                np.float64(int(t_dom[i]) * g) * 1e-6
+        leaf_meta = []
+        for name, eff, plan, curves_, curve_keys in specs:
+            tok = (
+                st.token(("leaf", (plan.layout, _qkey(eff))))
+                if st is not None
+                else None
             )
-    leaf_totals: list[tuple[float, float]] = []
-    for li, ((name, eff, plan, curves_, curve_keys), (tok, _pk)) in enumerate(
-        zip(specs, leaf_meta)
-    ):
-        u = float(np.float64(int(t_leaf[li]) * g) * 1e-6)
-        if domain_spent is not None:
-            domain_spent[name] = u
-        n_stages = len(plan.classes)
-        spends = [
-            float(fstate.keys_desc[li][s][int(js[li, s])])
-            for s in range(n_stages)
-        ]
-        skey = None
-        if st is not None and plan.key is not None:
-            skey = (tok, plan.key, tuple(spends))
-            hit = st.leaf_sol_cache.get(skey)
-            if hit is not None:
-                picks.update(hit[0])
-                leaf_totals.append((hit[1], hit[2]))
-                continue
-        lp, lt, ls = _assemble_plan(
-            plan, curve_keys, curves_, spends, pick_cache
-        )
-        if skey is not None:
-            st.leaf_sol_cache[skey] = (lp, lt, ls)
-        picks.update(lp)
-        leaf_totals.append((lt, ls))
+            leaf_meta.append((tok, plan.key))
 
-    total = 0.0
-    spent = 0.0
-    for lt, ls in leaf_totals:
-        total += lt
-        spent += ls
-    sol = MCKPSolution(
-        total_value=total, spent=spent, picks=picks, domain_spent=domain_spent
-    )
-    fstate.last_key = dec_key
-    fstate.last_solution = sol
-    seg["assembly_s"] += time.perf_counter() - t_seg
+        # layout no longer pins pitch / leaf names / class layouts (they are
+        # patchable content now), so the short-circuit key carries them
+        # explicitly alongside the row signatures
+        dec_key = (
+            layout,
+            g,
+            names,
+            dom_names,
+            tuple(tuple(rs) for rs in fstate.row_sigs),
+            tuple(leaf_meta),
+            t_root,
+            t_leaf.tobytes(),
+            js.tobytes(),
+        )
+    seg["backtrack_s"] += sp.seconds
+    with Span("fused.assembly") as sp:
+        if dec_key == fstate.last_key and fstate.last_solution is not None:
+            # unchanged device decision vector: the previous solution is the
+            # bit-identical answer — skip the host assembly entirely
+            fstate.stats["short_circuits"] += 1
+            return fstate.last_solution
+
+        picks: dict[str, tuple[float, float, tuple[float, float]]] = {}
+        domain_spent: dict[str, float] | None = (
+            {} if kind in ("tree", "leaf_root") else None
+        )
+        if use_tree:
+            # per-internal-domain spends off the device backtrack: the
+            # float64(t * g) * 1e-6 reconstruction is the host frontier-key
+            # round-trip, so the values are bitwise _backtrack_frontier's
+            t_dom = np.asarray(out[4])
+            for i, (dname, _de) in enumerate(doms):
+                domain_spent[dname] = float(
+                    np.float64(int(t_dom[i]) * g) * 1e-6
+                )
+        leaf_totals: list[tuple[float, float]] = []
+        for li, ((name, eff, plan, curves_, curve_keys), (tok, _pk)) in enumerate(
+            zip(specs, leaf_meta)
+        ):
+            u = float(np.float64(int(t_leaf[li]) * g) * 1e-6)
+            if domain_spent is not None:
+                domain_spent[name] = u
+            n_stages = len(plan.classes)
+            spends = [
+                float(fstate.keys_desc[li][s][int(js[li, s])])
+                for s in range(n_stages)
+            ]
+            skey = None
+            if st is not None and plan.key is not None:
+                skey = (tok, plan.key, tuple(spends))
+                hit = st.leaf_sol_cache.get(skey)
+                if hit is not None:
+                    picks.update(hit[0])
+                    leaf_totals.append((hit[1], hit[2]))
+                    continue
+            lp, lt, ls = _assemble_plan(
+                plan, curve_keys, curves_, spends, pick_cache
+            )
+            if skey is not None:
+                st.leaf_sol_cache[skey] = (lp, lt, ls)
+            picks.update(lp)
+            leaf_totals.append((lt, ls))
+
+        total = 0.0
+        spent = 0.0
+        for lt, ls in leaf_totals:
+            total += lt
+            spent += ls
+        sol = MCKPSolution(
+            total_value=total, spent=spent, picks=picks, domain_spent=domain_spent
+        )
+        fstate.last_key = dec_key
+        fstate.last_solution = sol
+    seg["assembly_s"] += sp.seconds
     return sol
 
 

@@ -165,8 +165,7 @@ class FusedRoundStats:
     banks, device-side compactions (layout changes repacked by on-device
     gather instead of a host rebuild), dirty rows patched by the donated
     delta uploads, rounds that short-circuited host assembly on an
-    unchanged decision vector, the last round's slack occupancy, and
-    cumulative seconds inside the jitted pipeline.
+    unchanged decision vector and the last round's slack occupancy.
     """
 
     rounds: int = 0
@@ -184,7 +183,6 @@ class FusedRoundStats:
     #: max over the padded dims of used/padded (1.0 = slack exhausted,
     #: the next structural growth compacts into bigger tiers)
     slack_utilization: float = 0.0
-    device_s: float = 0.0
     #: why the most recent fused attempt fell back to host ("" = it didn't):
     #: "off_lattice" | "grid_overflow" | "no_feasible_root" | "empty"
     #: (the historical "structure_change" fallback is retired — structure

@@ -151,23 +151,31 @@ def maxplus_stage_pallas_batched(
     vb = vb.astype(dp.dtype)
     kb = kb.astype(jnp.int32)
     rows = jnp.arange(r, dtype=jnp.int32)[:, None]
-    # spends >= NB can never land in out[:, :NB]: drop them from the curve
-    k_in = jnp.where(kb < nb, kb, nb)
-    f = jnp.full((r, nb), -jnp.inf, dp.dtype).at[rows, k_in].max(
-        vb, mode="drop"
-    )
-    top = vb == f[rows, jnp.minimum(k_in, nb - 1)]
-    jmap = jnp.full((r, nb), k_opts, jnp.int32).at[
-        rows, jnp.where(top, k_in, nb)
-    ].min(
-        jnp.broadcast_to(jnp.arange(k_opts, dtype=jnp.int32), (r, k_opts)),
-        mode="drop",
-    )
+    # the option mapping onto the dense curve and back, named for the
+    # trace.  The scatters index the curve flattened to [R * NB]: the TPU
+    # compiler flattens a 2-D scatter itself and drops its op metadata,
+    # while one built flat keeps it
+    with jax.named_scope("option_scatter"):
+        # spends >= NB can never land in out[:, :NB]: drop them from the
+        # curve (flat index R * NB is out of range, so mode="drop" skips it)
+        k_in = jnp.where(kb < nb, kb, nb)
+        flat = jnp.where(kb < nb, rows * nb + kb, r * nb)
+        f = jnp.full((r * nb,), -jnp.inf, dp.dtype).at[flat].max(
+            vb, mode="drop"
+        ).reshape(r, nb)
+        top = vb == f[rows, jnp.minimum(k_in, nb - 1)]
+        jmap = jnp.full((r * nb,), k_opts, jnp.int32).at[
+            jnp.where(top, flat, r * nb)
+        ].min(
+            jnp.broadcast_to(jnp.arange(k_opts, dtype=jnp.int32), (r, k_opts)),
+            mode="drop",
+        ).reshape(r, nb)
     out, arg_k = _maxplus_tiles(
         dp, f, n_shifts=nb, descending=True, interpret=interpret
     )
-    arg = jnp.take_along_axis(jmap, jnp.maximum(arg_k, 0), axis=1)
-    return out, jnp.where(arg_k < 0, 0, arg)
+    with jax.named_scope("option_scatter"):
+        arg = jnp.take_along_axis(jmap, jnp.maximum(arg_k, 0), axis=1)
+        return out, jnp.where(arg_k < 0, 0, arg)
 
 
 @functools.partial(jax.jit, static_argnames=("descending", "interpret"))

@@ -13,6 +13,7 @@ only the test worker that runs this file loads the TPU compiler.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -132,3 +133,28 @@ def test_fused_pipeline_compiles(topo, monkeypatch, shards):
     )
     compiled = run.lower(*avals).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_pipeline_keeps_its_names(topo):
+    """What the trace reduction reads by name survives the TPU compiler:
+    the Pallas custom calls keep their wrappers' names
+    (``maxplus_*_pallas_batched.N``), and the fusions that run the option
+    scatters keep the ``option_scatter`` scope in their ``op_name`` (the
+    compiler drops the metadata of a 2-D scatter that it flattens itself,
+    so the stage wrapper builds its scatters flat)."""
+    tree = _site_tree(L)
+    sh = SingleDeviceSharding(topo.devices[0])
+    run = mckp._fused_pipeline_fn.__wrapped__(
+        tree, L, L, S, K, NB, NBT, 1, False
+    )
+    text = run.lower(*_pipeline_avals(sh, sh, sh, sh, L, len(tree[1]))).compile().as_text()
+    kernels = re.findall(
+        r'^\s*%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text, re.M
+    )
+    assert len(kernels) == 1 + len(tree[0])  # the leaf stage, one per wave
+    assert all(re.fullmatch(r"maxplus_\w*pallas\w*\.\d+", k) for k in kernels)
+    for kind in ("scatter-max", "scatter-min"):
+        fused = re.findall(
+            rf'^\s*%fusion\S* = [^\n]* fusion\([^\n]*op_name="([^"]*/{kind})"', text, re.M
+        )
+        assert fused and all("/option_scatter/" in p for p in fused), kind

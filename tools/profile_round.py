@@ -11,8 +11,8 @@ the engine's phase timings (``ClusterSim.last_round_profile``):
 
 With ``--fused`` the controller runs the device-resident fused round
 (DESIGN.md §14) and each row also shows the device/host split of the
-allocate phase (``alloc_device_s`` — seconds inside the jitted pipeline —
-plus which solver produced the round).  With ``--fused --churn > 0`` the
+allocate phase (the fused round's ``dispatch_s`` — seconds in the jitted
+pipeline call, launch and wait — plus which solver produced the round).  With ``--fused --churn > 0`` the
 allocate phase of each structure-changing round further breaks into the
 fused segments (DESIGN.md §17): ``prep`` (host row prep + layout),
 ``patch`` (donated dirty-row scatter), ``compact`` (device-side bank
@@ -181,10 +181,11 @@ def main() -> None:
     for r in range(args.rounds):
         total = one_round(r)
         prof = sim.last_round_profile
-        device_s = float(prof.get("alloc_device_s", 0.0))
         solver = str(prof.get("alloc_solver", "")) or "-"
         fallback = str(prof.get("alloc_fallback_reason", ""))
         segments = ctrl.fused_segments() if args.fused else {}
+        device_s = float(segments.get("dispatch_s", 0.0)) if solver == "fused" else 0.0
+        fstats = ctrl.fused_stats() if args.fused else None
         rounds.append({
             "round": r,
             "total_ms": total * 1e3,
@@ -200,15 +201,9 @@ def main() -> None:
                         s[:-2]: float(segments.get(s, 0.0)) * 1e3
                         for s in SEGMENTS
                     },
-                    "alloc_fused_rebuilds": prof.get(
-                        "alloc_fused_rebuilds", 0
-                    ),
-                    "alloc_fused_compactions": prof.get(
-                        "alloc_fused_compactions", 0
-                    ),
-                    "alloc_fused_slack_utilization": prof.get(
-                        "alloc_fused_slack_utilization", 0.0
-                    ),
+                    "alloc_fused_rebuilds": fstats.rebuilds,
+                    "alloc_fused_compactions": fstats.compactions,
+                    "alloc_fused_slack_utilization": fstats.slack_utilization,
                 }
                 if args.fused
                 else {}

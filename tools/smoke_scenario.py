@@ -342,8 +342,6 @@ def fused_churn_smoke(system, apps, surfs) -> None:
         f"resident banks were host-rebuilt {stats.rebuilds} times "
         f"(only the cold start may rebuild)"
     )
-    prof = pair[0][0].last_round_profile
-    assert prof["alloc_fused_rebuilds"] == stats.rebuilds
     elapsed = time.perf_counter() - t0
     assert elapsed < FUSED_CHURN_BUDGET_S, (
         f"fused-churn tier took {elapsed:.1f} s "
