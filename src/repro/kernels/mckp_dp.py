@@ -30,7 +30,10 @@ over (8, 128)-aligned tiles:
 Every index is explicit int32, so the kernel lowers the same under x64
 (CPU interpret mode, float64 values) and on the chip (float32 values).
 The sparse-option stage of the fused round reuses the same kernel: its
-options are scattered onto a dense per-row curve first.
+options are mapped onto a dense per-row curve first, and the kernel's
+winning shifts back to option indices after, each by a broadcast compare
+of the option/grid one-hot and a max or min along the option axis, which
+XLA fuses into vector code (no TPU scatter or gather).
 """
 
 from __future__ import annotations
@@ -135,46 +138,48 @@ def maxplus_stage_pallas_batched(
     device-resident round backtracks through (DESIGN.md §14).  Options
     sharing one spend resolve to the first of maximal value.
 
-    The options are scattered onto a dense per-row curve (max value per
-    spend, plus the option index that holds it) and run through the dense
-    kernel in descending shift order; every candidate is the same single
-    IEEE add ``dp + vb`` as the option loop, so results are bitwise the
-    option-order scan.  Keeps the input dtype (float64 under x64 for the
-    bit-for-bit CPU contract; float32 on the chip).
+    The options are mapped onto a dense per-row curve through their
+    one-hot ``kb[r, j] == b``: ``f[r, b]`` is the max value of the options
+    spending ``b`` (spends >= NB land nowhere).  The curve runs through the
+    dense kernel in descending shift order, and each winning shift maps
+    back to the first option of maximal value at that spend, a min over
+    option indices.  Max and min are exact in any order and ties are
+    resolved by index, so the mapping adds no rounding; every candidate
+    is the same single IEEE add ``dp + vb`` as the option loop, so
+    results are bitwise the option-order scan.  Keeps the input dtype
+    (float64 under x64 for the bit-for-bit CPU contract; float32 on the
+    chip).
     """
     if dp.ndim != 2 or kb.shape != vb.shape or kb.shape[0] != dp.shape[0]:
         raise ValueError(
             f"bad shapes dp={dp.shape} kb={kb.shape} vb={vb.shape}"
         )
-    r, nb = dp.shape
+    nb = dp.shape[1]
     k_opts = kb.shape[1]
     vb = vb.astype(dp.dtype)
     kb = kb.astype(jnp.int32)
-    rows = jnp.arange(r, dtype=jnp.int32)[:, None]
+    neg = jnp.asarray(-jnp.inf, dp.dtype)
     # the option mapping onto the dense curve and back, named for the
-    # trace.  The scatters index the curve flattened to [R * NB]: the TPU
-    # compiler flattens a 2-D scatter itself and drops its op metadata,
-    # while one built flat keeps it
+    # trace: broadcast compares of the option/grid one-hot reduced along
+    # the option axis (axis 1, so each result keeps the grid on the lanes)
     with jax.named_scope("option_scatter"):
-        # spends >= NB can never land in out[:, :NB]: drop them from the
-        # curve (flat index R * NB is out of range, so mode="drop" skips it)
-        k_in = jnp.where(kb < nb, kb, nb)
-        flat = jnp.where(kb < nb, rows * nb + kb, r * nb)
-        f = jnp.full((r * nb,), -jnp.inf, dp.dtype).at[flat].max(
-            vb, mode="drop"
-        ).reshape(r, nb)
-        top = vb == f[rows, jnp.minimum(k_in, nb - 1)]
-        jmap = jnp.full((r * nb,), k_opts, jnp.int32).at[
-            jnp.where(top, flat, r * nb)
-        ].min(
-            jnp.broadcast_to(jnp.arange(k_opts, dtype=jnp.int32), (r, k_opts)),
-            mode="drop",
-        ).reshape(r, nb)
+        # option j lands on grid point b iff kb[j] == b: spends >= NB land
+        # nowhere.  f[r, b] = max value of the options spending b
+        lands = kb[:, :, None] == jnp.arange(nb, dtype=jnp.int32)
+        f = jnp.max(jnp.where(lands, vb[:, :, None], neg), axis=1)
+        # top[r, j]: option j holds the maximal value of its spend, read
+        # against the options sharing that spend (== f[r, kb[r, j]])
+        same = kb[:, :, None] == kb[:, None, :]
+        top = vb == jnp.max(jnp.where(same, vb[:, :, None], neg), axis=1)
     out, arg_k = _maxplus_tiles(
         dp, f, n_shifts=nb, descending=True, interpret=interpret
     )
     with jax.named_scope("option_scatter"):
-        arg = jnp.take_along_axis(jmap, jnp.maximum(arg_k, 0), axis=1)
+        # back to option indices: the first top option whose spend is the
+        # winning shift (none where arg_k < 0, which maps to 0)
+        hit = top[:, :, None] & (kb[:, :, None] == arg_k[:, None, :])
+        opt = jnp.arange(k_opts, dtype=jnp.int32)[None, :, None]
+        arg = jnp.min(jnp.where(hit, opt, k_opts), axis=1)
         return out, jnp.where(arg_k < 0, 0, arg)
 
 

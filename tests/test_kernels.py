@@ -233,6 +233,44 @@ class TestMaxPlusStageBatched:
             np.testing.assert_array_equal(np.asarray(out), out_r)
             np.testing.assert_array_equal(np.asarray(arg), arg_r)
 
+    @pytest.mark.parametrize(
+        "case,r,nb,k",
+        [
+            ("shared_spends", 3, 32, 24),
+            ("past_grid", 3, 16, 12),
+            ("all_pads", 4, 16, 8),
+            ("k_over_nb", 2, 8, 40),
+            ("cell_k256", 2, 256, 256),
+            ("cell_k128", 2, 256, 128),
+        ],
+    )
+    def test_option_mapping_bitwise(self, case, r, nb, k):
+        """The one-hot option mapping in float64, bit-for-bit against the
+        option-order scan: options sharing a spend, equal values among
+        them (the first of maximal value wins), spends past the grid,
+        rows of pads only, more options than grid points, and the
+        benchmark cells' (K, NB) on a few rows."""
+        rng = np.random.default_rng(sum(map(ord, case)))
+        with jax.enable_x64(True):
+            dp, kb, vb = _stage_inputs(rng, r, nb, k, np.float64)
+            if case == "shared_spends":
+                # few spends and few values: runs of equal (spend, value)
+                kb = np.sort(rng.integers(0, 6, (r, k)), axis=1)[:, ::-1]
+                vb = rng.choice([0.125, 0.25, 0.375], (r, k))
+            elif case == "past_grid":
+                kb = np.sort(rng.integers(0, 2 * nb, (r, k)), axis=1)[:, ::-1]
+            elif case == "all_pads":
+                vb[1::2], kb[1::2] = -np.inf, 0
+            elif case == "k_over_nb":
+                vb = np.round(vb, 1)
+            kb = np.ascontiguousarray(kb, dtype=np.int32)
+            out, arg = mckp_dp.maxplus_stage_pallas_batched(
+                jnp.asarray(dp), jnp.asarray(kb), jnp.asarray(vb),
+            )
+            out_r, arg_r = _stage_ref_np(dp, kb, vb)
+            np.testing.assert_array_equal(np.asarray(out), out_r)
+            np.testing.assert_array_equal(np.asarray(arg), arg_r)
+
     def test_direct_vs_jitted_lowering(self):
         """Interpret-mode kernel: the direct call (primitive impl) and an
         explicit outer-jit XLA lowering produce identical bits.

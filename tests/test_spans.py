@@ -162,9 +162,9 @@ def test_fused_segments_keep_their_keys_and_dispatch_is_launch_plus_wait(fused_s
 
 def test_pipeline_ops_carry_the_scope_names():
     """The compiled pipeline (CPU, Pallas interpreted) names the option
-    scatter (the two scatters and the remap gather), each frontier wave
-    and the leaf backtrack in its ops' ``op_name`` metadata, the path a
-    trace's op metadata carries."""
+    mapping (the one-hot max onto the curve and the remap min), each
+    frontier wave and the leaf backtrack in its ops' ``op_name``
+    metadata, the path a trace's op metadata carries."""
     L, S, K, NB, NBT = 4, 3, 4, 16, 64
     ops_, depths, under, dom_rows = mckp._tree_ops(("d", 0, tuple(range(L))), L)
     waves = mckp._tree_waves(ops_, depths, under, NB, NBT)
@@ -178,7 +178,7 @@ def test_pipeline_ops_carry_the_scope_names():
         jax.ShapeDtypeStruct((len(dom_rows),), jnp.int32),
     ).compile().as_text()
     paths = set(re.findall(r'op_name="([^"]*)"', text))
-    for op in ("scatter-max", "scatter-min", "jit(take_along_axis)"):
+    for op in ("reduce_max", "reduce_min"):
         assert any("/leaf_scan/" in p and f"/option_scatter/{op}" in p for p in paths), op
     assert any(p.startswith("jit(run)/leaf_backtrack/") for p in paths)
     for w in range(len(waves)):

@@ -138,10 +138,12 @@ def test_fused_pipeline_compiles(topo, monkeypatch, shards):
 def test_fused_pipeline_keeps_its_names(topo):
     """What the trace reduction reads by name survives the TPU compiler:
     the Pallas custom calls keep their wrappers' names
-    (``maxplus_*_pallas_batched.N``), and the fusions that run the option
-    scatters keep the ``option_scatter`` scope in their ``op_name`` (the
-    compiler drops the metadata of a 2-D scatter that it flattens itself,
-    so the stage wrapper builds its scatters flat)."""
+    (``maxplus_*_pallas_batched.N``), and the option mapping of the stage
+    wrapper is dense compare-and-reduce: no scatter or gather is left in
+    the ``option_scatter`` scope, and the fusions the compiler makes of it
+    keep the scope in their ``op_name``, so ``option_scatter_ms`` cannot
+    silently read nothing (the compiler drops the metadata of ops it
+    rewrites, which then count under the enclosing ``while``)."""
     tree = _site_tree(L)
     sh = SingleDeviceSharding(topo.devices[0])
     run = mckp._fused_pipeline_fn.__wrapped__(
@@ -153,8 +155,14 @@ def test_fused_pipeline_keeps_its_names(topo):
     )
     assert len(kernels) == 1 + len(tree[0])  # the leaf stage, one per wave
     assert all(re.fullmatch(r"maxplus_\w*pallas\w*\.\d+", k) for k in kernels)
-    for kind in ("scatter-max", "scatter-min"):
-        fused = re.findall(
-            rf'^\s*%fusion\S* = [^\n]* fusion\([^\n]*op_name="([^"]*/{kind})"', text, re.M
-        )
-        assert fused and all("/option_scatter/" in p for p in fused), kind
+    # every instruction, fused or not, with its op name
+    named = re.findall(
+        r'^\s*(?:ROOT )?%\S+ = ([^\n]*?), metadata=\{[^\n]*?op_name="([^"]*)"',
+        text, re.M,
+    )
+    mapping = [(ins, p) for ins, p in named if "/option_scatter/" in p]
+    assert mapping
+    for ins, p in mapping:
+        assert not re.search(r"\s(scatter|gather)\(", ins), ins
+        assert not re.match(r"(scatter|gather)", p.rsplit("/", 1)[1]), p
+    assert any(re.search(r"\sfusion\(", ins) for ins, _ in mapping)
