@@ -911,7 +911,10 @@ class EcoShiftController(_OptionCachingController):
         options = self._options_for(receivers, baselines, surfaces)
         sol = self._solve(options, budget)
         return policies_mod.allocation_from_solution(
-            sol, baselines, budget, self.system.grid
+            sol,
+            *policies_mod.receiver_rows(receivers, baselines),
+            budget,
+            self.system.grid,
         )
 
     def _incremental_groups(self, batch: ReceiverBatch, leaf_ids=None):
@@ -997,7 +1000,7 @@ class EcoShiftController(_OptionCachingController):
                 )
         with Span("controller.allocation"):
             alloc = policies_mod.allocation_from_solution(
-                sol, batch.baselines_map(), budget, self.system.grid
+                sol, batch.names, batch.baselines, budget, self.system.grid
             )
             if key is not None:
                 self._alloc_cache[key] = alloc
@@ -1025,9 +1028,10 @@ class EcoShiftController(_OptionCachingController):
             unit=self.unit,
             backend=backend,
         )
+        names, base = policies_mod.receiver_rows(receivers, baselines)
         return [
             policies_mod.allocation_from_solution(
-                sol, baselines, budget, self.system.grid
+                sol, names, base, budget, self.system.grid
             )
             for budget, sol in zip(budgets, sols)
         ]
@@ -1270,7 +1274,7 @@ class EcoShiftHierController(EcoShiftController):
         self.last_domain_spent = sol.domain_spent
         with Span("controller.allocation"):
             alloc = policies_mod.allocation_from_solution(
-                sol, batch.baselines_map(), budget, self.system.grid
+                sol, batch.names, batch.baselines, budget, self.system.grid
             )
             if key is not None:
                 self._alloc_cache[key] = (alloc, sol.domain_spent)
@@ -1380,7 +1384,10 @@ class OracleController(_OptionCachingController):
             else mckp.solve_sparse(options, budget)
         )
         return policies_mod.allocation_from_solution(
-            sol, baselines, budget, self.system.grid
+            sol,
+            *policies_mod.receiver_rows(receivers, baselines),
+            budget,
+            self.system.grid,
         )
 
     def allocate_grouped(
@@ -1404,7 +1411,7 @@ class OracleController(_OptionCachingController):
             )
         )
         return policies_mod.allocation_from_solution(
-            sol, batch.baselines_map(), budget, self.system.grid
+            sol, batch.names, batch.baselines, budget, self.system.grid
         )
 
 

@@ -1208,21 +1208,6 @@ class ClusterSim:
         )
         if mode == "true" and not self._batch_surfaces_fresh(rows, batch):
             return None
-        # carry the name -> baseline map across patched batches: row
-        # baselines are immutable, so only joins/leaves/changes need
-        # touching (the map is read-only by convention)
-        prev_map = c_batch.__dict__.get("_baselines_map")
-        if prev_map is not None:
-            if len(joined) or len(left):
-                m = dict(prev_map)
-                for nm in batch.removed:
-                    m.pop(nm, None)
-                bl = batch.baselines
-                for p in batch.delta:
-                    m[names[p]] = (float(bl[p, 0]), float(bl[p, 1]))
-                object.__setattr__(batch, "_baselines_map", m)
-            else:
-                object.__setattr__(batch, "_baselines_map", prev_map)
         self._batch_cache = (mode, t.version, rows, batch)
         return batch
 

@@ -15,6 +15,7 @@ All policies share one signature and return a validated ``Allocation``:
 
 from __future__ import annotations
 
+import itertools
 from typing import Callable, Mapping, Sequence
 
 import numpy as np
@@ -27,6 +28,7 @@ from repro.core.types import (
     SystemSpec,
     as_receiver_order,
     validate_allocation,
+    validate_caps,
 )
 
 PolicyFn = Callable[..., Allocation]
@@ -34,19 +36,51 @@ PolicyFn = Callable[..., Allocation]
 
 def allocation_from_solution(
     sol: mckp.MCKPSolution,
-    baselines: Mapping[str, tuple[float, float]],
+    names: Sequence[str],
+    baselines: np.ndarray,
     budget: float,
     grid,
 ) -> Allocation:
     """Turn an MCKP solution's picks into a validated ``Allocation`` —
-    the shared assembly step of every DP policy and controller."""
-    alloc = Allocation(
-        caps={name: pick[2] for name, pick in sol.picks.items()},
+    the shared assembly step of every DP policy and controller.
+
+    ``names`` are the round's receivers and ``baselines`` their ``[n, 2]``
+    baseline caps in the same row order (a ``ReceiverBatch``'s own
+    arrays, or :func:`receiver_rows`).  The picks must cover exactly
+    those receivers.  One walk of ``names`` gathers the caps: the
+    ``Allocation.caps`` dict and the validated ``[n, 2]`` array are both
+    built from it, in row order (DESIGN.md §11.5).
+    """
+    picks = sol.picks
+    try:
+        caps = [picks[nm][2] for nm in names]
+    except KeyError as e:
+        raise ValueError(
+            f"solution has no pick for receiver {e.args[0]}"
+        ) from None
+    if len(picks) != len(caps):
+        present = set(names)
+        extra = next(nm for nm in picks if nm not in present)
+        raise ValueError(f"solution picks {extra}, which is not a receiver")
+    n = len(caps)
+    rows = np.fromiter(itertools.chain.from_iterable(caps), np.float64, 2 * n)
+    validate_caps(rows.reshape(n, 2), baselines, names, budget, grid)
+    return Allocation(
+        caps=dict(zip(names, caps)),
         spent=sol.spent,
         predicted_improvement=sol.average_improvement(),
     )
-    validate_allocation(alloc, baselines, budget, grid)
-    return alloc
+
+
+def receiver_rows(
+    receivers: Sequence[AppSpec], baselines: Mapping[str, tuple[float, float]]
+) -> tuple[list[str], np.ndarray]:
+    """Receiver names in :func:`as_receiver_order` and their ``[n, 2]``
+    baseline caps: the arrays :func:`allocation_from_solution` takes, for
+    callers that hold name-keyed baselines."""
+    names = [a.name for a in as_receiver_order(receivers)]
+    base = np.array([baselines[nm] for nm in names], dtype=np.float64)
+    return names, base.reshape(len(names), 2)
 
 
 def _headroom(baselines, name, system) -> tuple[float, float]:
@@ -220,7 +254,9 @@ def ecoshift(
             ),
         )
         sol = mckp.solve_grouped(groups, budget, solver=solver, unit=unit)
-        return allocation_from_solution(sol, baselines, budget, system.grid)
+        return allocation_from_solution(
+            sol, *receiver_rows(receivers, baselines), budget, system.grid
+        )
     options = [
         curves.build_options(
             a.name, surfaces[a.name], baselines[a.name], system.grid, budget
@@ -235,7 +271,9 @@ def ecoshift(
         sol = mckp.solve_dense_jax(options, budget, unit=unit, backend=solver)
     else:
         raise ValueError(f"unknown solver {solver!r}")
-    return allocation_from_solution(sol, baselines, budget, system.grid)
+    return allocation_from_solution(
+        sol, *receiver_rows(receivers, baselines), budget, system.grid
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -325,7 +363,9 @@ def ecoshift_hier(
         )
     root = domain_tree(topology, caps, groups_by_leaf)
     sol = mckp.solve_hierarchical(root, budget, solver=solver, unit=unit)
-    return allocation_from_solution(sol, baselines, budget, system.grid)
+    return allocation_from_solution(
+        sol, *receiver_rows(receivers, baselines), budget, system.grid
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -361,7 +401,9 @@ def oracle(
         if exhaustive
         else mckp.solve_sparse(options, budget)
     )
-    return allocation_from_solution(sol, baselines, budget, system.grid)
+    return allocation_from_solution(
+        sol, *receiver_rows(receivers, baselines), budget, system.grid
+    )
 
 
 POLICIES: dict[str, PolicyFn] = {

@@ -266,15 +266,6 @@ class ReceiverBatch:
     def __len__(self) -> int:
         return len(self.names)
 
-    def baselines_map(self) -> dict[str, tuple[float, float]]:
-        """name -> baseline caps dict, memoized on the (reused) batch."""
-        m = self.__dict__.get("_baselines_map")
-        if m is None:
-            pairs = self.baselines.tolist()
-            m = dict(zip(self.names, map(tuple, pairs)))
-            object.__setattr__(self, "_baselines_map", m)
-        return m
-
 
 def validate_allocation(
     alloc: Allocation,
@@ -284,19 +275,44 @@ def validate_allocation(
     *,
     atol: float = 1e-6,
 ) -> None:
-    """Invariant checks shared by tests and the emulator.
+    """Check a name-keyed allocation against name-keyed baselines.
 
-    1. every allocated cap is >= its baseline (monotonic upgrade model, §6.2)
+    The front door for callers that hold mappings (the paper-scale
+    policies, tests): it lines both up in the allocation's order and runs
+    :func:`validate_caps`, the one set of checks every allocation takes.
+    """
+    names = list(alloc.caps)
+    n = len(names)
+    validate_caps(
+        np.array([alloc.caps[nm] for nm in names], dtype=np.float64).reshape(n, 2),
+        np.array([baselines[nm] for nm in names], dtype=np.float64).reshape(n, 2),
+        names,
+        budget,
+        grid,
+        atol=atol,
+    )
+
+
+def validate_caps(
+    caps: np.ndarray,
+    baselines: np.ndarray,
+    names: Sequence[str],
+    budget: float,
+    grid: CapGrid,
+    *,
+    atol: float = 1e-6,
+) -> None:
+    """Invariant checks of an allocation, over row-aligned ``[n, 2]``
+    (cpu, gpu) arrays of caps and baseline caps (DESIGN.md §11.5).
+
+    1. every cap is >= its baseline (monotonic upgrade model, §6.2)
     2. every cap is inside the feasible grid range
     3. total extra power <= budget
+
+    ``names`` labels the rows in the error messages only.
     """
-    names = list(alloc.caps.keys())
-    if not names:
-        if 0.0 > budget + atol:
-            raise ValueError(f"allocation spends 0.0 W > budget {budget} W")
-        return
-    cg = np.array([alloc.caps[nm] for nm in names], dtype=np.float64)
-    base = np.array([baselines[nm] for nm in names], dtype=np.float64)
+    cg = np.asarray(caps, dtype=np.float64)
+    base = np.asarray(baselines, dtype=np.float64)
     below = (cg < base - atol).any(axis=1)
     if below.any():
         i = int(np.flatnonzero(below)[0])
@@ -313,7 +329,7 @@ def validate_allocation(
     if bad_g.any():
         i = int(np.flatnonzero(bad_g)[0])
         raise ValueError(f"{names[i]}: gpu cap {cg[i, 1]} outside grid")
-    extra = float(np.cumsum((cg - base).sum(axis=1))[-1])
+    extra = float(np.cumsum((cg - base).sum(axis=1))[-1]) if len(cg) else 0.0
     if extra > budget + atol:
         raise ValueError(f"allocation spends {extra} W > budget {budget} W")
 

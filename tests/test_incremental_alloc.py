@@ -20,7 +20,7 @@ except ImportError:  # image without hypothesis: property tests skip
 from repro.cluster import ClusterSim, PowerTopology, scenario as sc
 from repro.cluster.controller import make_controller
 from repro.cluster.sim import NodeTable
-from repro.core import curves, mckp, surfaces, types
+from repro.core import curves, mckp, policies, surfaces, types
 
 
 @pytest.fixture(scope="module")
@@ -924,3 +924,109 @@ class TestDeviceView:
                 np.asarray(got), getattr(sim.table, col).astype(np.float32)
             )
         assert str(view.domain_id.dtype) == "int32"
+
+
+# ---------------------------------------------------------------------------
+# Allocation assembly from the batch's row arrays (DESIGN.md §11.5)
+# ---------------------------------------------------------------------------
+
+
+def _name_keyed_allocation(sol, names, baselines, budget, grid):
+    """The Allocation as assembled by name: caps in the picks' own order,
+    validated against a name -> baseline mapping."""
+    alloc = types.Allocation(
+        caps={nm: pick[2] for nm, pick in sol.picks.items()},
+        spent=sol.spent,
+        predicted_improvement=sol.average_improvement(),
+    )
+    by_name = dict(zip(names, map(tuple, np.asarray(baselines).tolist())))
+    types.validate_allocation(alloc, by_name, budget, grid)
+    return alloc
+
+
+def _alloc_bits(alloc):
+    return (
+        sorted(
+            (nm, float(c).hex(), float(g).hex())
+            for nm, (c, g) in alloc.caps.items()
+        ),
+        float(alloc.spent).hex(),
+        float(alloc.predicted_improvement).hex(),
+    )
+
+
+@pytest.mark.parametrize("seed", range(2))
+@pytest.mark.parametrize(
+    "policy,kw,nack",
+    [
+        ("ecoshift", {}, False),
+        ("ecoshift_hier", {}, False),
+        ("ecoshift", {}, True),
+        ("ecoshift_hier", {}, True),
+        ("ecoshift", {"fused": True}, False),
+        ("ecoshift_hier", {"fused": True}, False),
+    ],
+    ids=["grouped", "hier", "pinned", "pinned_hier", "fused", "fused_hier"],
+)
+def test_allocation_from_batch_rows_matches_name_keyed(
+    suite, monkeypatch, policy, kw, nack, seed
+):
+    """Every controller path assembles from the batch's row arrays the
+    Allocation that the name-keyed construction gives, bit for bit, with
+    the caps in the batch's row order; under random budgets, stragglers,
+    phase changes and failures."""
+    from repro.cluster.faults import ActuationNack
+
+    system, apps, surfs = suite
+    rng = np.random.default_rng(seed)
+    assemble = policies.allocation_from_solution
+    sizes = []
+
+    def checked(sol, names, baselines, budget, grid):
+        alloc = assemble(sol, names, baselines, budget, grid)
+        ref = _name_keyed_allocation(sol, names, baselines, budget, grid)
+        assert _alloc_bits(alloc) == _alloc_bits(ref)
+        assert list(alloc.caps) == list(names)
+        sizes.append(len(names))
+        return alloc
+
+    monkeypatch.setattr(policies, "allocation_from_solution", checked)
+    n = int(rng.integers(32, 64))
+    hier = policy == "ecoshift_hier"
+    sim = ClusterSim.build(
+        system, apps[:8], surfs, n_nodes=n, seed=int(rng.integers(1000)),
+        initial_caps=(150.0, 150.0),
+        topology=(
+            PowerTopology.uniform_racks(n, 4, rack_cap=7000.0) if hier else None
+        ),
+    )
+    rounds = 6
+    budgets = [float(b) * 25.0 for b in rng.integers(36, 72, rounds)]
+    victims = iter(rng.permutation(n).tolist())
+    events = []
+    for r in range(1, rounds):
+        events += [
+            sc.StragglerOnset(
+                round=r, node_id=next(victims),
+                slowdown=float(rng.choice([1.4, 1.9])),
+            ),
+            sc.PhaseChange(
+                round=r, node_id=next(victims),
+                surface_id=apps[int(rng.integers(8))].name,
+            ),
+            sc.NodeFailure(round=r, node_ids=(next(victims),)),
+        ]
+    scen = sc.Scenario(rounds, budget=budgets).with_events(events)
+    if nack:
+        scen = scen.with_faults(
+            [ActuationNack(round=1, fraction=0.3, seed=seed)]
+        )
+    ctrl = make_controller(policy, system, **kw)
+    pinned = []
+    solve_pinned = ctrl._solve_pinned
+    ctrl._solve_pinned = lambda *a, **k: pinned.append(1) or solve_pinned(*a, **k)
+    sim.run(scen, ctrl)
+    assert sizes
+    assert bool(pinned) == nack
+    if kw.get("fused"):
+        assert ctrl.fused_stats().rounds > 0

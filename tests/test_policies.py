@@ -3,8 +3,15 @@
 import numpy as np
 import pytest
 
-from repro.core import policies, surfaces, types
-from repro.core.types import Allocation, AppSpec, CapGrid, SystemSpec, validate_allocation
+from repro.core import mckp, policies, surfaces, types
+from repro.core.types import (
+    Allocation,
+    AppSpec,
+    CapGrid,
+    SystemSpec,
+    validate_allocation,
+    validate_caps,
+)
 
 
 @pytest.fixture(scope="module")
@@ -118,16 +125,75 @@ class TestInvariants:
         np.testing.assert_allclose(alloc.caps["hi"], (325.0, 150.0))
         np.testing.assert_allclose(alloc.caps["lo"], (250.0, 175.0))
 
-    def test_validate_allocation_rejects_bad(self):
+    @pytest.mark.parametrize(
+        "caps,budget,text",
+        [
+            ({"x": (120.0, 150.0)}, 100, r"x: caps \(120.0,150.0\) below baseline"),
+            ({"x": (425.0, 150.0)}, 1000, "x: cpu cap 425.0 outside grid"),
+            ({"x": (140.0, 425.0)}, 1000, "x: gpu cap 425.0 outside grid"),
+            ({"x": (240.0, 150.0)}, 50, "allocation spends 100.0 W > budget 50 W"),
+            ({}, -1, "allocation spends 0.0 W > budget -1 W"),
+        ],
+        ids=["below_baseline", "cpu_off_grid", "gpu_off_grid", "over_budget", "empty"],
+    )
+    def test_validate_allocation_rejects_bad(self, caps, budget, text):
+        """The mapping front door, the array core and the solution assembly
+        refuse each bad allocation with the same message."""
         grid = types.SYSTEM_1.grid
         baselines = {"x": (140.0, 150.0)}
-        with pytest.raises(ValueError, match="below baseline"):
-            validate_allocation(
-                Allocation(caps={"x": (120.0, 150.0)}, spent=0), baselines, 100, grid
-            )
-        with pytest.raises(ValueError, match="> budget"):
-            validate_allocation(
-                Allocation(caps={"x": (240.0, 150.0)}, spent=100), baselines, 50, grid
+        names = list(caps)
+        rows = np.array([caps[nm] for nm in names]).reshape(len(names), 2)
+        base = np.array([baselines[nm] for nm in names]).reshape(len(names), 2)
+        sol = mckp.MCKPSolution(
+            total_value=0.0,
+            spent=0.0,
+            picks={nm: (0.0, 0.0, cg) for nm, cg in caps.items()},
+        )
+        messages = []
+        for check in (
+            lambda: validate_allocation(
+                Allocation(caps=caps, spent=0), baselines, budget, grid
+            ),
+            lambda: validate_caps(rows, base, names, budget, grid),
+            lambda: policies.allocation_from_solution(sol, names, base, budget, grid),
+        ):
+            with pytest.raises(ValueError, match=text) as err:
+                check()
+            messages.append(str(err.value))
+        assert len(set(messages)) == 1
+
+    @pytest.mark.parametrize(
+        "caps", [{}, {"x": (140.0, 150.0), "y": (400.0, 400.0)}], ids=["empty", "two"]
+    )
+    def test_validate_allocation_accepts_good(self, caps):
+        grid = types.SYSTEM_1.grid
+        baselines = {"x": (140.0, 150.0), "y": (150.0, 150.0)}
+        names = list(caps)
+        rows = np.array([caps[nm] for nm in names]).reshape(len(names), 2)
+        base = np.array([baselines[nm] for nm in names]).reshape(len(names), 2)
+        validate_allocation(Allocation(caps=caps, spent=0), baselines, 500, grid)
+        validate_caps(rows, base, names, 500, grid)
+
+    @pytest.mark.parametrize(
+        "names,text",
+        [
+            (["a", "b", "c"], "solution has no pick for receiver c"),
+            (["b"], "solution picks a, which is not a receiver"),
+        ],
+        ids=["missing", "extra"],
+    )
+    def test_allocation_from_solution_rejects_receiver_mismatch(self, names, text):
+        """The picks must cover exactly the receivers; the error names the
+        first one missing or extra."""
+        sol = mckp.MCKPSolution(
+            total_value=0.0,
+            spent=0.0,
+            picks={"a": (0.0, 0.0, (150.0, 150.0)), "b": (0.0, 0.0, (150.0, 150.0))},
+        )
+        base = np.full((len(names), 2), 150.0)
+        with pytest.raises(ValueError, match=text):
+            policies.allocation_from_solution(
+                sol, names, base, 1000.0, types.SYSTEM_1.grid
             )
 
     def test_ecoshift_at_least_heuristics_on_true_surfaces(self):
