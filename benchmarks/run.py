@@ -23,7 +23,6 @@ def main() -> None:
     from benchmarks import (
         budget_horizon,
         cluster_scaling,
-        dp_scaling,
         fault_storm,
         hier_alloc,
         incremental_alloc,
@@ -52,7 +51,6 @@ def main() -> None:
         ("fig9", fig9_distribution.run, True),
         ("fig10", fig10_oracle_gap.run, True),
         ("fig11", fig11_fairness.run, True),
-        ("dp_scaling", dp_scaling.run, True),
         ("cluster_scaling", cluster_scaling.run, True),
         ("hier_alloc", hier_alloc.run, True),
         ("incremental_alloc", incremental_alloc.run, True),

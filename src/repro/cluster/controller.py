@@ -156,7 +156,7 @@ class MixedAdaptiveController(_StatelessController):
 class ControllerConfig:
     """One construction config for every EcoShift-family controller.
 
-    The solver/grouping/fusion/predictor knobs grew organically across
+    The grouping/fusion/predictor knobs grew organically across
     ``EcoShiftController`` / ``EcoShiftHierController`` /
     ``EcoShiftOnlineController`` / ``OracleController``; this dataclass
     folds them into a single object so callers (and
@@ -176,8 +176,6 @@ class ControllerConfig:
     candidate count and allowance lattice.
     """
 
-    solver: str = "sparse"
-    unit: float = 1.0
     grouped: bool = True
     incremental: bool = True
     fused: bool = False
@@ -713,8 +711,6 @@ class EcoShiftController(_OptionCachingController):
         system: SystemSpec,
         *,
         config: ControllerConfig | None = None,
-        solver: str | None = None,
-        unit: float | None = None,
         allocator=None,
         grouped: bool | None = None,
         incremental: bool | None = None,
@@ -726,15 +722,12 @@ class EcoShiftController(_OptionCachingController):
     ):
         super().__init__(system)
         cfg = (config if config is not None else ControllerConfig()).merged(
-            solver=solver, unit=unit, allocator=allocator, grouped=grouped,
-            incremental=incremental, fused=fused, horizon=horizon,
-            eco_factor=eco_factor, plan_levels=plan_levels,
-            plan_grid=plan_grid,
+            allocator=allocator, grouped=grouped, incremental=incremental,
+            fused=fused, horizon=horizon, eco_factor=eco_factor,
+            plan_levels=plan_levels, plan_grid=plan_grid,
         )
         #: the resolved construction config (ControllerConfig)
         self.config = cfg
-        self.solver = cfg.solver
-        self.unit = cfg.unit
         #: optional repro.core.allocator.EcoShiftAllocator (warm NCF handle)
         self.allocator = cfg.allocator
         #: group-collapsed allocation (one DP super-stage per behaviour
@@ -750,13 +743,14 @@ class EcoShiftController(_OptionCachingController):
         #: pipeline as one jitted Pallas program.  Structure churn stays
         #: fused — rows patch or compact in place under the capacity-slack
         #: layout; only off-lattice keys / oversized grids / empty or
-        #: infeasible rounds route to the host sparse path.  Requires
-        #: ``incremental`` and ``solver='sparse'`` — otherwise silently
-        #: ignored.
+        #: infeasible rounds route to the host sparse path.  Applies on the
+        #: incremental path only (``incremental`` and engine-sequenced
+        #: batches); other rounds solve on the host.
         self.fused = cfg.fused
         #: resident device banks + capacity-slack layout for fused rounds
         self._fused_state = mckp.FusedState()
-        #: 'fused' | 'host' — which path produced the last solution
+        #: 'fused' | 'host' | 'cache' | 'pinned' — which path produced the
+        #: last solution
         self.last_solver: str | None = None
         #: why the last fused attempt routed to host ("" when it stayed
         #: fused, wasn't attempted, or hit the alloc cache) — mirrors
@@ -825,7 +819,6 @@ class EcoShiftController(_OptionCachingController):
             self.horizon > 1
             and self.eco_factor < 1.0
             and self._outlook is not None
-            and self.solver == "sparse"
         )
 
     def _plan_budget(self, budget: float, frontier_fn) -> float:
@@ -896,20 +889,9 @@ class EcoShiftController(_OptionCachingController):
     def supports_grouped(self) -> bool:  # type: ignore[override]
         return self.grouped
 
-    def _solve(self, options, budget) -> mckp.MCKPSolution:
-        if self.solver == "sparse":
-            return mckp.solve_sparse(options, budget)
-        if self.solver == "dense":
-            return mckp.solve_dense(options, budget, unit=self.unit)
-        if self.solver in ("jax", "pallas"):
-            return mckp.solve_dense_jax(
-                options, budget, unit=self.unit, backend=self.solver
-            )
-        raise ValueError(f"unknown solver {self.solver!r}")
-
     def allocate(self, receivers, baselines, budget, surfaces):
         options = self._options_for(receivers, baselines, surfaces)
-        sol = self._solve(options, budget)
+        sol = mckp.solve_sparse(options, budget)
         return policies_mod.allocation_from_solution(
             sol,
             *policies_mod.receiver_rows(receivers, baselines),
@@ -928,9 +910,9 @@ class EcoShiftController(_OptionCachingController):
         baseline) solve as one multiplicity-m DP super-stage — parity with
         :meth:`allocate` is certified by tests/test_grouped_alloc.py.
 
-        On the incremental path (default, sparse solver, engine-sequenced
-        batches) the behaviour-class grouping is delta-maintained across
-        rounds, the solve reuses content-keyed curve/pick/plan caches, and
+        On the incremental path (default, engine-sequenced batches) the
+        behaviour-class grouping is delta-maintained across rounds, the
+        solve reuses content-keyed curve/pick/plan caches, and
         a round whose classes and budget are unchanged returns the cached
         Allocation outright — bit-for-bit what a from-scratch solve
         produces (tests/test_incremental_alloc.py)."""
@@ -940,11 +922,7 @@ class EcoShiftController(_OptionCachingController):
             pins = {nm: c for nm, c in pins.items() if nm in present}
             if pins:
                 return self._solve_pinned(batch, budget, pins)
-        incremental = (
-            self.incremental
-            and self.solver == "sparse"
-            and getattr(batch, "seq", 0) != 0
-        )
+        incremental = self.incremental and getattr(batch, "seq", 0) != 0
         with Span("controller.grouping_sync"):
             if incremental:
                 self._incremental_groups(batch)
@@ -988,11 +966,9 @@ class EcoShiftController(_OptionCachingController):
         self.last_solver = "fused" if sol is not None else "host"
         if sol is None:
             with Span("controller.host_solve"):
-                sol = mckp.solve_grouped(
+                sol = mckp.solve_sparse_grouped(
                     groups,
                     budget,
-                    solver=self.solver,
-                    unit=self.unit,
                     curve_cache=self._agg_curves,
                     pick_cache=self._pick_cache if incremental else None,
                     plan_cache=self._plan_cache if incremental else None,
@@ -1006,36 +982,6 @@ class EcoShiftController(_OptionCachingController):
                 self._alloc_cache[key] = alloc
         return alloc
 
-    def allocate_batch(
-        self,
-        receivers: Sequence[AppSpec],
-        baselines: Mapping[str, tuple[float, float]],
-        budgets: Sequence[float],
-        surfaces: Mapping[str, PowerSurface],
-    ) -> list[Allocation]:
-        """Solve one receiver set under many budgets in a single vmapped
-        dense DP (option tables cached once, one accelerator dispatch).
-
-        Always solves on the dense ``unit``-watt budget grid regardless of
-        ``self.solver`` — with fractional option costs the unit rounding can
-        pick slightly different caps than a ``solver='sparse'``
-        :meth:`allocate` call at the same budget."""
-        options = self._options_for(receivers, baselines, surfaces)
-        backend = self.solver if self.solver in ("jax", "pallas") else "jax"
-        sols = mckp.solve_dense_jax_batch(
-            [options] * len(budgets),
-            list(budgets),
-            unit=self.unit,
-            backend=backend,
-        )
-        names, base = policies_mod.receiver_rows(receivers, baselines)
-        return [
-            policies_mod.allocation_from_solution(
-                sol, names, base, budget, self.system.grid
-            )
-            for budget, sol in zip(budgets, sols)
-        ]
-
 
 @policies_mod.register_controller("ecoshift_hier")
 class EcoShiftHierController(EcoShiftController):
@@ -1048,14 +994,11 @@ class EcoShiftHierController(EcoShiftController):
     becomes a capped value-vs-spend frontier, and the upper-level DP splits
     the cluster budget across domains (``mckp.solve_hierarchical``).
 
-    Warm state (``solver='sparse'``, the default): the shared
-    aggregate-curve cache plus a **frontier cache** keyed by (per-class
-    digest+multiplicity layout, quantized budget) — both content-keyed, so
-    telemetry-driven surface swaps invalidate implicitly (a swapped
-    surface digests differently and the stale entry stops matching).  The
-    dense ``'jax'``/``'pallas'`` path recomputes its layouts per round
-    (the warm tables still apply).  Passing ``predictor`` sources every
-    receiver surface
+    Warm state: the shared aggregate-curve cache plus a **frontier cache**
+    keyed by (per-class digest+multiplicity layout, quantized budget) —
+    both content-keyed, so telemetry-driven surface swaps invalidate
+    implicitly (a swapped surface digests differently and the stale entry
+    stops matching).  Passing ``predictor`` sources every receiver surface
     from a telemetry-driven :class:`~repro.cluster.predictor
     .OnlinePredictor` exactly like ``ecoshift_online``.
     """
@@ -1072,8 +1015,6 @@ class EcoShiftHierController(EcoShiftController):
         *,
         config: ControllerConfig | None = None,
         topology=None,
-        solver: str | None = None,
-        unit: float | None = None,
         predictor=None,
         allocator=None,
         incremental: bool | None = None,
@@ -1084,8 +1025,8 @@ class EcoShiftHierController(EcoShiftController):
         plan_grid: int | None = None,
     ):
         cfg = (config if config is not None else ControllerConfig()).merged(
-            topology=topology, solver=solver, unit=unit, predictor=predictor,
-            allocator=allocator, incremental=incremental, fused=fused,
+            topology=topology, predictor=predictor, allocator=allocator,
+            incremental=incremental, fused=fused,
             horizon=horizon, eco_factor=eco_factor, plan_levels=plan_levels,
             plan_grid=plan_grid,
         )
@@ -1178,7 +1119,7 @@ class EcoShiftHierController(EcoShiftController):
         ``domain_extra`` is the per-domain extra-power headroom (preorder
         ids, caps net of committed draw).
 
-        Incremental path (default, sparse solver): the per-leaf grouping is
+        Incremental path (default): the per-leaf grouping is
         delta-maintained from the batch, unchanged leaves reuse their
         frontier DPs / assembled solutions, dirty leaves re-aggregate
         through O(log n_leaves) tree combines, and a round whose classes,
@@ -1199,11 +1140,7 @@ class EcoShiftHierController(EcoShiftController):
                 return self._solve_pinned(
                     batch, budget, pins, domain_extra=domain_extra
                 )
-        incremental = (
-            self.incremental
-            and self.solver == "sparse"
-            and getattr(batch, "seq", 0) != 0
-        )
+        incremental = self.incremental and getattr(batch, "seq", 0) != 0
         state = None
         key = None
         with Span("controller.grouping_sync"):
@@ -1265,8 +1202,6 @@ class EcoShiftHierController(EcoShiftController):
                 sol = mckp.solve_hierarchical(
                     root,
                     budget,
-                    solver=self.solver,
-                    unit=self.unit,
                     curve_cache=self._agg_curves,
                     frontier_cache=self._frontiers,
                     state=state,
@@ -1314,11 +1249,9 @@ class EcoShiftOnlineController(EcoShiftController):
         *,
         predictor=None,
         config: ControllerConfig | None = None,
-        solver: str | None = None,
-        unit: float | None = None,
     ):
         cfg = (config if config is not None else ControllerConfig()).merged(
-            predictor=predictor, solver=solver, unit=unit
+            predictor=predictor
         )
         if cfg.predictor is None:
             raise ValueError("ecoshift_online needs a predictor")

@@ -76,7 +76,6 @@ class EcoShiftAllocator:
         baselines: Mapping[str, tuple[float, float]],
         budget: float,
         *,
-        solver: str = "sparse",
         surface_of: Mapping[str, str] | None = None,
     ) -> Allocation:
         """Solve one redistribution round on the *predicted* surfaces.
@@ -87,5 +86,5 @@ class EcoShiftAllocator:
         surface_of = surface_of or {a.name: a.name for a in receivers}
         surfaces = {a.name: self.predicted[surface_of[a.name]] for a in receivers}
         return policies.ecoshift(
-            receivers, baselines, budget, self.system, surfaces, solver=solver
+            receivers, baselines, budget, self.system, surfaces
         )

@@ -6,12 +6,11 @@ pairs on the feasible grid, compute the predicted relative improvement
 
  * keep only the best improvement at each distinct cost (Algorithm 1 l.2-18),
  * prune dominated options (an option is dominated if a cheaper-or-equal
-   option achieves >= improvement),
- * optionally densify to a monotone value-vs-budget curve F_i(b) on a 1 W
-   (or coarser) budget grid.
+   option achieves >= improvement).
 
-The sparse option table is what the faithful Algorithm-1 solver consumes;
-the dense curve feeds the vectorized/JAX/Pallas (max,+) DP.
+The resulting option table is what every solver in ``repro.core.mckp``
+consumes: the host DP directly, the device pipeline after mapping it onto
+its spend lattice.
 """
 
 from __future__ import annotations
@@ -95,68 +94,3 @@ def build_options(
             best = impr[j]
     sel = np.array(keep_idx)
     return OptionTable(name=name, costs=cost[sel], values=impr[sel], caps=pairs[sel])
-
-
-def dense_curve(
-    opts: OptionTable, budget: float, unit: float = 1.0
-) -> tuple[np.ndarray, np.ndarray]:
-    """Densify an option table to F_i(b) on a budget grid of ``unit`` watts.
-
-    Returns ``(F, choice)`` with ``F[b] = max improvement at cost <= b*unit``
-    (Eq. 1; monotone non-decreasing) and ``choice[b]`` the index into
-    ``opts`` realizing it.  Costs are *rounded up* to the next unit so the
-    densified solution never overspends.
-    """
-    nb = int(np.floor(budget / unit + 1e-9)) + 1
-    f = np.zeros(nb, dtype=np.float64)
-    choice = np.zeros(nb, dtype=np.int32)
-    cost_units = np.ceil(opts.costs / unit - 1e-9).astype(np.int64)
-    # scatter the best option onto each occupied grid position: sort by
-    # (unit cost asc, value desc, index asc) and keep each position's first
-    # row — the first option attaining the position's max value, exactly the
-    # strict-improvement sequential update; positions whose max value is
-    # <= 0 keep the (0, choice 0) default
-    valid = np.nonzero(cost_units < nb)[0]
-    if valid.size:
-        order = valid[
-            np.lexsort((valid, -opts.values[valid], cost_units[valid]))
-        ]
-        cu_s = cost_units[order]
-        first = np.ones(len(order), dtype=bool)
-        first[1:] = cu_s[1:] != cu_s[:-1]
-        take = order[first & (opts.values[order] > 0.0)]
-        f[cost_units[take]] = opts.values[take]
-        choice[cost_units[take]] = take
-    # running max to enforce "cost <= b": a position keeps its own choice iff
-    # it attains the running max (ties keep the later index, matching the
-    # sequential update which only overwrote on strict decrease)
-    run = np.maximum.accumulate(f)
-    kept = np.empty(nb, dtype=bool)
-    kept[0] = True
-    kept[1:] = f[1:] >= run[:-1]
-    src = np.maximum.accumulate(np.where(kept, np.arange(nb), 0))
-    return run, choice[src]
-
-
-def dense_curves_matrix(
-    options: list[OptionTable], budget: float, unit: float = 1.0
-) -> tuple[np.ndarray, np.ndarray]:
-    """Stack per-receiver dense curves: F [N, B+1], choices [N, B+1].
-
-    Receivers sharing an ``OptionTable`` object (group-collapsed clusters
-    replicate one table across a whole behaviour class) densify once; the
-    stacked result gathers the shared rows.
-    """
-    slot_of: dict[int, int] = {}
-    inv = np.empty(len(options), dtype=np.int64)
-    fs, chs = [], []
-    for i, o in enumerate(options):
-        slot = slot_of.get(id(o))
-        if slot is None:
-            slot = len(fs)
-            slot_of[id(o)] = slot
-            f, ch = dense_curve(o, budget, unit)
-            fs.append(f)
-            chs.append(ch)
-        inv[i] = slot
-    return np.stack(fs)[inv], np.stack(chs)[inv]

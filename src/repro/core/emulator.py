@@ -88,7 +88,6 @@ class ClusterEmulator:
         budget: float | None = None,
         *,
         policy_surfaces: Mapping[str, PowerSurface] | None = None,
-        solver: str = "sparse",
         receivers: Sequence[NodeState] | None = None,
     ) -> EmulationResult:
         """Apply ``policy`` and measure improvements on true surfaces.
@@ -97,8 +96,7 @@ class ClusterEmulator:
         EcoShift; defaults to true surfaces keyed per instance).  ``budget``
         defaults to the donor-derived reclaimed pool.
         """
-        kwargs = {"solver": solver} if policy == "ecoshift" else {}
-        controller = policies_mod.get_controller(policy, self.system, **kwargs)
+        controller = policies_mod.get_controller(policy, self.system)
         return self._sim().run_round(
             controller,
             budget=budget,

@@ -1,17 +1,18 @@
 """Multiple-choice-knapsack solvers for reclaimed-power distribution (§3.2.2).
 
-Three equivalent solvers (equivalence-tested against each other and against
-exhaustive brute force):
+Three roles, each certified against the others in the tests:
 
- * ``solve_sparse``   — faithful Algorithm 1: dict-keyed sparse DP over the
-                        distinct per-app extra-power levels, O(B * Σ K_i).
- * ``solve_dense``    — vectorized numpy DP over dense F_i(b) curves; each
-                        stage is a (max,+)-convolution restricted to the K_i
-                        option costs, O(B * Σ K_i) with numpy inner loops.
- * ``solve_dense_jax``— the same dense DP as a jit-compiled ``lax.scan``
-                        (one stage per receiver), used by the Pallas kernel
-                        path (repro.kernels.mckp_dp) and by the scaling
-                        benchmarks.
+ * the **host reference DP** — ``solve_sparse`` (faithful Algorithm 1: a
+   dict-keyed sparse DP over the distinct per-app extra-power levels,
+   O(B * Σ K_i)), its group-collapsed form ``solve_sparse_grouped`` and
+   the topology-aware ``solve_hierarchical``;
+ * the **device pipeline** — ``solve_grouped_fused`` and
+   ``solve_hierarchical_fused``: the leaf DPs, the frontier tree and the
+   backtrack resident on the accelerator (DESIGN.md §14/§16/§17),
+   bit-for-bit the host DP's decisions with float64 values (the CPU) and
+   within DESIGN.md §14's value bound with float32 values (a TPU); each
+   returns None where the round must fall back to the host DP;
+ * ``brute_force`` — exhaustive search, the oracle for small instances.
 
 All solvers return allocations in *watts spent per receiver* plus the cap
 pair realizing it, and they all respect the monotone-upgrade model: a
@@ -20,22 +21,11 @@ receiver may always take the zero-cost baseline option.
 **Group-collapsed solving** (DESIGN.md §11): real clusters replicate a small
 number of behaviour classes across thousands of nodes, so receivers sharing
 one option table collapse into a :class:`GroupedOptions` with multiplicity
-``m``:
-
- * ``solve_sparse_grouped``    — bounded MCKP: each group's m-fold aggregate
-                                 curve is built by binary-split (max,+)
-                                 self-convolution (O(log m) convolutions),
-                                 then one sparse DP runs over the ~G group
-                                 super-stages instead of the N receivers.
-                                 Bit-for-bit equal to ``solve_sparse`` on
-                                 the name-sorted ungrouped expansion.
- * ``solve_dense_jax_grouped`` — repeated-stage scan: the lax.scan walks a
-                                 per-receiver group-id sequence and gathers
-                                 its stage curve from a [G, NB] matrix, so
-                                 curves are densified once per group.
-                                 Bitwise identical to ``solve_dense_jax``
-                                 (same convolutions, same order).
- * ``solve_dense_grouped``     — the numpy analogue of the gather scan.
+``m``.  ``solve_sparse_grouped`` solves the bounded MCKP: each group's
+m-fold aggregate curve is built by binary-split (max,+) self-convolution
+(O(log m) convolutions), then one sparse DP runs over the ~G group
+super-stages instead of the N receivers — bit-for-bit equal to
+``solve_sparse`` on the name-sorted ungrouped expansion.
 
 **Hierarchical solving** (DESIGN.md §12): facilities cascade caps down a
 site → rack/PDU tree, so :func:`solve_hierarchical` turns each domain's
@@ -64,7 +54,7 @@ from typing import MutableMapping, Sequence
 
 import numpy as np
 
-from repro.core.curves import OptionTable, dense_curve, dense_curves_matrix
+from repro.core.curves import OptionTable
 from repro.core.spans import Span
 
 
@@ -344,34 +334,6 @@ def collapse_receivers(
         )
         for surf, base, members in classes.values()
     ]
-
-
-def solve_grouped(
-    groups: Sequence[GroupedOptions],
-    budget: float,
-    *,
-    solver: str = "sparse",
-    unit: float = 1.0,
-    curve_cache: MutableMapping | None = None,
-    pick_cache: MutableMapping | None = None,
-    plan_cache: MutableMapping | None = None,
-    chain_cache: MutableMapping | None = None,
-) -> MCKPSolution:
-    """Solver dispatch for the group-collapsed paths (see ``solve_*_grouped``)."""
-    if solver == "sparse":
-        return solve_sparse_grouped(
-            groups,
-            budget,
-            curve_cache=curve_cache,
-            pick_cache=pick_cache,
-            plan_cache=plan_cache,
-            chain_cache=chain_cache,
-        )
-    if solver == "dense":
-        return solve_dense_grouped(groups, budget, unit=unit)
-    if solver in ("jax", "pallas"):
-        return solve_dense_jax_grouped(groups, budget, unit=unit, backend=solver)
-    raise ValueError(f"unknown solver {solver!r}")
 
 
 def _dedupe_first_max(
@@ -846,15 +808,16 @@ def _superstage_dp_batch(
     leaf.  All leaves advance through their stages *together*: stage ``s``
     of every leaf is a single [L, K, NB] gather + argmax on the per-leaf
     integer spend lattice, replacing L x S per-leaf convolution calls with
-    S batched numpy ops (the sparse-path analogue of the Pallas
-    ``maxplus_conv_batched`` dispatch).  Per-leaf results — frontier keys,
-    values and backtracking stages — are **bitwise identical** to running
-    :func:`_superstage_dp` on each leaf alone: the candidate sets, float64
-    adds and (value desc, a-spend asc) tie-breaking are data-parallel
-    across leaves, padding rows are exact identities (+0.0), and per-leaf
-    feasibility masks mirror the per-stage pruning.  Returns None when any
-    leaf's keys leave the integer lattice or the padded grid would be
-    degenerate — callers then fall back to the per-leaf path.
+    S batched numpy ops (the host analogue of the fused pipeline's
+    ``maxplus_stage_pallas_batched`` leaf scan).  Per-leaf results —
+    frontier keys, values and backtracking stages — are **bitwise
+    identical** to running :func:`_superstage_dp` on each leaf alone: the
+    candidate sets, float64 adds and (value desc, a-spend asc)
+    tie-breaking are data-parallel across leaves, padding rows are exact
+    identities (+0.0), and per-leaf feasibility masks mirror the
+    per-stage pruning.  Returns None when any leaf's keys leave the
+    integer lattice or the padded grid would be degenerate — callers then
+    fall back to the per-leaf path.
     """
     L = len(jobs)
     per_leaf = []
@@ -1419,8 +1382,6 @@ def solve_hierarchical(
     root: DomainGroups,
     budget: float,
     *,
-    solver: str = "sparse",
-    unit: float = 1.0,
     curve_cache: MutableMapping | None = None,
     frontier_cache: MutableMapping | None = None,
     state: HierState | None = None,
@@ -1434,9 +1395,8 @@ def solve_hierarchical(
     → row → PDU → ... → leaf), then backtracks down to the per-receiver
     picks.  Every domain's spend is <= its cap by construction, and with a
     single root domain whose cap >= the cluster budget the result is
-    **bit-for-bit** ``solve_sparse_grouped`` (``solver='sparse'``) /
-    ``solve_dense_jax_grouped`` (``solver='jax'`` / ``'pallas'``) —
-    certified by tests/test_hier_alloc.py.
+    **bit-for-bit** ``solve_sparse_grouped`` — certified by
+    tests/test_hier_alloc.py.
 
     Passing a persistent :class:`HierState` makes warm re-solves
     incremental (O(what changed) — see the class docstring) while staying
@@ -1446,27 +1406,23 @@ def solve_hierarchical(
     Returns a solution whose ``domain_spent`` maps each domain name to the
     watts spent inside it.
     """
-    if solver == "sparse":
-        st = state if state is not None else HierState(curve_cache, frontier_cache)
-        _prime_leaf_frontiers(root, float(budget), st)
-        f = _sparse_frontier(root, float(budget), st)
-        u = float(f.keys[int(np.argmax(f.vals))])
-        picks: dict[str, tuple[float, float, tuple[float, float]]] = {}
-        domain_spent: dict[str, float] = {}
-        leaf_totals: list[tuple[float, float]] = []
-        _backtrack_frontier(f, u, st, picks, domain_spent, leaf_totals)
-        total = 0.0
-        spent = 0.0
-        for lt, ls in leaf_totals:
-            total += lt
-            spent += ls
-        return MCKPSolution(
-            total_value=total, spent=spent, picks=picks,
-            domain_spent=domain_spent,
-        )
-    if solver in ("jax", "pallas"):
-        return _solve_hier_dense(root, float(budget), unit=unit, backend=solver)
-    raise ValueError(f"unknown hierarchical solver {solver!r}")
+    st = state if state is not None else HierState(curve_cache, frontier_cache)
+    _prime_leaf_frontiers(root, float(budget), st)
+    f = _sparse_frontier(root, float(budget), st)
+    u = float(f.keys[int(np.argmax(f.vals))])
+    picks: dict[str, tuple[float, float, tuple[float, float]]] = {}
+    domain_spent: dict[str, float] = {}
+    leaf_totals: list[tuple[float, float]] = []
+    _backtrack_frontier(f, u, st, picks, domain_spent, leaf_totals)
+    total = 0.0
+    spent = 0.0
+    for lt, ls in leaf_totals:
+        total += lt
+        spent += ls
+    return MCKPSolution(
+        total_value=total, spent=spent, picks=picks,
+        domain_spent=domain_spent,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -1958,7 +1914,7 @@ def _fused_pipeline_fn(
                 left = buf[rows([op[0] for op in wave])]
                 right = buf[rows([op[1] for op in wave]), :k_level]
                 out, t_right = _mk.maxplus_conv_pallas_batched(
-                    left, right, descending=True, interpret=interpret
+                    left, right, interpret=interpret
                 )
                 tc = tcuts[rows([op[3] for op in wave])]
                 out = jnp.where(t_idx_tree[None, :] > tc[:, None], neg, out)
@@ -2670,602 +2626,6 @@ def solve_hierarchical_fused(
         fstate=fstate,
         st=state,
     )
-
-
-# ---------------------------------------------------------------------------
-# Dense-grid DP (numpy)
-# ---------------------------------------------------------------------------
-
-
-def _stage_maxplus(
-    dp: np.ndarray,
-    costs_u: np.ndarray,
-    values: np.ndarray,
-    chunk: int | None = None,
-) -> tuple[np.ndarray, np.ndarray]:
-    """One (max,+) stage restricted to option costs.
-
-    dp' [b] = max_j dp[b - cost_j] + value_j   (invalid b-cost_j masked)
-    Returns (dp', argmax_j) with first-max tie-breaking.  ``chunk`` bounds
-    the [k, chunk] candidate tile for stages with many costs (the full
-    (max,+) convolution of the hierarchical dense path, where costs_u is
-    the whole budget grid); columns are independent, so chunking is
-    bitwise-neutral.
-    """
-    nb = dp.shape[0]
-    if chunk is None:
-        chunk = nb
-    out = np.empty(nb, dtype=np.float64)
-    arg = np.empty(nb, dtype=np.int32)
-    for b0 in range(0, nb, chunk):
-        b = np.arange(b0, min(b0 + chunk, nb))
-        # cand[j, b] = dp[b - c_j] + v_j
-        idx = b[None, :] - costs_u[:, None]  # [k, chunk]
-        valid = idx >= 0
-        cand = (
-            np.where(valid, dp[np.clip(idx, 0, nb - 1)], -np.inf)
-            + values[:, None]
-        )
-        a = np.argmax(cand, axis=0)
-        out[b] = cand[a, np.arange(len(b))]
-        arg[b] = a
-    return out, arg
-
-
-def solve_dense(
-    options: Sequence[OptionTable], budget: float, unit: float = 1.0
-) -> MCKPSolution:
-    """Vectorized dense DP at ``unit``-watt budget granularity."""
-    nb = int(np.floor(budget / unit + 1e-9)) + 1
-    dp = np.zeros(nb, dtype=np.float64)
-    args: list[np.ndarray] = []
-    costs_per_app: list[np.ndarray] = []
-    kept_per_app: list[np.ndarray] = []
-    for opt in options:
-        cu = np.ceil(opt.costs / unit - 1e-9).astype(np.int64)
-        keep = cu < nb
-        cu, vals = cu[keep], opt.values[keep]
-        dp, arg = _stage_maxplus(dp, cu, vals)
-        args.append(arg)
-        costs_per_app.append(cu)
-        kept_per_app.append(np.nonzero(keep)[0])
-
-    b = int(np.argmax(dp))
-    total = float(dp[b])
-    picks: dict[str, tuple[float, float, tuple[float, float]]] = {}
-    for i in range(len(options) - 1, -1, -1):
-        opt = options[i]
-        j_local = int(args[i][b])
-        j = int(kept_per_app[i][j_local])
-        picks[opt.name] = (
-            float(opt.costs[j]),
-            float(opt.values[j]),
-            (float(opt.caps[j, 0]), float(opt.caps[j, 1])),
-        )
-        b -= int(costs_per_app[i][j_local])
-    spent = sum(c for c, _, _ in picks.values())
-    return MCKPSolution(total_value=total, spent=spent, picks=picks)
-
-
-def _grouped_dense_layout(
-    groups: Sequence[GroupedOptions], budget: float, unit: float
-):
-    """Digest-merged stage layout shared by the grouped dense solvers.
-
-    Returns ``(names, stage_gids, tables, f_groups, ch_groups)``: the
-    name-sorted receiver order, each receiver's behaviour-class id, and the
-    per-class tables / dense curves — densified once per class instead of
-    once per receiver.
-    """
-    classes = _merge_classes(groups)
-    pairs = sorted(
-        (name, cid)
-        for cid, (_, members, _) in enumerate(classes)
-        for name in members
-    )
-    names = [p[0] for p in pairs]
-    stage_gids = np.array([p[1] for p in pairs], dtype=np.int32)
-    tables = [c[0] for c in classes]
-    fs, chs = [], []
-    for table in tables:
-        f, ch = dense_curve(table, budget, unit)
-        fs.append(f)
-        chs.append(ch)
-    return names, stage_gids, tables, np.stack(fs), np.stack(chs)
-
-
-def solve_dense_grouped(
-    groups: Sequence[GroupedOptions], budget: float, unit: float = 1.0
-) -> MCKPSolution:
-    """Grouped numpy dense DP: per-class cost/value prep, one stage per
-    receiver — bitwise identical to ``solve_dense`` on the name-sorted
-    ungrouped expansion (same stage convolutions in the same order)."""
-    nb = int(np.floor(budget / unit + 1e-9)) + 1
-    names, stage_gids, tables, _, _ = _grouped_dense_layout(
-        groups, budget, unit
-    )
-    cu_of, vals_of, kept_of = [], [], []
-    for table in tables:
-        cu = np.ceil(table.costs / unit - 1e-9).astype(np.int64)
-        keep = cu < nb
-        cu_of.append(cu[keep])
-        vals_of.append(table.values[keep])
-        kept_of.append(np.nonzero(keep)[0])
-
-    dp = np.zeros(nb, dtype=np.float64)
-    args: list[np.ndarray] = []
-    for gid in stage_gids:
-        dp, arg = _stage_maxplus(dp, cu_of[gid], vals_of[gid])
-        args.append(arg)
-
-    b = int(np.argmax(dp))
-    total = float(dp[b])
-    picks: dict[str, tuple[float, float, tuple[float, float]]] = {}
-    for i in range(len(names) - 1, -1, -1):
-        gid = stage_gids[i]
-        table = tables[gid]
-        j_local = int(args[i][b])
-        j = int(kept_of[gid][j_local])
-        picks[names[i]] = (
-            float(table.costs[j]),
-            float(table.values[j]),
-            (float(table.caps[j, 0]), float(table.caps[j, 1])),
-        )
-        b -= int(cu_of[gid][j_local])
-    spent = sum(c for c, _, _ in picks.values())
-    return MCKPSolution(total_value=total, spent=spent, picks=picks)
-
-
-# ---------------------------------------------------------------------------
-# Dense-grid DP (JAX, scan over receivers)
-# ---------------------------------------------------------------------------
-
-
-def _jax_dp(f_mat, backend: str = "jax"):
-    """jit-compiled forward DP over dense curves.
-
-    f_mat: [N, NB] monotone curves (F_i). Returns (dp_final [NB],
-    argk [N, NB]) where argk[i, b] is the spend chosen for receiver i when b
-    units are available to receivers 0..i.
-
-    The inner maximization DP'[b] = max_k DP[b-k] + F[k] is a full
-    (max,+)-convolution; ``backend='pallas'`` routes it through the Pallas
-    TPU kernel (repro.kernels.mckp_dp), 'jax' uses a pure-jnp masked gather.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    if backend == "pallas":
-        from repro.kernels import ops as kops
-
-        conv = kops.maxplus_conv
-    else:
-        from repro.kernels import ref as kref
-
-        conv = kref.maxplus_conv
-
-    def stage(dp, f_row):
-        out, arg = conv(dp, f_row)
-        return out, arg
-
-    @jax.jit
-    def run(f_mat):
-        dp0 = jnp.zeros(f_mat.shape[1], dtype=f_mat.dtype)
-        dp_final, args = jax.lax.scan(stage, dp0, f_mat)
-        return dp_final, args
-
-    return run(f_mat)
-
-
-def solve_dense_jax(
-    options: Sequence[OptionTable],
-    budget: float,
-    unit: float = 1.0,
-    backend: str = "jax",
-) -> MCKPSolution:
-    """Dense DP via jit'd lax.scan (+ optional Pallas (max,+) kernel)."""
-    import numpy as np
-
-    f_mat, choices = dense_curves_matrix(list(options), budget, unit)
-    dp_final, args = _jax_dp(f_mat, backend=backend)
-    dp_final = np.asarray(dp_final)
-    args = np.asarray(args)
-
-    b = int(np.argmax(dp_final))
-    total = float(dp_final[b])
-    picks: dict[str, tuple[float, float, tuple[float, float]]] = {}
-    for i in range(len(options) - 1, -1, -1):
-        opt = options[i]
-        k = int(args[i, b])  # units granted to receiver i
-        j = int(choices[i][k])  # option index realizing F_i(k)
-        picks[opt.name] = (
-            float(opt.costs[j]),
-            float(opt.values[j]),
-            (float(opt.caps[j, 0]), float(opt.caps[j, 1])),
-        )
-        b -= k
-    spent = sum(c for c, _, _ in picks.values())
-    return MCKPSolution(total_value=total, spent=spent, picks=picks)
-
-
-def _jax_dp_gather(f_groups, stage_gids, backend: str = "jax"):
-    """Repeated-stage forward DP: scan over group ids, gathering each
-    stage's curve from the [G, NB] class matrix.  Same convolutions in the
-    same order as ``_jax_dp`` on the row-expanded matrix — bitwise equal —
-    without materializing [N, NB] curves."""
-    import jax
-    import jax.numpy as jnp
-
-    if backend == "pallas":
-        from repro.kernels import ops as kops
-
-        return kops.maxplus_scan(f_groups, stage_gids)
-
-    from repro.kernels import ref as kref
-
-    @jax.jit
-    def run(f_groups, gids):
-        def stage(dp, gid):
-            out, arg = kref.maxplus_conv(dp, f_groups[gid])
-            return out, arg
-
-        dp0 = jnp.zeros(f_groups.shape[1], dtype=f_groups.dtype)
-        return jax.lax.scan(stage, dp0, gids)
-
-    return run(f_groups, jnp.asarray(stage_gids))
-
-
-def _gather_backtrack(
-    layout,
-    args: np.ndarray,
-    b: int,
-    picks: dict[str, tuple[float, float, tuple[float, float]]],
-) -> float:
-    """Walk a gather scan's argmaxes from ``b`` granted units down to
-    per-receiver picks (reverse stage order, the dense solvers' shared
-    backtrack); returns the watts actually spent."""
-    names, stage_gids, tables, _, ch_groups = layout
-    spent = 0.0
-    for i in range(len(names) - 1, -1, -1):
-        gid = stage_gids[i]
-        table = tables[gid]
-        k = int(args[i, b])  # units granted to receiver i
-        j = int(ch_groups[gid][k])  # option index realizing F(k)
-        picks[names[i]] = (
-            float(table.costs[j]),
-            float(table.values[j]),
-            (float(table.caps[j, 0]), float(table.caps[j, 1])),
-        )
-        spent += float(table.costs[j])
-        b -= k
-    return spent
-
-
-def solve_dense_jax_grouped(
-    groups: Sequence[GroupedOptions],
-    budget: float,
-    unit: float = 1.0,
-    backend: str = "jax",
-) -> MCKPSolution:
-    """Grouped dense DP via the repeated-stage gather scan.
-
-    Bitwise identical to ``solve_dense_jax`` on the name-sorted ungrouped
-    expansion; curves are densified once per behaviour class and the scan
-    gathers its stage row by class id (jax or Pallas (max,+) kernel)."""
-    layout = _grouped_dense_layout(groups, budget, unit)
-    _, stage_gids, _, f_groups, _ = layout
-    dp_final, args = _jax_dp_gather(f_groups, stage_gids, backend=backend)
-    dp_final = np.asarray(dp_final)
-    args = np.asarray(args)
-
-    b = int(np.argmax(dp_final))
-    total = float(dp_final[b])
-    picks: dict[str, tuple[float, float, tuple[float, float]]] = {}
-    spent = _gather_backtrack(layout, args, b, picks)
-    return MCKPSolution(total_value=total, spent=spent, picks=picks)
-
-
-# ---------------------------------------------------------------------------
-# Hierarchical dense-grid solve (domain frontiers on the unit budget grid)
-# ---------------------------------------------------------------------------
-
-
-class _DenseFrontier:
-    """Dense analogue of :class:`_SparseFrontier`: ``f[k]`` is the domain's
-    best value at spend ``k`` units (length min(cap, budget)//unit + 1 — the
-    cap restriction is the truncation).  Leaves keep their grouped dense
-    layout for backtracking; internal domains keep per-child conv argmaxes.
-    """
-
-    __slots__ = ("dom", "f", "args", "layout", "children")
-
-    def __init__(self, dom, f, args, layout=None, children=None):
-        self.dom: DomainGroups = dom
-        self.f: np.ndarray = f
-        self.args = args
-        self.layout = layout
-        self.children: list["_DenseFrontier"] | None = children
-
-
-def _conv_full(dp: np.ndarray, f: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    """Full (max,+) convolution: out[b] = max_k dp[b-k] + f[k].
-
-    ``f`` may be shorter than ``dp`` (a capped child frontier).  One
-    :func:`_stage_maxplus` stage whose "options" are every grid spend,
-    b-chunked so the candidate tile stays bounded."""
-    return _stage_maxplus(dp, np.arange(len(f)), f, chunk=512)
-
-
-#: padded-element ceiling for the single-dispatch batched leaf solve
-#: (L x N x NB argmax tables); beyond it leaves solve one by one
-_BATCH_LEAF_MAX_ELEMS = 150_000_000
-
-
-@functools.cache
-def _ref_scan_batched_fn():
-    """Jitted jax-reference batched leaf scan, built once per process —
-    re-jitting per call would retrace the whole scan every round."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels import ref as kref
-
-    @jax.jit
-    def run(f_banks, gids):
-        rows_idx = jnp.arange(f_banks.shape[0])
-
-        def stage(dp, gid_col):
-            rows = f_banks[rows_idx, gid_col]
-            out, arg = jax.vmap(kref.maxplus_conv)(dp, rows)
-            return out, arg
-
-        dp0 = jnp.zeros(
-            (f_banks.shape[0], f_banks.shape[2]), dtype=f_banks.dtype
-        )
-        dp_final, args = jax.lax.scan(stage, dp0, gids.T)
-        return dp_final, args.swapaxes(0, 1)
-
-    return run
-
-
-def _batch_dense_leaves(
-    root: DomainGroups, budget: float, unit: float, backend: str
-) -> dict[int, tuple]:
-    """Single-dispatch batched solve of every non-empty leaf's gather scan.
-
-    Collects each leaf's (groups, eff) pair, densifies every leaf's class
-    curves on the *widest* leaf grid, pads class banks with the identity
-    curve and stage sequences with the identity class id, and runs one
-    ``ops.maxplus_scan_batched`` (or the jax reference equivalent) for all
-    leaves.  Per-leaf slices are bitwise what the per-leaf scan returns:
-    grid positions past a leaf's own budget never influence positions
-    inside it, and identity stages are exact (+0.0) no-ops.  Returns
-    {id(dom): (layout, dp_final, args)}; empty when batching is
-    inapplicable (single leaf, or padded size beyond the ceiling).
-    """
-    leaves: list[tuple[DomainGroups, float]] = []
-
-    def walk(dom: DomainGroups, b: float) -> None:
-        eff = _domain_eff(dom, b)
-        if dom.children:
-            for c in dom.children:
-                walk(c, eff)
-        elif dom.groups:
-            leaves.append((dom, eff))
-
-    walk(root, float(budget))
-    if len(leaves) < 2:
-        return {}
-    nbs = [int(np.floor(eff / unit + 1e-9)) + 1 for _, eff in leaves]
-    nb_max = max(nbs)
-    layouts = [
-        _grouped_dense_layout(dom.groups, (nb_max - 1) * unit, unit)
-        for dom, _ in leaves
-    ]
-    g_max = max(lay[3].shape[0] for lay in layouts)
-    n_max = max(len(lay[1]) for lay in layouts)
-    if len(leaves) * n_max * nb_max > _BATCH_LEAF_MAX_ELEMS:
-        return {}
-    identity = np.full(nb_max, -np.inf)
-    identity[0] = 0.0
-    f_banks = np.empty((len(leaves), g_max + 1, nb_max), dtype=np.float64)
-    gids_pad = np.empty((len(leaves), n_max), dtype=np.int32)
-    for li, lay in enumerate(layouts):
-        _, stage_gids, _, f_groups, _ = lay
-        g_l, n_l = f_groups.shape[0], len(stage_gids)
-        f_banks[li, :g_l] = f_groups
-        f_banks[li, g_l:] = identity
-        gids_pad[li, :n_l] = stage_gids
-        gids_pad[li, n_l:] = g_l  # identity stage: dp + 0.0
-    if backend == "pallas":
-        from repro.kernels import ops as kops
-
-        dp_all, args_all = kops.maxplus_scan_batched(f_banks, gids_pad)
-    else:
-        dp_all, args_all = _ref_scan_batched_fn()(f_banks, gids_pad)
-    dp_all = np.asarray(dp_all)
-    args_all = np.asarray(args_all)
-    out: dict[int, tuple] = {}
-    for li, ((dom, _), lay, nb) in enumerate(zip(leaves, layouts, nbs)):
-        n_l = len(lay[1])
-        out[id(dom)] = (lay, dp_all[li, :nb], args_all[li, :n_l, :nb])
-    return out
-
-
-def _dense_frontier(
-    dom: DomainGroups,
-    budget: float,
-    unit: float,
-    backend: str,
-    batched: dict[int, tuple] | None = None,
-) -> _DenseFrontier:
-    """Capped dense frontier of one domain on the ``unit``-watt grid.
-
-    A leaf runs the repeated-stage gather scan of its groups (the same
-    convolutions as ``solve_dense_jax_grouped``, so a single root with
-    cap >= budget is bitwise identical to the flat solve) — or picks up
-    its slice of the single-dispatch batched solve when one ran; an
-    internal domain convolves its children's truncated frontiers in numpy.
-    """
-    eff = _domain_eff(dom, budget)
-    nb = int(np.floor(eff / unit + 1e-9)) + 1
-    if dom.children:
-        subs = [
-            _dense_frontier(c, eff, unit, backend, batched)
-            for c in dom.children
-        ]
-        dp = np.zeros(nb, dtype=np.float64)
-        args: list[np.ndarray] = []
-        for sub in subs:
-            dp, arg = _conv_full(dp, sub.f)
-            args.append(arg)
-        return _DenseFrontier(dom, dp, args, children=subs)
-    if not dom.groups:
-        # no receivers under this leaf: zero spend or nothing
-        f = np.full(nb, -np.inf)
-        f[0] = 0.0
-        return _DenseFrontier(dom, f, None, layout=None)
-    hit = batched.get(id(dom)) if batched else None
-    if hit is not None:
-        layout, dp_final, args_arr = hit
-        return _DenseFrontier(dom, dp_final, args_arr, layout=layout)
-    layout = _grouped_dense_layout(dom.groups, eff, unit)
-    _, stage_gids, _, f_groups, _ = layout
-    dp_final, args = _jax_dp_gather(f_groups, stage_gids, backend=backend)
-    return _DenseFrontier(
-        dom, np.asarray(dp_final), np.asarray(args), layout=layout
-    )
-
-
-def _backtrack_dense(
-    fr: _DenseFrontier,
-    b: int,
-    picks: dict[str, tuple[float, float, tuple[float, float]]],
-    domain_spent: dict[str, float],
-) -> float:
-    """Walk ``b`` granted units down the frontier tree into picks; returns
-    the watts actually spent inside this domain."""
-    spent = 0.0
-    if fr.children is not None:
-        for i in range(len(fr.children) - 1, -1, -1):
-            k = int(fr.args[i][b])
-            spent += _backtrack_dense(fr.children[i], k, picks, domain_spent)
-            b -= k
-    elif fr.layout is not None:
-        spent = _gather_backtrack(fr.layout, fr.args, b, picks)
-    domain_spent[fr.dom.name] = spent
-    return spent
-
-
-def _solve_hier_dense(
-    root: DomainGroups,
-    budget: float,
-    *,
-    unit: float = 1.0,
-    backend: str = "jax",
-) -> MCKPSolution:
-    """Dense-grid hierarchical solve (see :func:`solve_hierarchical`)."""
-    batched = _batch_dense_leaves(root, budget, unit, backend)
-    fr = _dense_frontier(root, budget, unit, backend, batched)
-    b = int(np.argmax(fr.f))
-    total = float(fr.f[b])
-    picks: dict[str, tuple[float, float, tuple[float, float]]] = {}
-    domain_spent: dict[str, float] = {}
-    _backtrack_dense(fr, b, picks, domain_spent)
-    spent = sum(c for c, _, _ in picks.values())
-    return MCKPSolution(
-        total_value=total, spent=spent, picks=picks, domain_spent=domain_spent
-    )
-
-
-def _jax_dp_batch(f_mats, backend: str = "jax"):
-    """Batched forward DP over R independent rounds.
-
-    f_mats: [R, N, NB].  Returns (dp_final [R, NB], args [R, N, NB]): one
-    scan over the N receiver stages where each stage is the *batched*
-    (max,+) convolution over all R rounds at once (vmap over the Pallas
-    kernel for ``backend='pallas'``).
-    """
-    import jax
-    import jax.numpy as jnp
-
-    if backend == "pallas":
-        from repro.kernels import ops as kops
-
-        conv_b = kops.maxplus_conv_batched
-    else:
-        from repro.kernels import ref as kref
-
-        def conv_b(dp, f):
-            return jax.vmap(kref.maxplus_conv)(dp, f)
-
-    def stage(dp, f_rows):  # dp, f_rows: [R, NB]
-        out, arg = conv_b(dp, f_rows)
-        return out, arg
-
-    @jax.jit
-    def run(f_mats):
-        r, _, nb = f_mats.shape
-        dp0 = jnp.zeros((r, nb), dtype=f_mats.dtype)
-        dp_final, args = jax.lax.scan(stage, dp0, f_mats.swapaxes(0, 1))
-        return dp_final, args.swapaxes(0, 1)
-
-    return run(f_mats)
-
-
-def solve_dense_jax_batch(
-    rounds: Sequence[Sequence[OptionTable]],
-    budgets: Sequence[float],
-    unit: float = 1.0,
-    backend: str = "jax",
-) -> list[MCKPSolution]:
-    """Solve R independent dense-DP rounds with one vmapped scan.
-
-    Each round is an (option tables, budget) pair — e.g. the rounds of a
-    scenario trace, or one receiver set under a budget sweep.  Curves are
-    densified on the widest budget grid; rounds with fewer receivers are
-    padded with identity stages (F = [0, -inf, ...], which picks zero
-    spend), and each round's argmax is restricted to its own budget range,
-    so every solution equals its standalone ``solve_dense_jax`` call.
-    """
-    if len(rounds) != len(budgets):
-        raise ValueError("rounds and budgets must have equal length")
-    nbs = [int(np.floor(b / unit + 1e-9)) + 1 for b in budgets]
-    nb = max(nbs)
-    n_max = max(len(r) for r in rounds)
-    f_all = np.empty((len(rounds), n_max, nb), dtype=np.float64)
-    ch_all = np.zeros((len(rounds), n_max, nb), dtype=np.int32)
-    pad_row = np.full(nb, -np.inf)
-    pad_row[0] = 0.0
-    for r, opts in enumerate(rounds):
-        f, ch = dense_curves_matrix(list(opts), (nb - 1) * unit, unit)
-        f_all[r, : len(opts)] = f
-        ch_all[r, : len(opts)] = ch
-        f_all[r, len(opts) :] = pad_row
-
-    dp_final, args = _jax_dp_batch(f_all, backend=backend)
-    dp_final = np.asarray(dp_final)
-    args = np.asarray(args)
-
-    sols: list[MCKPSolution] = []
-    for r, opts in enumerate(rounds):
-        b = int(np.argmax(dp_final[r, : nbs[r]]))
-        total = float(dp_final[r, b])
-        picks: dict[str, tuple[float, float, tuple[float, float]]] = {}
-        for i in range(n_max - 1, -1, -1):
-            k = int(args[r, i, b])
-            if i < len(opts):
-                opt = opts[i]
-                j = int(ch_all[r, i][k])
-                picks[opt.name] = (
-                    float(opt.costs[j]),
-                    float(opt.values[j]),
-                    (float(opt.caps[j, 0]), float(opt.caps[j, 1])),
-                )
-            b -= k
-        spent = sum(c for c, _, _ in picks.values())
-        sols.append(MCKPSolution(total_value=total, spent=spent, picks=picks))
-    return sols
 
 
 # ---------------------------------------------------------------------------

@@ -230,8 +230,6 @@ def ecoshift(
     system: SystemSpec,
     surfaces: Mapping[str, PowerSurface],
     *,
-    solver: str = "sparse",
-    unit: float = 1.0,
     grouped: bool = False,
 ) -> Allocation:
     """Build per-receiver option curves from the (predicted) surfaces and
@@ -240,8 +238,8 @@ def ecoshift(
     ``grouped=True`` collapses receivers sharing (surface identity,
     baseline) into one behaviour class — one option table and one DP
     super-stage per class (DESIGN.md §11) — solving clusters of replicated
-    app classes in ~G stages instead of N, with bit-for-bit (sparse) /
-    bitwise (dense) parity against the ungrouped path.
+    app classes in ~G stages instead of N, bit-for-bit equal to the
+    ungrouped path.
     """
     order = as_receiver_order(receivers)
     if grouped:
@@ -253,7 +251,7 @@ def ecoshift(
                 "class", surf, base, system.grid, budget
             ),
         )
-        sol = mckp.solve_grouped(groups, budget, solver=solver, unit=unit)
+        sol = mckp.solve_sparse_grouped(groups, budget)
         return allocation_from_solution(
             sol, *receiver_rows(receivers, baselines), budget, system.grid
         )
@@ -263,14 +261,7 @@ def ecoshift(
         )
         for a in order
     ]
-    if solver == "sparse":
-        sol = mckp.solve_sparse(options, budget)
-    elif solver == "dense":
-        sol = mckp.solve_dense(options, budget, unit=unit)
-    elif solver in ("jax", "pallas"):
-        sol = mckp.solve_dense_jax(options, budget, unit=unit, backend=solver)
-    else:
-        raise ValueError(f"unknown solver {solver!r}")
+    sol = mckp.solve_sparse(options, budget)
     return allocation_from_solution(
         sol, *receiver_rows(receivers, baselines), budget, system.grid
     )
@@ -317,8 +308,6 @@ def ecoshift_hier(
     topology,
     node_of: Mapping[str, int],
     domain_extra: Mapping[str, float] | None = None,
-    solver: str = "sparse",
-    unit: float = 1.0,
 ) -> Allocation:
     """Topology-aware EcoShift: per-domain capped frontiers + upper-level DP.
 
@@ -362,7 +351,7 @@ def ecoshift_hier(
             ),
         )
     root = domain_tree(topology, caps, groups_by_leaf)
-    sol = mckp.solve_hierarchical(root, budget, solver=solver, unit=unit)
+    sol = mckp.solve_hierarchical(root, budget)
     return allocation_from_solution(
         sol, *receiver_rows(receivers, baselines), budget, system.grid
     )

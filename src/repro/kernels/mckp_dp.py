@@ -5,10 +5,10 @@ grid:
 
     out[b] = max_{0<=k<=b} dp[b-k] + f[k],        b, k in [0, NB)
 
-with NB = budget/granularity + 1 (the paper uses 1 W granularity, so NB can
-reach ~1.4e4 for the Fig. 8 sweeps and far more for pod-scale budgets; the
-full cluster solve is ``N_receivers`` such stages — promoted here from the
-paper's host-Python loop to an accelerator kernel, see DESIGN.md §8.1).
+with NB the points of the budget grid.  The fused round runs it for every
+leaf class super-stage and for every frontier combine of its aggregation
+tree — promoted here from the paper's host-Python loop to an accelerator
+kernel, see DESIGN.md §8.1 and §14.
 
 TPU mapping
 -----------
@@ -24,8 +24,9 @@ over (8, 128)-aligned tiles:
  * The per-row scalar ``f[k]`` comes from the 128-lane-aligned block that
    holds lane ``k``, rotated so that lane lands at 0 and broadcast along
    the lanes.
- * Argmax is tracked alongside in int32 (first maximizer in the loop's
-   shift order; ``-1`` where nothing beats -inf, mapped to 0 by callers).
+ * Argmax is tracked alongside in int32: the loop walks the shifts in
+   descending order and keeps the first maximizer, so ties resolve to the
+   largest shift; ``-1`` where nothing beats -inf, mapped to 0 by callers.
 
 Every index is explicit int32, so the kernel lowers the same under x64
 (CPU interpret mode, float64 values) and on the chip (float32 values).
@@ -54,7 +55,7 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def _maxplus_kernel(dp_ref, f_ref, out_ref, arg_ref, *, n_shifts, descending):
+def _maxplus_kernel(dp_ref, f_ref, out_ref, arg_ref, *, n_shifts):
     dp = dp_ref[...]
     neg = jnp.asarray(-jnp.inf, dp.dtype)
     lane = jax.lax.broadcasted_iota(jnp.int32, dp.shape, 1)
@@ -62,7 +63,7 @@ def _maxplus_kernel(dp_ref, f_ref, out_ref, arg_ref, *, n_shifts, descending):
 
     def body(i, carry):
         acc, arg = carry
-        k = last - i if descending else i
+        k = last - i
         # f[:, k] as an [8, 1] column: aligned 128-lane block, lane k%128
         # rotated to lane 0
         base = pl.multiple_of((k // _LANES) * _LANES, _LANES)
@@ -83,14 +84,14 @@ def _maxplus_kernel(dp_ref, f_ref, out_ref, arg_ref, *, n_shifts, descending):
     arg_ref[...] = arg
 
 
-def _maxplus_tiles(dp, f, *, n_shifts: int, descending: bool, interpret: bool):
+def _maxplus_tiles(dp, f, *, n_shifts: int, interpret: bool):
     """Row-batched dense (max,+) convolution over aligned [8, NBp] tiles.
 
     dp, f: [R, NB] (same dtype).  Returns ``(out [R, NB], arg [R, NB])``
     with ``out[r, b] = max_{k < n_shifts, k <= b} dp[r, b-k] + f[r, k]``
-    and ``arg`` the first maximizing shift in ascending (or, with
-    ``descending``, descending) shift order, -1 where every candidate is
-    -inf.
+    and ``arg`` the largest maximizing shift (the kernel walks the shifts
+    in descending order and keeps the first maximizer), -1 where every
+    candidate is -inf.
     """
     r, nb = dp.shape
     rp, nbp = _round_up(r, _ROWS), _round_up(nb, _LANES)
@@ -100,9 +101,7 @@ def _maxplus_tiles(dp, f, *, n_shifts: int, descending: bool, interpret: bool):
     f_p = jnp.pad(f.astype(dp.dtype), pad, constant_values=neg)
     tile = pl.BlockSpec((_ROWS, nbp), lambda i: (i, 0))
     out, arg = pl.pallas_call(
-        functools.partial(
-            _maxplus_kernel, n_shifts=n_shifts, descending=descending
-        ),
+        functools.partial(_maxplus_kernel, n_shifts=n_shifts),
         grid=(rp // _ROWS,),
         in_specs=[tile, tile],
         out_specs=[tile, tile],
@@ -171,9 +170,7 @@ def maxplus_stage_pallas_batched(
         # against the options sharing that spend (== f[r, kb[r, j]])
         same = kb[:, :, None] == kb[:, None, :]
         top = vb == jnp.max(jnp.where(same, vb[:, :, None], neg), axis=1)
-    out, arg_k = _maxplus_tiles(
-        dp, f, n_shifts=nb, descending=True, interpret=interpret
-    )
+    out, arg_k = _maxplus_tiles(dp, f, n_shifts=nb, interpret=interpret)
     with jax.named_scope("option_scatter"):
         # back to option indices: the first top option whose spend is the
         # winning shift (none where arg_k < 0, which maps to 0)
@@ -183,24 +180,22 @@ def maxplus_stage_pallas_batched(
         return out, jnp.where(arg_k < 0, 0, arg)
 
 
-@functools.partial(jax.jit, static_argnames=("descending", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def maxplus_conv_pallas_batched(
     dp: jax.Array,
     f: jax.Array,
     *,
-    descending: bool = False,
     interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
-    """Row-batched (max,+) convolution: one kernel launch for R rounds.
+    """Row-batched (max,+) convolution: one kernel launch for R rows.
 
     dp: [R, NB], f: [R, K] with K <= NB.  out[r, b] = max_{k<=b, k<K}
-    dp[r, b-k] + f[r, k], plus the per-row argmax k — the smallest
-    maximizing k, or with ``descending`` the largest (0 where out is
-    -inf).  Each row is identical to :func:`maxplus_conv_pallas` on that
-    row alone: R independent DP stages (e.g. all dirty rack leaves of a
-    hierarchical solve) share a single dispatch.  Keeps a float64 input
-    float64 (the fused round's frontier combine under x64); anything else
-    runs in float32.
+    dp[r, b-k] + f[r, k], plus the per-row argmax k — the largest
+    maximizing k (0 where out is -inf), the fused round's frontier
+    combine's tie-break.  Rows are independent DP stages (e.g. the pairs
+    of one frontier wave) sharing a single dispatch.  Keeps a float64
+    input float64 (the fused round's frontier combine under x64);
+    anything else runs in float32.
     """
     if dp.ndim != 2 or f.ndim != 2 or f.shape[0] != dp.shape[0]:
         raise ValueError(f"dp/f must be 2D with equal rows, got {dp.shape} {f.shape}")
@@ -213,28 +208,5 @@ def maxplus_conv_pallas_batched(
         f.astype(dtype), ((0, 0), (0, dp.shape[1] - n_shifts)),
         constant_values=-jnp.inf,
     )
-    out, arg = _maxplus_tiles(
-        dp, f, n_shifts=n_shifts, descending=descending, interpret=interpret
-    )
+    out, arg = _maxplus_tiles(dp, f, n_shifts=n_shifts, interpret=interpret)
     return out, jnp.maximum(arg, 0)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def maxplus_conv_pallas(
-    dp: jax.Array,
-    f: jax.Array,
-    *,
-    interpret: bool = True,
-) -> tuple[jax.Array, jax.Array]:
-    """out[b] = max_{k<=b} dp[b-k] + f[k]; also returns argmax k (int32).
-
-    dp, f: [NB] float32.  ``interpret=True`` runs the kernel body on CPU
-    (the validation mode off the chip); on a TPU pass False.
-    """
-    if dp.ndim != 1 or dp.shape != f.shape:
-        raise ValueError(f"dp/f must be equal-length 1D, got {dp.shape} {f.shape}")
-    out, arg = maxplus_conv_pallas_batched(
-        dp.astype(jnp.float32)[None], f.astype(jnp.float32)[None],
-        interpret=interpret,
-    )
-    return out[0], arg[0]

@@ -123,93 +123,6 @@ def bank_compact(kb_old, vb_old, src_s, src_l, *, k_pad: int):
     return jnp.where(m, kb_g, kb_id), jnp.where(m, vb_g, vb_id)
 
 
-def maxplus_conv(dp: jax.Array, f: jax.Array):
-    """(max,+)-convolution DP stage.  Returns (out, argmax_k)."""
-    return _mckp_dp.maxplus_conv_pallas(dp, f, interpret=not on_tpu())
-
-
-def maxplus_conv_batched(dp: jax.Array, f: jax.Array):
-    """Batched (max,+) stage: one row-batched Pallas launch.
-
-    dp, f: [R, NB].  Returns (out [R, NB], argmax_k [R, NB]) — each row
-    bitwise what ``maxplus_conv`` computes for it alone (the kernel body
-    is identical; the grid just grows a leading row dimension).  Each
-    stage of ``repro.core.mckp.solve_dense_jax_batch`` and of the batched
-    hierarchical leaf solve runs through this to advance many independent
-    DPs in a single dispatch.
-    """
-    return _mckp_dp.maxplus_conv_pallas_batched(dp, f, interpret=not on_tpu())
-
-
-@functools.cache
-def _maxplus_scan_batched_fn(interpret: bool):
-    import jax
-    import jax.numpy as jnp
-
-    @jax.jit
-    def run(f_groups, gids):
-        # f_groups: [L, G, NB]; gids: [L, N]
-        n_leaves = f_groups.shape[0]
-        rows_idx = jnp.arange(n_leaves)
-
-        def stage(dp, gid_col):  # dp: [L, NB]; gid_col: [L]
-            rows = f_groups[rows_idx, gid_col]
-            out, arg = _mckp_dp.maxplus_conv_pallas_batched(
-                dp, rows, interpret=interpret
-            )
-            return out, arg
-
-        dp0 = jnp.zeros(
-            (f_groups.shape[0], f_groups.shape[2]), dtype=f_groups.dtype
-        )
-        dp_final, args = jax.lax.scan(stage, dp0, gids.T)
-        return dp_final, args.swapaxes(0, 1)
-
-    return run
-
-
-def maxplus_scan_batched(f_groups, stage_gids):
-    """Ragged batched repeated-stage (max,+) DP scan over many leaves.
-
-    f_groups: [L, G, NB] per-leaf class curve banks (leaves padded to a
-    shared class count and budget grid — pad rows must be the identity
-    curve [0, -inf, ...]); stage_gids: [L, N] int32 per-leaf stage class
-    ids (padded stages gather the identity row, which leaves the DP
-    bitwise unchanged).  Returns (dp_final [L, NB], argmax_k [L, N, NB]).
-
-    One jitted scan whose every stage is a single row-batched Pallas
-    dispatch: the per-leaf Python loop of the hierarchical dense solve
-    collapses into one accelerator call for all dirty leaves, and each
-    leaf's row is bitwise what ``maxplus_scan`` returns for it alone.
-    """
-    import jax.numpy as jnp
-
-    run = _maxplus_scan_batched_fn(not on_tpu())
-    return run(f_groups, jnp.asarray(stage_gids))
-
-
-def maxplus_scan(f_groups, stage_gids):
-    """Repeated-stage (max,+) DP scan over a group-id sequence.
-
-    f_groups: [G, NB] per-behaviour-class dense curves; stage_gids: [N]
-    int32, one class id per DP stage.  Each stage gathers its curve row and
-    runs the Pallas (max,+) convolution, so N-receiver clusters with G
-    distinct classes never materialize an [N, NB] curve matrix.  Returns
-    (dp_final [NB], argmax_k [N, NB]) — bitwise equal to scanning the
-    row-expanded matrix through ``maxplus_conv``.
-
-    Delegates to :func:`maxplus_scan_batched` with a leading leaf axis of
-    1 — the single-row and batched scans are one kernel (each batched row
-    is bitwise the single-row result; see test_maxplus_scan_batched_rows_
-    bitwise), so there is exactly one scan body to maintain.
-    """
-    import jax.numpy as jnp
-
-    gids = jnp.asarray(stage_gids)
-    dp_final, args = maxplus_scan_batched(f_groups[None], gids[None])
-    return dp_final[0], args[0]
-
-
 def maxplus_stage_batched(dp, kb, vb):
     """Sparse-option (max,+) stage with backpointer output.
 

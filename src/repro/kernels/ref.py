@@ -2,7 +2,7 @@
 
 Each function here is the semantic ground truth: kernels are validated
 against these with ``assert_allclose`` over shape/dtype sweeps
-(tests/test_kernels.py), and they double as the CPU fallback paths.
+(tests/test_kernels.py).
 """
 
 from __future__ import annotations

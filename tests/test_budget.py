@@ -330,27 +330,27 @@ class TestSameRoundPrecedence:
 class TestControllerConfig:
     def test_legacy_kwargs_match_config(self, suite):
         system, _, _ = suite
-        a = EcoShiftController(system, solver="dense", unit=2.0, fused=True)
+        a = EcoShiftController(system, horizon=3, eco_factor=0.5, fused=True)
         b = EcoShiftController(
             system,
-            config=ControllerConfig(solver="dense", unit=2.0, fused=True),
+            config=ControllerConfig(horizon=3, eco_factor=0.5, fused=True),
         )
-        assert (a.solver, a.unit, a.fused) == (b.solver, b.unit, b.fused)
+        assert (a.horizon, a.eco_factor, a.fused) == (
+            b.horizon, b.eco_factor, b.fused,
+        )
         assert a.config == b.config
 
     def test_explicit_kwarg_beats_config(self, suite):
         system, _, _ = suite
-        cfg = ControllerConfig(horizon=8, eco_factor=0.7, solver="dense")
+        cfg = ControllerConfig(horizon=8, eco_factor=0.7, fused=True)
         c = EcoShiftController(system, config=cfg, horizon=4)
         assert c.horizon == 4  # kwarg wins
-        assert c.eco_factor == 0.7 and c.solver == "dense"  # config holds
+        assert c.eco_factor == 0.7 and c.fused is True  # config holds
 
     def test_defaults_are_historical(self, suite):
         system, _, _ = suite
         c = EcoShiftController(system)
-        assert (c.solver, c.unit, c.grouped, c.incremental, c.fused) == (
-            "sparse", 1.0, True, True, False,
-        )
+        assert (c.grouped, c.incremental, c.fused) == (True, True, False)
         assert c.horizon == 1 and c.eco_factor == 1.0
 
     def test_hier_config_carries_topology(self, suite):

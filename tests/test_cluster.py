@@ -4,8 +4,7 @@ Certifies the refactor's contracts:
  * vectorized measurement is bit-for-bit equal to the legacy per-node loop
    on identical RNG streams (and >= 5x faster at 100 nodes);
  * round 0 of every migrated policy equals the single-round emulator path;
- * multi-round regression: failure -> pool return -> warm re-optimization;
- * the vmap-batched Pallas (max,+) DP equals per-round single calls.
+ * multi-round regression: failure -> pool return -> warm re-optimization.
 """
 
 import time
@@ -15,7 +14,7 @@ import pytest
 
 from repro.cluster import ClusterSim, Scenario
 from repro.cluster.controller import make_controller
-from repro.core import curves, mckp, policies, surfaces, types
+from repro.core import policies, surfaces, types
 from repro.core.emulator import ClusterEmulator
 
 
@@ -110,21 +109,24 @@ class TestRoundZeroParity:
         assert dict(got.allocation.caps) == dict(want.allocation.caps)
         assert got.budget == want.budget
 
-    @pytest.mark.parametrize("solver", ["sparse", "dense", "jax"])
-    def test_warm_controller_matches_pure_policy(self, suite, solver):
+    @pytest.mark.parametrize(
+        "grouped", [False, True], ids=["sparse", "sparse_grouped"]
+    )
+    def test_warm_controller_matches_pure_policy(self, suite, grouped):
         """Budget-independent cached option tables solve identically to the
-        per-call tables the pure policy function builds."""
+        per-call tables the pure policy function builds, ungrouped or
+        group-collapsed."""
         system, apps, surfs = suite
         sim = _sim(suite, n_nodes=20, seed=4)
         _, recv, _ = sim.partition()
         baselines = {n.app.name: n.caps for n in recv}
         seen = {n.app.name: sim._surface(n) for n in recv}
-        ctrl = make_controller("ecoshift", system, solver=solver)
+        ctrl = make_controller("ecoshift", system)
         for budget in (400.0, 1100.0, 2500.0):  # warm after first call
             got = ctrl.allocate([n.app for n in recv], baselines, budget, seen)
             want = policies.ecoshift(
                 [n.app for n in recv], baselines, budget, system, seen,
-                solver=solver,
+                grouped=grouped,
             )
             assert dict(got.caps) == dict(want.caps)
             assert got.spent == want.spent
@@ -205,65 +207,6 @@ class TestScenarios:
 
 def f_victim_name(sim, node_id):
     return [n for n in sim.nodes if n.node_id == node_id][0].app.name
-
-
-# ---------------------------------------------------------------------------
-# Batched (vmap) DP == single calls
-# ---------------------------------------------------------------------------
-
-
-class TestBatchedDP:
-    def _rounds(self, suite):
-        system, apps, surfs = suite
-        base = (system.init_cpu, system.init_gpu)
-        budgets = [300.0, 900.0, 1600.0]
-        rounds = []
-        for i, b in enumerate(budgets):
-            names = sorted(a.name for a in apps[: 3 + i])
-            rounds.append(
-                [
-                    curves.build_options(n, surfs[n], base, system.grid, b)
-                    for n in names
-                ]
-            )
-        return rounds, budgets
-
-    @pytest.mark.parametrize("backend", ["jax", "pallas"])
-    def test_solve_batch_matches_singles(self, suite, backend):
-        rounds, budgets = self._rounds(suite)
-        batch = mckp.solve_dense_jax_batch(rounds, budgets, backend=backend)
-        for opts, budget, got in zip(rounds, budgets, batch):
-            want = mckp.solve_dense_jax(opts, budget, backend=backend)
-            assert got.picks == want.picks
-            assert got.total_value == want.total_value
-            assert got.spent == want.spent
-
-    def test_batched_kernel_matches_single(self):
-        import jax.numpy as jnp
-
-        from repro.kernels import ops
-
-        rng = np.random.default_rng(0)
-        dp = jnp.asarray(rng.uniform(0, 1, (4, 96)), jnp.float32)
-        f = jnp.asarray(rng.uniform(0, 1, (4, 96)), jnp.float32)
-        out_b, arg_b = ops.maxplus_conv_batched(dp, f)
-        for r in range(4):
-            out_s, arg_s = ops.maxplus_conv(dp[r], f[r])
-            np.testing.assert_array_equal(np.asarray(out_b[r]), np.asarray(out_s))
-            np.testing.assert_array_equal(np.asarray(arg_b[r]), np.asarray(arg_s))
-
-    def test_controller_allocate_batch(self, suite):
-        system, _, _ = suite
-        sim = _sim(suite, n_nodes=12, seed=8)
-        _, recv, _ = sim.partition()
-        baselines = {n.app.name: n.caps for n in recv}
-        seen = {n.app.name: sim._surface(n) for n in recv}
-        ctrl = make_controller("ecoshift", system, solver="jax")
-        budgets = (500.0, 1500.0)
-        batch = ctrl.allocate_batch([n.app for n in recv], baselines, budgets, seen)
-        for budget, got in zip(budgets, batch):
-            want = ctrl.allocate([n.app for n in recv], baselines, budget, seen)
-            assert dict(got.caps) == dict(want.caps)
 
 
 # ---------------------------------------------------------------------------

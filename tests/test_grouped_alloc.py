@@ -6,8 +6,8 @@ The load-bearing contracts of the multiplicity-aware MCKP solvers:
    the name-sorted ungrouped expansion — picks, total_value and spent —
    on randomized mixed clusters, including interleaved member names and
    byte-identical duplicate tables (the straggler split);
- * the grouped dense/JAX/Pallas paths are bitwise equal to their ungrouped
-   counterparts (same convolutions, same order);
+ * the device pipeline's grouped solve (``solve_grouped_fused``) is
+   bit-for-bit ``solve_sparse`` on the same expansion;
  * end-to-end: a grouped controller stepping a scenario with failures and
    stragglers produces exactly the legacy per-instance controller's
    allocations and measured improvements, round for round.
@@ -136,31 +136,24 @@ def test_aggregate_curve_matches_sequential_stages():
 
 
 # ---------------------------------------------------------------------------
-# Dense / JAX / Pallas grouped paths
+# Device pipeline
 # ---------------------------------------------------------------------------
 
 
-def test_dense_grouped_bitwise_parity():
-    rng = np.random.default_rng(3)
-    for _ in range(20):
+@pytest.mark.parametrize("seed", range(3))
+def test_fused_grouped_matches_expanded_sparse(seed):
+    """The fused grouped solve (CPU: interpret mode, float64 values) is
+    bit-for-bit the ungrouped host DP on the name-sorted expansion."""
+    rng = np.random.default_rng(30 + seed)
+    fstate = mckp.FusedState()
+    for _ in range(3):
         budget = float(rng.integers(3, 25)) * 25.0
         groups = _random_groups(rng, budget)
-        de = mckp.solve_dense(mckp.expand_groups(groups), budget)
-        dg = mckp.solve_dense_grouped(groups, budget)
-        _assert_bitwise_equal(de, dg)
-
-
-@pytest.mark.parametrize("backend", ["jax", "pallas"])
-def test_jax_grouped_bitwise_parity(backend):
-    rng = np.random.default_rng(4)
-    for _ in range(3):
-        budget = float(rng.integers(3, 10)) * 25.0
-        groups = _random_groups(rng, budget)
-        ja = mckp.solve_dense_jax(
-            mckp.expand_groups(groups), budget, backend=backend
+        sol = mckp.solve_grouped_fused(groups, budget, fstate=fstate)
+        assert sol is not None, fstate.stats["fallback_reason"]
+        _assert_bitwise_equal(
+            mckp.solve_sparse(mckp.expand_groups(groups), budget), sol
         )
-        jg = mckp.solve_dense_jax_grouped(groups, budget, backend=backend)
-        _assert_bitwise_equal(ja, jg)
 
 
 # ---------------------------------------------------------------------------
@@ -193,21 +186,12 @@ class TestControllerParity:
         baselines = {n.app.name: n.caps for n in recv}
         seen = {n.app.name: sim._surface(n) for n in recv}
         recv_apps = [n.app for n in recv]
-        for solver in ("sparse", "dense"):
-            a = policies.ecoshift(
-                recv_apps, baselines, 900.0, system, seen, solver=solver
-            )
-            b = policies.ecoshift(
-                recv_apps,
-                baselines,
-                900.0,
-                system,
-                seen,
-                solver=solver,
-                grouped=True,
-            )
-            assert dict(a.caps) == dict(b.caps)
-            assert a.spent == b.spent
+        a = policies.ecoshift(recv_apps, baselines, 900.0, system, seen)
+        b = policies.ecoshift(
+            recv_apps, baselines, 900.0, system, seen, grouped=True
+        )
+        assert dict(a.caps) == dict(b.caps)
+        assert a.spent == b.spent
 
     @pytest.mark.parametrize("policy", ["ecoshift", "oracle"])
     def test_scenario_grouped_equals_legacy_per_instance(self, suite, policy):

@@ -3,9 +3,9 @@
 The load-bearing contracts of the topology-aware two-level solver:
 
  * **single-root parity**: a topology degenerating to one domain whose cap
-   covers the cluster budget is *bit-for-bit* the flat grouped solve —
-   ``solve_sparse_grouped`` for the sparse path, ``solve_dense_jax_grouped``
-   for the dense/jax/pallas path — picks, total_value and spent;
+   covers the cluster budget is *bit-for-bit* the flat grouped solve
+   ``solve_sparse_grouped`` — picks, total_value and spent — on the host
+   DP and on the device pipeline (``solve_hierarchical_fused``);
  * **cap feasibility**: randomized multi-domain instances never spend above
    any domain cap, and match an exhaustive cap-constrained brute force on
    small cases;
@@ -94,15 +94,21 @@ def test_single_root_sparse_parity(seed):
         assert abs(hier.domain_spent["root"] - hier.spent) < 1e-6
 
 
-@pytest.mark.parametrize("backend", ["jax", "pallas"])
-def test_single_root_dense_parity(backend):
-    rng = np.random.default_rng(11)
+@pytest.mark.parametrize("seed", range(4))
+def test_single_root_fused_parity(seed):
+    """The device pipeline on a single root domain (CPU: interpret mode,
+    float64 values) is bit-for-bit the flat grouped host DP."""
+    rng = np.random.default_rng(11 + seed)
+    fstate = mckp.FusedState()
     for _ in range(3):
         budget = float(rng.integers(3, 10)) * 25.0
         groups = _random_groups(rng, budget)
-        flat = mckp.solve_dense_jax_grouped(groups, budget, backend=backend)
+        flat = mckp.solve_sparse_grouped(groups, budget)
         root = mckp.DomainGroups(name="root", cap=budget, groups=tuple(groups))
-        hier = mckp.solve_hierarchical(root, budget, solver=backend)
+        hier = mckp.solve_hierarchical_fused(
+            root, budget, state=mckp.HierState(), fstate=fstate
+        )
+        assert hier is not None, fstate.stats["fallback_reason"]
         _assert_bitwise_equal(flat, hier)
 
 
@@ -166,9 +172,13 @@ def test_multi_domain_matches_constrained_brute_force(seed):
     for d, (cap, _) in enumerate(doms):
         assert hier.domain_spent[f"d{d}"] <= cap + 1e-6
     assert hier.spent <= budget + 1e-9
-    # dense path agrees on the optimum (jax float32 tolerance)
-    dense = mckp.solve_hierarchical(root, budget, solver="jax")
-    np.testing.assert_allclose(dense.total_value, best, atol=1e-5)
+    # the device pipeline agrees on the optimum (float64 on the CPU)
+    fstate = mckp.FusedState()
+    fused = mckp.solve_hierarchical_fused(
+        root, budget, state=mckp.HierState(), fstate=fstate
+    )
+    assert fused is not None, fstate.stats["fallback_reason"]
+    np.testing.assert_allclose(fused.total_value, best, atol=1e-9)
 
 
 @hypothesis.given(seed=st.integers(0, 2**31 - 1))

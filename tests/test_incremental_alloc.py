@@ -296,43 +296,6 @@ def test_maxplus_pair_int_matches_generic(seed):
         assert f.tobytes() == r.tobytes()
 
 
-def test_maxplus_conv_batched_rows_bitwise():
-    from repro.kernels import ops, ref
-
-    rng = np.random.default_rng(0)
-    dp = rng.uniform(0, 1, size=(5, 96)).astype(np.float32)
-    f = np.sort(rng.uniform(0, 1, size=(5, 96)), axis=1).astype(np.float32)
-    out_b, arg_b = ops.maxplus_conv_batched(dp, f)
-    for r in range(5):
-        out_r, arg_r = ops.maxplus_conv(dp[r], f[r])
-        np.testing.assert_array_equal(np.asarray(out_b)[r], np.asarray(out_r))
-        np.testing.assert_array_equal(np.asarray(arg_b)[r], np.asarray(arg_r))
-    # and both agree with the reference semantics
-    out_ref, _ = ref.maxplus_conv(dp[0], f[0])
-    np.testing.assert_allclose(np.asarray(out_b)[0], np.asarray(out_ref), rtol=1e-6)
-
-
-def test_maxplus_scan_batched_rows_bitwise():
-    from repro.kernels import ops
-
-    rng = np.random.default_rng(1)
-    n_leaves, g, nb, n = 3, 4, 64, 6
-    f_groups = np.sort(rng.uniform(0, 1, size=(n_leaves, g, nb)), axis=2)
-    f_groups[:, :, 0] = 0.0
-    gids = rng.integers(0, g, size=(n_leaves, n)).astype(np.int32)
-    dp_b, args_b = ops.maxplus_scan_batched(
-        f_groups.astype(np.float32), gids
-    )
-    for leaf in range(n_leaves):
-        dp_s, args_s = ops.maxplus_scan(
-            f_groups[leaf].astype(np.float32), gids[leaf]
-        )
-        np.testing.assert_array_equal(np.asarray(dp_b)[leaf], np.asarray(dp_s))
-        np.testing.assert_array_equal(
-            np.asarray(args_b)[leaf], np.asarray(args_s)
-        )
-
-
 def test_curve_cutoff_invariance():
     """Aggregate curves truncated from any cutoff >= the DP budget solve
     identically (states, values, unwound multisets)."""

@@ -23,34 +23,75 @@ def _tol(dtype):
 # ---------------------------------------------------------------------------
 
 
+def _largest_argmax(dp, f):
+    """Largest maximizing shift of out[b] = max_{k<=b} dp[b-k] + f[k] in
+    float32 (0 where every candidate is -inf): the kernel's tie rule."""
+    dp = np.asarray(dp, np.float32)
+    f = np.asarray(f, np.float32)
+    idx = np.arange(len(dp))[:, None] - np.arange(len(f))[None, :]
+    cand = np.where(
+        idx >= 0, dp[np.clip(idx, 0, None)] + f[None, :], np.float32(-np.inf)
+    )
+    best = cand.max(axis=1)
+    last = len(f) - 1 - np.argmax((cand == best[:, None])[:, ::-1], axis=1)
+    return np.where(np.isneginf(best), 0, last)
+
+
 class TestMaxPlus:
     @pytest.mark.parametrize("nb", [17, 64, 200, 513])
     @pytest.mark.parametrize("rows", [1, 11])
     def test_matches_ref(self, nb, rows):
-        """Single-row kernel, and every row of a batch spanning two
-        8-row tiles, against the jnp oracle."""
+        """One row, and every row of a batch spanning two 8-row tiles,
+        against the jnp oracle; ties go to the largest shift."""
         rng = np.random.default_rng(nb + rows)
         dp = np.maximum.accumulate(rng.uniform(0, 1, (rows, nb)), axis=1)
         f = np.maximum.accumulate(rng.uniform(0, 1, (rows, nb)), axis=1)
         dp, f = jnp.asarray(dp, jnp.float32), jnp.asarray(f, jnp.float32)
-        if rows == 1:
-            out_p, arg_p = mckp_dp.maxplus_conv_pallas(dp[0], f[0])
-            out_p, arg_p = out_p[None], arg_p[None]
-        else:
-            out_p, arg_p = mckp_dp.maxplus_conv_pallas_batched(dp, f)
+        out_p, arg_p = mckp_dp.maxplus_conv_pallas_batched(dp, f)
         for r in range(rows):
-            out_r, arg_r = ref.maxplus_conv(dp[r], f[r])
+            out_r, _ = ref.maxplus_conv(dp[r], f[r])
             np.testing.assert_allclose(out_p[r], out_r, rtol=1e-6)
             np.testing.assert_array_equal(
-                np.asarray(arg_p[r]), np.asarray(arg_r)
+                np.asarray(arg_p[r]), _largest_argmax(dp[r], f[r])
             )
 
     def test_monotone_inputs_monotone_output(self):
         rng = np.random.default_rng(0)
         dp = jnp.asarray(np.maximum.accumulate(rng.uniform(0, 1, 128)), jnp.float32)
         f = jnp.asarray(np.maximum.accumulate(rng.uniform(0, 1, 128)), jnp.float32)
-        out, _ = mckp_dp.maxplus_conv_pallas(dp, f)
-        assert np.all(np.diff(np.asarray(out)) >= -1e-6)
+        out, _ = mckp_dp.maxplus_conv_pallas_batched(dp[None], f[None])
+        assert np.all(np.diff(np.asarray(out)[0]) >= -1e-6)
+
+    def test_planted_ties_resolve_to_largest_shift(self):
+        """Equal candidates at several shifts: the kernel keeps the largest
+        (the frontier combine's smallest-left-spend tie-break), where the
+        oracle keeps the smallest; values agree bitwise."""
+        dp = jnp.zeros((1, 4), jnp.float32)
+        f = jnp.ones((1, 3), jnp.float32)
+        out, arg = mckp_dp.maxplus_conv_pallas_batched(dp, f)
+        np.testing.assert_array_equal(np.asarray(out)[0], [1, 1, 1, 1])
+        np.testing.assert_array_equal(np.asarray(arg)[0], [0, 1, 2, 2])
+
+        rng = np.random.default_rng(5)
+        rows, nb, k = 9, 150, 70
+        dp = rng.integers(0, 4, (rows, nb)).astype(np.float32)
+        f = rng.integers(0, 4, (rows, k)).astype(np.float32)
+        dp[:, :3] = -np.inf  # unreachable low grid points
+        out, arg = mckp_dp.maxplus_conv_pallas_batched(
+            jnp.asarray(dp), jnp.asarray(f)
+        )
+        f_full = np.full((rows, nb), -np.inf, np.float32)
+        f_full[:, :k] = f
+        differs = 0
+        for r in range(rows):
+            out_r, arg_r = ref.maxplus_conv(
+                jnp.asarray(dp[r]), jnp.asarray(f_full[r])
+            )
+            np.testing.assert_array_equal(np.asarray(out)[r], np.asarray(out_r))
+            want = _largest_argmax(dp[r], f[r])
+            np.testing.assert_array_equal(np.asarray(arg)[r], want)
+            differs += int(np.sum(want != np.asarray(arg_r)))
+        assert differs > 0  # the ties were real
 
 
 # ---------------------------------------------------------------------------

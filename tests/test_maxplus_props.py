@@ -5,8 +5,9 @@ The tropical semiring facts the solvers rely on (DESIGN.md §8, §11, §12):
  * (max,+) convolution is **commutative** and **associative** — the
    binary-split self-convolution and the hierarchical frontier convolution
    are only correct because operand order/grouping cannot change values;
- * ``maxplus_scan`` (the repeated-stage gather scan) is bitwise identical
-   to folding ``maxplus_conv`` stage by stage;
+ * the row-batched Pallas kernel ``maxplus_conv_pallas_batched`` computes
+   every row's convolution as the jnp oracle does, bitwise, with a
+   maximizing shift as its argmax;
  * ``aggregate_curve``'s binary-split m-fold self-convolution equals the
    naive m-fold left fold on randomized option tables.
 
@@ -27,7 +28,7 @@ except ImportError:  # image without hypothesis: property tests skip
     from _hypothesis_stub import hypothesis, st
 
 from repro.core import curves, mckp
-from repro.kernels import ops as kops
+from repro.kernels import mckp_dp
 from repro.kernels import ref as kref
 
 
@@ -97,30 +98,33 @@ def test_maxplus_conv_identity():
 
 
 # ---------------------------------------------------------------------------
-# maxplus_scan == repeated maxplus_conv
+# The row-batched kernel == the oracle, row by row
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("seed", range(3))
-def test_maxplus_scan_equals_repeated_conv(seed):
-    """The gather scan is bitwise the stage-by-stage fold (Pallas path,
-    interpret mode on CPU)."""
+def test_maxplus_conv_batched_matches_ref(seed):
+    """Every row of one batched launch (rows spanning two 8-row tiles,
+    fewer shifts than grid points) is the oracle's convolution, bitwise,
+    and its argmax realizes the value at a shift no smaller than the
+    oracle's smallest maximizer (Pallas path, interpret mode on CPU)."""
     rng = np.random.default_rng(200 + seed)
-    g, nb, n = 3, 24, 6
-    f_groups = np.stack([_rand_curve(rng, nb, dyadic=False) for _ in range(g)])
-    gids = rng.integers(0, g, size=n).astype(np.int32)
-
-    dp_scan, args_scan = kops.maxplus_scan(f_groups, gids)
-    dp_scan, args_scan = np.asarray(dp_scan), np.asarray(args_scan)
-
-    dp = np.zeros(nb)
-    args = []
-    for gid in gids:
-        dp, arg = kops.maxplus_conv(dp, f_groups[gid])
-        dp = np.asarray(dp)
-        args.append(np.asarray(arg))
-    np.testing.assert_array_equal(dp_scan, dp)
-    np.testing.assert_array_equal(args_scan, np.stack(args))
+    rows, nb = 11, int(rng.integers(20, 90))
+    k = int(rng.integers(1, nb + 1))
+    dp = np.stack([_rand_curve(rng, nb, dyadic=False) for _ in range(rows)])
+    f = np.stack([_rand_curve(rng, k, dyadic=False) for _ in range(rows)])
+    dp, f = dp.astype(np.float32), f.astype(np.float32)
+    out, arg = mckp_dp.maxplus_conv_pallas_batched(dp, f)
+    out, arg = np.asarray(out), np.asarray(arg)
+    f_full = np.full((rows, nb), -np.inf, np.float32)
+    f_full[:, :k] = f
+    bs = np.arange(nb)
+    for r in range(rows):
+        out_r, arg_r = kref.maxplus_conv(dp[r], f_full[r])
+        np.testing.assert_array_equal(out[r], np.asarray(out_r))
+        assert np.all((arg[r] >= 0) & (arg[r] < k) & (arg[r] <= bs))
+        np.testing.assert_array_equal(dp[r][bs - arg[r]] + f[r][arg[r]], out[r])
+        assert np.all(arg[r] >= np.asarray(arg_r))
 
 
 # ---------------------------------------------------------------------------

@@ -40,39 +40,40 @@ def test_all_solvers_match_brute_force(seed, n_apps, budget):
     opts = random_options(rng, n_apps, float(budget))
     bf = mckp.brute_force(opts, float(budget))
     sp = mckp.solve_sparse(opts, float(budget))
-    de = mckp.solve_dense(opts, float(budget), unit=1.0)
     np.testing.assert_allclose(sp.total_value, bf.total_value, atol=1e-9)
-    np.testing.assert_allclose(de.total_value, bf.total_value, atol=1e-9)
     assert sp.spent <= budget + 1e-9
-    assert de.spent <= budget + 1e-9
 
 
-@pytest.mark.parametrize("backend", ["jax", "pallas"])
-def test_jax_solvers_match_brute_force(backend):
-    rng = np.random.default_rng(7)
-    for trial in range(5):
-        budget = float(rng.integers(10, 50))
-        opts = random_options(rng, int(rng.integers(1, 5)), budget)
-        bf = mckp.brute_force(opts, budget)
-        jx = mckp.solve_dense_jax(opts, budget, unit=1.0, backend=backend)
-        np.testing.assert_allclose(jx.total_value, bf.total_value, atol=1e-5)
-        assert jx.spent <= budget + 1e-9
+@pytest.mark.parametrize("seed", range(10))
+def test_fused_matches_brute_force(seed):
+    """The device pipeline (CPU: interpret mode, float64 values) reaches
+    the exhaustive optimum, within budget, with picks that add up."""
+    rng = np.random.default_rng(500 + seed)
+    budget = float(rng.integers(10, 60))
+    opts = random_options(rng, int(rng.integers(1, 6)), budget)
+    groups = [mckp.GroupedOptions(table=o, members=(o.name,)) for o in opts]
+    fstate = mckp.FusedState()
+    sol = mckp.solve_grouped_fused(groups, budget, fstate=fstate)
+    assert sol is not None, fstate.stats["fallback_reason"]
+    bf = mckp.brute_force(opts, budget)
+    np.testing.assert_allclose(sol.total_value, bf.total_value, atol=1e-9)
+    assert sol.spent <= budget + 1e-9
+    assert set(sol.picks) == {o.name for o in opts}
+    total = sum(v for _, v, _ in sol.picks.values())
+    np.testing.assert_allclose(total, sol.total_value, atol=1e-9)
+    spent = sum(c for c, _, _ in sol.picks.values())
+    np.testing.assert_allclose(spent, sol.spent, atol=1e-9)
 
 
 def test_picks_consistent_with_value():
     """Reported picks must sum to the reported total (backtrack integrity)."""
     rng = np.random.default_rng(3)
     opts = random_options(rng, 6, 80.0)
-    for solver in (
-        lambda: mckp.solve_sparse(opts, 80.0),
-        lambda: mckp.solve_dense(opts, 80.0),
-        lambda: mckp.solve_dense_jax(opts, 80.0),
-    ):
-        sol = solver()
-        total = sum(v for _, v, _ in sol.picks.values())
-        np.testing.assert_allclose(total, sol.total_value, atol=1e-6)
-        spent = sum(c for c, _, _ in sol.picks.values())
-        np.testing.assert_allclose(spent, sol.spent, atol=1e-6)
+    sol = mckp.solve_sparse(opts, 80.0)
+    total = sum(v for _, v, _ in sol.picks.values())
+    np.testing.assert_allclose(total, sol.total_value, atol=1e-6)
+    spent = sum(c for c, _, _ in sol.picks.values())
+    np.testing.assert_allclose(spent, sol.spent, atol=1e-6)
 
 
 @hypothesis.given(seed=st.integers(0, 2**31 - 1))
@@ -95,15 +96,6 @@ def test_zero_budget_zero_value():
         assert cost == 0.0 and val == 0.0
 
 
-def test_dense_unit_rounding_never_overspends():
-    """Coarse DP units round costs UP: solution stays budget-feasible."""
-    rng = np.random.default_rng(13)
-    opts = random_options(rng, 5, 47.0)
-    for unit in (1.0, 2.0, 5.0, 10.0):
-        sol = mckp.solve_dense(opts, 47.0, unit=unit)
-        assert sol.spent <= 47.0 + 1e-9
-
-
 class TestBuildOptions:
     def test_staircase_properties(self):
         from repro.core import surfaces, types
@@ -121,19 +113,3 @@ class TestBuildOptions:
             c, g = opts.caps[j]
             assert c >= 300.0 - 1e-9 and g >= 200.0 - 1e-9
             np.testing.assert_allclose((c - 300.0) + (g - 200.0), opts.costs[j])
-
-    def test_dense_curve_monotone(self):
-        from repro.core import surfaces, types
-
-        s = surfaces.raytracing_surface()
-        opts = curves.build_options(
-            "rt", s, (300.0, 200.0), types.SYSTEM_2.grid, 200.0
-        )
-        f, choice = curves.dense_curve(opts, 200.0, unit=1.0)
-        assert f.shape == (201,)
-        assert np.all(np.diff(f) >= 0)
-        assert f[0] == 0.0
-        # F(b) equals the best option with cost <= b (Eq. 1)
-        for b in (0, 24, 25, 99, 200):
-            feas = opts.costs <= b + 1e-9
-            np.testing.assert_allclose(f[b], np.max(opts.values[feas]))

@@ -75,25 +75,17 @@ def test_stage_kernel_compiles(one_chip, rows, nb, k):
     )
 
 
-@pytest.mark.parametrize("batched", [False, True])
-def test_dense_kernels_compile(one_chip, batched):
-    """The dense (max,+) convolutions, single-row and row-batched (the
-    batched one descending: the frontier combine of the fused round)."""
+def test_dense_kernels_compile(one_chip):
+    """The row-batched dense (max,+) convolution: the frontier combine of
+    the fused round."""
     f32 = jnp.float32
-    if batched:
-        _compiled_text(
-            lambda dp, f: mckp_dp.maxplus_conv_pallas_batched(
-                dp, f, descending=True, interpret=False
-            ),
-            jax.ShapeDtypeStruct((8, NBT), f32, sharding=one_chip),
-            jax.ShapeDtypeStruct((8, 121), f32, sharding=one_chip),
-        )
-    else:
-        _compiled_text(
-            lambda dp, f: mckp_dp.maxplus_conv_pallas(dp, f, interpret=False),
-            jax.ShapeDtypeStruct((1000,), f32, sharding=one_chip),
-            jax.ShapeDtypeStruct((1000,), f32, sharding=one_chip),
-        )
+    _compiled_text(
+        lambda dp, f: mckp_dp.maxplus_conv_pallas_batched(
+            dp, f, interpret=False
+        ),
+        jax.ShapeDtypeStruct((8, NBT), f32, sharding=one_chip),
+        jax.ShapeDtypeStruct((8, 121), f32, sharding=one_chip),
+    )
 
 
 def _pipeline_avals(kb_sh, vb_sh, leaf_sh, rep_sh, lp, n_doms):

@@ -1,8 +1,7 @@
 """CI smoke: a seeded multi-round scenario through the cluster engine.
 
 5 rounds, 50 nodes, one failure and one straggler, for both the
-``ecoshift`` and ``dps`` controllers — on CPU (Pallas interpret mode for
-the jax-solver round).  Also reports the vectorized-vs-loop measurement
+``ecoshift`` and ``dps`` controllers, on CPU.  Also reports the vectorized-vs-loop measurement
 speedup at 100 nodes, runs the **1k-node scaling tier** (group-collapsed
 columnar engine: a 6-round scenario with failure/straggler/arrival under
 its own wall-clock guard, plus a grouped-vs-legacy allocation parity spot
@@ -504,14 +503,6 @@ def main() -> None:
             f"{policy:9s} rounds={trace.n_rounds} "
             f"avg_improvement={[f'{x*100:.1f}%' for x in imp]}"
         )
-
-    # one jax-solver round exercises the (interpret-mode) Pallas DP path
-    sim = ClusterSim.build(system, apps, surfs, n_nodes=20, seed=1)
-    res = sim.run_round(
-        make_controller("ecoshift", system, solver="jax"), budget=1000.0
-    )
-    assert res.avg_improvement > 0
-    print(f"jax-solver round: avg_improvement={res.avg_improvement*100:.1f}%")
 
     # vectorized measurement speedup at 100 nodes
     sim = ClusterSim.build(system, apps, surfs, n_nodes=100, seed=0)
