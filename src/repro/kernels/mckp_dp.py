@@ -15,18 +15,34 @@ TPU mapping
 (max,+) cannot use the MXU (no tropical matmul), so this is a VPU kernel
 over (8, 128)-aligned tiles:
 
- * Rows (independent DPs) ride the 8 sublanes, the budget grid the lanes:
-   each grid step holds one ``[8, NBp]`` row tile (NBp = NB rounded up to
-   128 lanes) of ``dp`` and ``f`` in VMEM.
- * For each shift ``k`` every row of the tile needs ``dp[b - k]`` — one
+ * Rows (independent DPs) ride the sublanes, the budget grid the lanes:
+   each grid step holds one ``[block, NBp]`` row block (NBp = NB rounded
+   up to 128 lanes, ``block`` a multiple of 8) of ``dp`` and ``f`` in
+   VMEM.  A small batch runs whole in one block (a leaf stage's 16 rows
+   on 256 points: ``[16, 256]``); a batch past ``_BLOCK_REGS`` registers
+   splits into the fewest blocks that fit.
+ * For each shift ``k`` every row of the block needs ``dp[b - k]`` — one
    uniform lane rotation (``pltpu.roll``) with the wrapped-around lanes
    ``b < k`` masked to -inf, so no load is ever lane-unaligned.
  * The per-row scalar ``f[k]`` comes from the 128-lane-aligned block that
    holds lane ``k``, rotated so that lane lands at 0 and broadcast along
    the lanes.
- * Argmax is tracked alongside in int32: the loop walks the shifts in
-   descending order and keeps the first maximizer, so ties resolve to the
-   largest shift; ``-1`` where nothing beats -inf, mapped to 0 by callers.
+ * A trip of the shift loop takes ``U`` shifts, one to each of ``U``
+   partial ``(value, shift)`` accumulators, so their rotations and adds
+   are independent work the scheduler overlaps: a trip's cost is latency,
+   not data.  ``U`` (a power of two up to 8) and the block come from the
+   static shape alone (``_loop_shape``): the partials' registers stay
+   within half the register file, so a ``[16, 256]`` block (4 registers)
+   takes 4 shifts a trip and a frontier combine's ``[8, 4096]`` tile (32
+   registers) one: a plain one-shift loop.  Shifts past the count, up to a multiple
+   of ``U``, read ``f = -inf`` and never win.
+ * Argmax is tracked alongside in int32: each partial walks its shifts in
+   descending order and keeps the first maximizer, and the partials merge
+   by "larger value wins; on equal values, the larger shift", so ties
+   resolve to the largest shift overall, the values are the same single
+   IEEE adds ``dp[b - k] + f[k]``, and both outputs are bitwise those of
+   one descending scan; ``-1`` where nothing beats -inf, mapped to 0 by
+   callers.
 
 Every index is explicit int32, so the kernel lowers the same under x64
 (CPU interpret mode, float64 values) and on the chip (float32 values).
@@ -49,60 +65,111 @@ from jax.experimental.pallas import tpu as pltpu
 #: (sublane, lane) tile of one 32-bit vector register
 _ROWS = 8
 _LANES = 128
+_VREG = _ROWS * _LANES
+#: vector registers one row block may span, so a small batch runs whole
+_BLOCK_REGS = 8
+#: vector registers the loop's partial (value, shift) accumulators may
+#: hold (half of the 64-register file; the rest for dp and temporaries)
+_CARRY_REGS = 32
+#: most shifts a trip unrolls; a power of two, so it divides 128
+_MAX_UNROLL = 8
 
 
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def _maxplus_kernel(dp_ref, f_ref, out_ref, arg_ref, *, n_shifts):
+def _loop_shape(rows: int, lanes: int) -> tuple[int, int]:
+    """``(rows per block, shifts per trip)`` for ``rows`` DPs on ``lanes``
+    (a multiple of 128) grid points, from the shape alone.
+
+    Rows go into as few blocks as keep a block within ``_BLOCK_REGS``
+    registers (at least one 8-row tile).  Each trip then takes the largest
+    power-of-two count of shifts whose partial accumulators, a value and a
+    shift register set each, stay within ``_CARRY_REGS``: a leaf stage's
+    ``[16, 256]`` block (4 registers) takes 4 shifts a trip, a frontier
+    combine's ``[8, 4096]`` tile (32 registers) one.
+    """
+    rows8 = _round_up(rows, _ROWS)
+    cap = max(_ROWS, _BLOCK_REGS * _VREG // lanes // _ROWS * _ROWS)
+    n_blocks = -(-rows8 // cap)
+    block = _round_up(-(-rows8 // n_blocks), _ROWS)
+    regs = block * lanes // _VREG
+    unroll = 1
+    while unroll < _MAX_UNROLL and 2 * (2 * unroll) * regs <= _CARRY_REGS:
+        unroll *= 2
+    return block, unroll
+
+
+def _merge(a, b):
+    """The better of two partial ``(value, shift)`` maxima: the larger
+    value, and on equal values the larger shift."""
+    take = (b[0] > a[0]) | ((b[0] == a[0]) & (b[1] > a[1]))
+    return jnp.where(take, b[0], a[0]), jnp.where(take, b[1], a[1])
+
+
+def _maxplus_kernel(dp_ref, f_ref, out_ref, arg_ref, *, n_trips, unroll):
     dp = dp_ref[...]
     neg = jnp.asarray(-jnp.inf, dp.dtype)
     lane = jax.lax.broadcasted_iota(jnp.int32, dp.shape, 1)
-    last = jnp.int32(n_shifts - 1)
+    top = jnp.int32((n_trips - 1) * unroll)
 
-    def body(i, carry):
-        acc, arg = carry
-        k = last - i
-        # f[:, k] as an [8, 1] column: aligned 128-lane block, lane k%128
-        # rotated to lane 0
-        base = pl.multiple_of((k // _LANES) * _LANES, _LANES)
+    def body(i, parts):
+        # shifts lo .. lo + unroll - 1, one to each partial accumulator;
+        # lo is a multiple of unroll, which divides 128, so all of them
+        # lie in the one aligned 128-lane block of f at base
+        lo = top - i * unroll
+        base = pl.multiple_of((lo // _LANES) * _LANES, _LANES)
         fblk = f_ref[:, pl.ds(base, _LANES)]
-        fk = pltpu.roll(fblk, (_LANES - k % _LANES) % _LANES, 1)[:, :1]
-        # dp[b - k]: uniform rotation, wrapped lanes b < k read -inf
-        col = jnp.where(lane >= k, pltpu.roll(dp, k, 1), neg)
-        cand = col + fk
-        better = cand > acc
-        return jnp.where(better, cand, acc), jnp.where(better, k, arg)
+        new = []
+        for u, (acc, arg) in enumerate(parts):
+            k = lo + u
+            # f[:, k] as a [rows, 1] column: lane k % 128 rotated to lane 0
+            fk = pltpu.roll(fblk, (_LANES - k % _LANES) % _LANES, 1)[:, :1]
+            # dp[b - k]: uniform rotation, wrapped lanes b < k read -inf
+            col = jnp.where(lane >= k, pltpu.roll(dp, k, 1), neg)
+            cand = col + fk
+            better = cand > acc
+            new.append((jnp.where(better, cand, acc), jnp.where(better, k, arg)))
+        return tuple(new)
 
-    acc0 = jnp.full(dp.shape, neg)
-    arg0 = jnp.full(dp.shape, -1, jnp.int32)
-    acc, arg = jax.lax.fori_loop(
-        jnp.int32(0), jnp.int32(n_shifts), body, (acc0, arg0)
+    part0 = (jnp.full(dp.shape, neg), jnp.full(dp.shape, -1, jnp.int32))
+    parts = jax.lax.fori_loop(
+        jnp.int32(0), jnp.int32(n_trips), body, (part0,) * unroll
     )
+    acc, arg = functools.reduce(_merge, parts)
     out_ref[...] = acc
     arg_ref[...] = arg
 
 
-def _maxplus_tiles(dp, f, *, n_shifts: int, interpret: bool):
-    """Row-batched dense (max,+) convolution over aligned [8, NBp] tiles.
+def _maxplus_tiles(dp, f, *, interpret: bool):
+    """Row-batched dense (max,+) convolution over aligned row blocks.
 
-    dp, f: [R, NB] (same dtype).  Returns ``(out [R, NB], arg [R, NB])``
-    with ``out[r, b] = max_{k < n_shifts, k <= b} dp[r, b-k] + f[r, k]``
-    and ``arg`` the largest maximizing shift (the kernel walks the shifts
-    in descending order and keeps the first maximizer), -1 where every
-    candidate is -inf.
+    dp: [R, NB], f: [R, K] with K <= NB.  Returns ``(out [R, NB],
+    arg [R, NB])`` with ``out[r, b] = max_{k < K, k <= b} dp[r, b-k] +
+    f[r, k]`` and ``arg`` the largest maximizing shift, -1 where every
+    candidate is -inf.  The block and the shifts a trip takes come from
+    the static shape (``_loop_shape``); shifts past K, up to the trips'
+    multiple of the unroll, read ``f = -inf`` and never win.
     """
     r, nb = dp.shape
-    rp, nbp = _round_up(r, _ROWS), _round_up(nb, _LANES)
+    n_shifts = f.shape[1]
+    nbp = _round_up(nb, _LANES)
+    block, unroll = _loop_shape(r, nbp)
+    rp = _round_up(r, block)
     neg = jnp.asarray(-jnp.inf, dp.dtype)
-    pad = ((0, rp - r), (0, nbp - nb))
-    dp_p = jnp.pad(dp, pad, constant_values=neg)
-    f_p = jnp.pad(f.astype(dp.dtype), pad, constant_values=neg)
-    tile = pl.BlockSpec((_ROWS, nbp), lambda i: (i, 0))
+    dp_p = jnp.pad(dp, ((0, rp - r), (0, nbp - nb)), constant_values=neg)
+    f_p = jnp.pad(
+        f.astype(dp.dtype), ((0, rp - r), (0, nbp - n_shifts)),
+        constant_values=neg,
+    )
+    tile = pl.BlockSpec((block, nbp), lambda i: (i, 0))
+    kernel = functools.partial(
+        _maxplus_kernel, n_trips=-(-n_shifts // unroll), unroll=unroll
+    )
     out, arg = pl.pallas_call(
-        functools.partial(_maxplus_kernel, n_shifts=n_shifts),
-        grid=(rp // _ROWS,),
+        kernel,
+        grid=(rp // block,),
         in_specs=[tile, tile],
         out_specs=[tile, tile],
         out_shape=[
@@ -170,7 +237,7 @@ def maxplus_stage_pallas_batched(
         # against the options sharing that spend (== f[r, kb[r, j]])
         same = kb[:, :, None] == kb[:, None, :]
         top = vb == jnp.max(jnp.where(same, vb[:, :, None], neg), axis=1)
-    out, arg_k = _maxplus_tiles(dp, f, n_shifts=nb, interpret=interpret)
+    out, arg_k = _maxplus_tiles(dp, f, interpret=interpret)
     with jax.named_scope("option_scatter"):
         # back to option indices: the first top option whose spend is the
         # winning shift (none where arg_k < 0, which maps to 0)
@@ -202,11 +269,7 @@ def maxplus_conv_pallas_batched(
     if f.shape[1] > dp.shape[1]:
         raise ValueError(f"more shifts than grid points: {f.shape} > {dp.shape}")
     dtype = jnp.float64 if dp.dtype == jnp.float64 else jnp.float32
-    dp = dp.astype(dtype)
-    n_shifts = f.shape[1]
-    f = jnp.pad(
-        f.astype(dtype), ((0, 0), (0, dp.shape[1] - n_shifts)),
-        constant_values=-jnp.inf,
+    out, arg = _maxplus_tiles(
+        dp.astype(dtype), f.astype(dtype), interpret=interpret
     )
-    out, arg = _maxplus_tiles(dp, f, n_shifts=n_shifts, interpret=interpret)
     return out, jnp.maximum(arg, 0)

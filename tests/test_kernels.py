@@ -38,22 +38,52 @@ def _largest_argmax(dp, f):
 
 
 class TestMaxPlus:
-    @pytest.mark.parametrize("nb", [17, 64, 200, 513])
-    @pytest.mark.parametrize("rows", [1, 11])
+    @pytest.mark.parametrize("nb", [17, 64, 200, 513, 2041])
+    @pytest.mark.parametrize("rows", [1, 11, 16, 40])
     def test_matches_ref(self, nb, rows):
-        """One row, and every row of a batch spanning two 8-row tiles,
-        against the jnp oracle; ties go to the largest shift."""
+        """One row, one whole-batch block, several blocks and padded rows,
+        on shift counts that are not a multiple of the loop's unroll,
+        bitwise against the jnp oracle; ties go to the largest shift.
+        Row 0 of a batch is all -inf: -1 from the kernel, 0 from the
+        wrapper."""
         rng = np.random.default_rng(nb + rows)
         dp = np.maximum.accumulate(rng.uniform(0, 1, (rows, nb)), axis=1)
         f = np.maximum.accumulate(rng.uniform(0, 1, (rows, nb)), axis=1)
+        if rows > 1:
+            dp[0] = -np.inf
         dp, f = jnp.asarray(dp, jnp.float32), jnp.asarray(f, jnp.float32)
         out_p, arg_p = mckp_dp.maxplus_conv_pallas_batched(dp, f)
         for r in range(rows):
             out_r, _ = ref.maxplus_conv(dp[r], f[r])
-            np.testing.assert_allclose(out_p[r], out_r, rtol=1e-6)
+            np.testing.assert_array_equal(np.asarray(out_p[r]), np.asarray(out_r))
             np.testing.assert_array_equal(
                 np.asarray(arg_p[r]), _largest_argmax(dp[r], f[r])
             )
+        if rows > 1:
+            _, arg_k = mckp_dp._maxplus_tiles(dp, f, interpret=True)
+            np.testing.assert_array_equal(np.asarray(arg_k[0]), -1)
+            assert np.all(np.asarray(arg_k[1:]) >= 0)
+
+    @pytest.mark.parametrize(
+        "rows,lanes,want",
+        [
+            (16, 256, (16, 4)),  # both cells' leaf stage: one block
+            (4, 256, (8, 8)),  # its share per chip of a four-chip scan
+            (8, 4096, (8, 1)),  # the frontier's widest wave
+            (1, 4096, (8, 1)),  # its last pair
+            (40, 256, (24, 2)),  # past one block: two, padded to 48 rows
+        ],
+    )
+    def test_loop_shape_follows_the_tile(self, rows, lanes, want):
+        """Row block and shifts a trip come from the static shape: whole
+        small batches, the partial accumulators within half the
+        register file, one shift a trip on the frontier's wide tiles."""
+        block, unroll = mckp_dp._loop_shape(rows, lanes)
+        assert (block, unroll) == want
+        regs = block * lanes // 1024
+        assert block % 8 == 0 and 128 % unroll == 0
+        assert unroll == 1 or 2 * unroll * regs <= 32
+        assert block == 8 or regs <= 8
 
     def test_monotone_inputs_monotone_output(self):
         rng = np.random.default_rng(0)
@@ -62,21 +92,33 @@ class TestMaxPlus:
         out, _ = mckp_dp.maxplus_conv_pallas_batched(dp[None], f[None])
         assert np.all(np.diff(np.asarray(out)[0]) >= -1e-6)
 
-    def test_planted_ties_resolve_to_largest_shift(self):
+    @pytest.mark.parametrize("rows,nb,k", [(9, 150, 70), (16, 256, 256), (40, 200, 131)])
+    def test_planted_ties_resolve_to_largest_shift(self, rows, nb, k):
         """Equal candidates at several shifts: the kernel keeps the largest
         (the frontier combine's smallest-left-spend tie-break), where the
-        oracle keeps the smallest; values agree bitwise."""
+        oracle keeps the smallest; values agree bitwise.  Ties planted in
+        every residue of the loop's unroll land in different partial
+        accumulators, and their merge still keeps the largest shift."""
         dp = jnp.zeros((1, 4), jnp.float32)
         f = jnp.ones((1, 3), jnp.float32)
         out, arg = mckp_dp.maxplus_conv_pallas_batched(dp, f)
         np.testing.assert_array_equal(np.asarray(out)[0], [1, 1, 1, 1])
         np.testing.assert_array_equal(np.asarray(arg)[0], [0, 1, 2, 2])
 
-        rng = np.random.default_rng(5)
-        rows, nb, k = 9, 150, 70
+        _, unroll = mckp_dp._loop_shape(rows, -(-nb // 128) * 128)
+        rng = np.random.default_rng(rows + nb)
         dp = rng.integers(0, 4, (rows, nb)).astype(np.float32)
         f = rng.integers(0, 4, (rows, k)).astype(np.float32)
         dp[:, :3] = -np.inf  # unreachable low grid points
+        # row 0: a flat dp and one tied best value at a few shifts spread
+        # over the residues mod the unroll, so each winner's rival sits
+        # in another partial accumulator (an earlier trip, too)
+        dp[0, 3:] = 0.0
+        f[0] = 0.0
+        plant = np.arange(3, k, max(1, unroll + 1))
+        f[0, plant] = 1.0
+        if unroll > 1:
+            assert len(set(plant % unroll)) > 1
         out, arg = mckp_dp.maxplus_conv_pallas_batched(
             jnp.asarray(dp), jnp.asarray(f)
         )
@@ -92,6 +134,11 @@ class TestMaxPlus:
             np.testing.assert_array_equal(np.asarray(arg)[r], want)
             differs += int(np.sum(want != np.asarray(arg_r)))
         assert differs > 0  # the ties were real
+        b = np.arange(nb)
+        best = np.array([plant[plant <= x - 3].max(initial=-1) for x in b])
+        np.testing.assert_array_equal(
+            np.asarray(arg)[0][best >= 0], best[best >= 0]
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -217,20 +264,20 @@ class TestRMSNorm:
 
 
 def _stage_ref_np(dp, kb, vb):
-    """Scalar oracle of maxplus_stage_pallas_batched (first-max in j)."""
+    """Scalar oracle of maxplus_stage_pallas_batched (first-max in j):
+    the option loop, each step one IEEE add per (row, grid point)."""
     r, nb = dp.shape
+    rows, b = np.arange(r)[:, None], np.arange(nb)[None, :]
     out = np.full((r, nb), -np.inf, dtype=dp.dtype)
     arg = np.zeros((r, nb), dtype=np.int32)
-    for ri in range(r):
-        for b in range(nb):
-            best, bj = -np.inf, 0
-            for j in range(kb.shape[1]):
-                k = kb[ri, j]
-                cand = dp[ri, b - k] + vb[ri, j] if b - k >= 0 else -np.inf
-                if cand > best:
-                    best, bj = cand, j
-            out[ri, b] = best
-            arg[ri, b] = bj
+    for j in range(kb.shape[1]):
+        src = b - kb[:, j : j + 1]
+        cand = np.where(
+            src >= 0, dp[rows, np.clip(src, 0, None)] + vb[:, j : j + 1], -np.inf
+        )
+        better = cand > out
+        out = np.where(better, cand, out)
+        arg = np.where(better, j, arg)
     return out, arg
 
 
@@ -246,10 +293,15 @@ def _stage_inputs(rng, r, nb, k, dtype):
 
 
 class TestMaxPlusStageBatched:
-    @pytest.mark.parametrize("r,nb,k", [(1, 16, 3), (4, 64, 8), (3, 200, 21)])
+    @pytest.mark.parametrize(
+        "r,nb,k",
+        [(1, 16, 3), (4, 64, 8), (3, 200, 21), (11, 17, 5), (16, 256, 256),
+         (40, 200, 21)],
+    )
     @pytest.mark.parametrize("tiles", [1, 3])
     def test_matches_scalar_ref(self, r, nb, k, tiles):
-        """``tiles=3`` stacks rows past one 8-row kernel tile."""
+        """``tiles=3`` stacks rows past one 8-row tile and, from 16 rows,
+        past one row block; grid widths not a multiple of the unroll."""
         r = r * tiles
         rng = np.random.default_rng(r * 1000 + nb + k)
         dp, kb, vb = _stage_inputs(rng, r, nb, k, np.float32)
@@ -283,14 +335,18 @@ class TestMaxPlusStageBatched:
             ("k_over_nb", 2, 8, 40),
             ("cell_k256", 2, 256, 256),
             ("cell_k128", 2, 256, 128),
+            ("rows11", 11, 17, 5),
+            ("cell_rows16_k256", 16, 256, 256),
+            ("rows40", 40, 200, 21),
         ],
     )
     def test_option_mapping_bitwise(self, case, r, nb, k):
         """The one-hot option mapping in float64, bit-for-bit against the
         option-order scan: options sharing a spend, equal values among
         them (the first of maximal value wins), spends past the grid,
-        rows of pads only, more options than grid points, and the
-        benchmark cells' (K, NB) on a few rows."""
+        rows of pads only, more options than grid points, the
+        benchmark cells' (K, NB) on a few rows and on a whole leaf batch,
+        and row counts of one, several and padded row blocks."""
         rng = np.random.default_rng(sum(map(ord, case)))
         with jax.enable_x64(True):
             dp, kb, vb = _stage_inputs(rng, r, nb, k, np.float64)

@@ -59,33 +59,53 @@ def _compiled_text(fn, *avals) -> str:
     return text
 
 
-@pytest.mark.parametrize("rows,nb,k", [(L, NB, K), (8, NBT, NBT)])
+def _kernel_names(text: str) -> list[str]:
+    return re.findall(
+        r'^\s*%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text, re.M
+    )
+
+
+@pytest.mark.parametrize(
+    "rows,nb,k",
+    [(L, NB, K), (8, NBT, NBT), (16, 256, 256), (16, 256, 128), (4, 256, 256)],
+)
 def test_stage_kernel_compiles(one_chip, rows, nb, k):
-    """The leaf DP stage at the real leaf widths, and at the frontier
-    tree's grid width."""
+    """The leaf DP stage at the real leaf widths, at the frontier tree's
+    grid width, at both benchmark cells' leaf batch (16 rows on a
+    256-point grid, K = 256 and 128) and at its four-chip share per
+    device (4 rows): one Mosaic kernel a stage, under its wrapper's
+    name."""
     def a(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    _compiled_text(
+    text = _compiled_text(
         lambda dp, kb, vb: mckp_dp.maxplus_stage_pallas_batched(
             dp, kb, vb, interpret=False
         ),
         a((rows, nb), jnp.float32), a((rows, k), jnp.int32),
         a((rows, k), jnp.float32),
     )
+    kernels = _kernel_names(text)
+    assert len(kernels) == 1
+    assert re.fullmatch(r"maxplus_\w*pallas\w*\.\d+", kernels[0])
 
 
-def test_dense_kernels_compile(one_chip):
+@pytest.mark.parametrize("rows,nb,k", [(8, NBT, 121), (8, 4096, 2041), (1, 4096, 256)])
+def test_dense_kernels_compile(one_chip, rows, nb, k):
     """The row-batched dense (max,+) convolution: the frontier combine of
-    the fused round."""
+    the fused round, also on the benchmark cells' 4,096-point tree grid
+    (the widest wave's and a single pair's shift counts)."""
     f32 = jnp.float32
-    _compiled_text(
+    text = _compiled_text(
         lambda dp, f: mckp_dp.maxplus_conv_pallas_batched(
             dp, f, interpret=False
         ),
-        jax.ShapeDtypeStruct((8, NBT), f32, sharding=one_chip),
-        jax.ShapeDtypeStruct((8, 121), f32, sharding=one_chip),
+        jax.ShapeDtypeStruct((rows, nb), f32, sharding=one_chip),
+        jax.ShapeDtypeStruct((rows, k), f32, sharding=one_chip),
     )
+    kernels = _kernel_names(text)
+    assert len(kernels) == 1
+    assert re.fullmatch(r"maxplus_\w*pallas\w*\.\d+", kernels[0])
 
 
 def _pipeline_avals(kb_sh, vb_sh, leaf_sh, rep_sh, lp, n_doms):
@@ -142,9 +162,7 @@ def test_fused_pipeline_keeps_its_names(topo):
         tree, L, L, S, K, NB, NBT, 1, False
     )
     text = run.lower(*_pipeline_avals(sh, sh, sh, sh, L, len(tree[1]))).compile().as_text()
-    kernels = re.findall(
-        r'^\s*%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text, re.M
-    )
+    kernels = _kernel_names(text)
     assert len(kernels) == 1 + len(tree[0])  # the leaf stage, one per wave
     assert all(re.fullmatch(r"maxplus_\w*pallas\w*\.\d+", k) for k in kernels)
     # every instruction, fused or not, with its op name
